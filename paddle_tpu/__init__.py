@@ -12,12 +12,6 @@ from .core.dtype import (bfloat16, bool_, complex64, complex128, float16,
                          int32, int64, set_default_dtype, uint8)
 from .core.errors import enforce
 from .core.flags import get_flags, set_flags
-from .core.flags import forward_xla_flags as _forward_xla_flags
-
-# XLA reads XLA_FLAGS once at backend init: forward the comm/compute
-# overlap knobs (latency-hiding scheduler, async collectives) before any
-# device use. Gated to explicit TPU targets — see core/flags.py.
-_forward_xla_flags()
 from .core.place import (CPUPlace, CUDAPlace, TPUPlace, TPUPinnedPlace,
                          device_count, get_device, is_compiled_with_cuda,
                          is_compiled_with_tpu, set_device)

@@ -212,14 +212,6 @@ define_flag("max_inplace_grad_add", 0,
             "Parity flag from flags.cc; unused (functional grads).")
 define_flag("tpu_matmul_precision", "default",
             "jax.lax matmul precision: default|high|highest.")
-define_flag("xla_latency_hiding_scheduler", True,
-            "Forward --xla_tpu_enable_latency_hiding_scheduler so XLA "
-            "schedules collectives/HBM copies under compute (comm/compute "
-            "overlap). Applied by forward_xla_flags() on TPU targets only.")
-define_flag("xla_async_collectives", True,
-            "Forward the async-collective-fusion trio so the dp gradient "
-            "all-reduce runs asynchronously and overlaps the backward. "
-            "Applied by forward_xla_flags() on TPU targets only.")
 
 
 # ---------------------------------------------------------------------------
@@ -237,10 +229,6 @@ define_env_flag(
     "(testing/chaos.py), e.g. 'ckpt.write:2:OSError'. Unset disables "
     "injection. See docs/fault_tolerance.md for the grammar and the "
     "registered site table.")
-define_env_flag(
-    "PADDLE_TPU_COMPILE_CACHE", "~/.cache/paddle_tpu/xla",
-    "Persistent XLA compile-cache directory (jit/compile_cache.py). "
-    "'0'/'off'/'none'/'' disables the cache.")
 define_env_flag(
     "PADDLE_TPU_DECODE_BUCKETS", "",
     "Decode-engine batch bucket ladder override, space/comma-separated "
@@ -488,95 +476,6 @@ define_env_flag(
     "PADDLE_TPU_VARZ_INTERVAL", 10.0,
     "Sampler period in seconds for /varz time-series history "
     "(observability/timeseries.py), min 0.05.")
-define_env_flag(
-    "PADDLE_TPU_XLA_OVERLAP", "auto",
-    "Gate for forwarding XLA comm/compute-overlap flags: '1'/'on' forces "
-    "(except on CPU), '0'/'off' never, 'auto' only when JAX_PLATFORMS "
-    "names tpu (this module, forward_xla_flags).")
-
-
-# ---------------------------------------------------------------------------
-# XLA_FLAGS forwarding (comm/compute overlap knobs)
-# ---------------------------------------------------------------------------
-# The production-TPU scheduling flags (MaxText's standard set). XLA reads
-# XLA_FLAGS once at backend init, so forwarding must happen before first
-# device use — paddle_tpu/__init__ calls forward_xla_flags() at import.
-_XLA_OVERLAP_FLAGS = {
-    "xla_latency_hiding_scheduler": (
-        "--xla_tpu_enable_latency_hiding_scheduler",
-    ),
-    "xla_async_collectives": (
-        "--xla_tpu_enable_async_collective_fusion",
-        "--xla_tpu_enable_async_collective_fusion_fuse_all_gather",
-        "--xla_tpu_enable_async_collective_fusion_multiple_steps",
-        "--xla_tpu_overlap_compute_collective_tc",
-    ),
-}
-
-
-def _xla_overlap_opts():
-    out = []
-    for opts in _XLA_OVERLAP_FLAGS.values():
-        out.extend(opts)
-    return out
-
-
-def forward_xla_flags(force=False):
-    """Append the enabled comm/compute-overlap knobs to XLA_FLAGS.
-
-    CAUTION: XLA aborts the process (LOG(FATAL) in parse_flags_from_env)
-    on flags its build does not register, and --xla_tpu_* flags only
-    exist in libtpu-backed builds. So forwarding is gated:
-
-    - ``PADDLE_TPU_XLA_OVERLAP=0/off``: never forward.
-    - ``PADDLE_TPU_XLA_OVERLAP=1/on`` (or ``force=True``): forward unless
-      the process targets CPU.
-    - default (auto): forward only when JAX_PLATFORMS explicitly names
-      ``tpu`` — the one target where these flags are known-registered.
-
-    Flags the user already set in XLA_FLAGS (either polarity) are left
-    alone. Returns the list of options appended (empty when gated off).
-    """
-    mode = str(env_value("PADDLE_TPU_XLA_OVERLAP")).strip().lower()
-    if mode in ("0", "off", "false", "no"):
-        return []
-    plats = os.environ.get("JAX_PLATFORMS", "").lower()
-    if plats.split(",")[0].strip() == "cpu":
-        return []
-    if not (force or mode in ("1", "on", "true", "yes")):
-        if "tpu" not in plats:
-            return []
-    current = os.environ.get("XLA_FLAGS", "")
-    added = []
-    for flag_name, opts in _XLA_OVERLAP_FLAGS.items():
-        if not get_flags(flag_name):
-            continue
-        for opt in opts:
-            if opt in current:
-                continue
-            added.append(f"{opt}=true")
-    if added:
-        os.environ["XLA_FLAGS"] = (current + " " + " ".join(added)).strip()
-    return added
-
-
-def strip_xla_overlap_flags(env=None):
-    """Remove every overlap knob from XLA_FLAGS (in `env` or os.environ).
-
-    Used by fallback paths that re-target a CPU backend after a TPU
-    failure: the CPU build would abort on the unknown --xla_tpu_* flags
-    this module (or the user) forwarded."""
-    env = os.environ if env is None else env
-    current = env.get("XLA_FLAGS", "")
-    if not current:
-        return env
-    kept = [tok for tok in current.split()
-            if tok.split("=")[0] not in _xla_overlap_opts()]
-    if kept:
-        env["XLA_FLAGS"] = " ".join(kept)
-    else:
-        env.pop("XLA_FLAGS", None)
-    return env
 
 
 if __name__ == "__main__":

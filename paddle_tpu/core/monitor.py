@@ -10,9 +10,10 @@ stat lands as a ``paddle_tpu_monitor_stat{name="..."}`` gauge sample,
 so framework/user instrumentation shows up on the same ``/metrics``
 scrape as the serving counters (docs/observability.md). Device memory
 numbers come from PJRT (jax ``Device.memory_stats``) instead of
-allocator internals, because XLA owns HBM on TPU (SURVEY.md rows 7/10);
-every probe is hardened to return empty/zero — never raise — when the
-backend is unreachable or reports no memory stats (CPU).
+allocator internals, because XLA owns HBM on TPU (SURVEY.md rows 7/10).
+A device that reports no memory stats (CPU) comes back empty/zero; a
+backend that fails to initialise raises — an unreachable accelerator
+must not read as "CPU".
 """
 from __future__ import annotations
 
@@ -55,42 +56,33 @@ def all_stats() -> Dict[str, int]:
             for labels, child in _STATS.samples()}
 
 
+def _stats_of(device) -> Dict[str, int]:
+    try:
+        return dict(device.memory_stats() or {})
+    except Exception:       # this device cannot report; others may
+        return {}
+
+
 def device_memory_stats(device=None) -> Dict[str, int]:
     """PJRT per-device memory counters (bytes_in_use, peak_bytes_in_use,
-    bytes_limit where the runtime reports them). Returns ``{}`` — never
-    raises — when the backend fails to initialize or the device reports
-    no memory stats (CPU)."""
-    try:
-        if device is None:
-            import jax
-            devs = jax.devices()
-            if not devs:
-                return {}
-            device = devs[0]
-        return dict(device.memory_stats() or {})
-    except Exception:
-        return {}
+    bytes_limit where the runtime reports them); ``{}`` when the device
+    reports none (CPU). Raises when the backend cannot initialise."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    return _stats_of(device)
 
 
 def all_device_memory_stats() -> Dict[str, Dict[str, int]]:
-    """{str(device): memory_stats} over every visible device; devices
-    (or backends) that cannot report come back as empty dicts."""
-    try:
-        import jax
-        devs = jax.devices()
-    except Exception:
-        return {}
-    out = {}
-    for d in devs:
-        try:
-            out[str(d)] = dict(d.memory_stats() or {})
-        except Exception:
-            out[str(d)] = {}
-    return out
+    """{str(device): memory_stats} over every visible device; a device
+    that cannot report comes back as an empty dict. Raises when the
+    backend cannot initialise."""
+    import jax
+    return {str(d): _stats_of(d) for d in jax.devices()}
 
 
 def hbm_usage(device=None):
     """(bytes_in_use, bytes_limit) — the STAT_GPU_MEM analog for HBM.
-    (0, 0) when the runtime has nothing to report."""
+    (0, 0) when the device has nothing to report (CPU)."""
     st = device_memory_stats(device)
     return st.get("bytes_in_use", 0), st.get("bytes_limit", 0)

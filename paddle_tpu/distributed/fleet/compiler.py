@@ -79,14 +79,9 @@ class CompiledTrainStep:
                 if self.mesh is not None else 1
             use_cache = not (n_mesh > 1
                              and jax.default_backend() == "cpu")
-            try:
-                self._aot, self.compile_stats = compile_cache.aot_compile(
-                    self._step, *args, label=self._step_label,
-                    use_cache=use_cache)
-            except compile_cache.RetraceError:
-                raise
-            except Exception:  # exotic input: keep the implicit jit path
-                self._aot = self._step
+            self._aot, self.compile_stats = compile_cache.aot_compile(
+                self._step, *args, label=self._step_label,
+                use_cache=use_cache)
         loss, self.params, self.state, self.opt_state = self._aot(*args)
         return loss
 
@@ -342,27 +337,36 @@ def compile_train_step(layer, optimizer, strategy: DistributedStrategy,
 
     # ---- the traced step -------------------------------------------------
     def _run_contexts():
-        """One source of truth for the amp + sequence-parallel scopes the
-        train AND eval traces run under."""
+        """One source of truth for the amp + attention-mesh scopes the
+        train AND eval traces run under. With sp > 1 attention is the
+        shard_map-inner ring/Ulysses; otherwise, on a multi-device mesh,
+        the flash kernel runs shard_map-inner on its dp/tp shard (GSPMD
+        cannot partition a Mosaic call)."""
         import contextlib
 
         from ... import amp as amp_mod
-        from ...nn.functional.attention import seq_parallel_scope
-        sp_ctx = (seq_parallel_scope(
-            mesh, "sp", impl=strategy.sequence_parallel_impl,
-            batch_axis="dp" if n_dp > 1 else None,
-            head_axis="tp" if n_tp > 1 else None)
-            if n_sp > 1 else contextlib.nullcontext())
+        from ...nn.functional.attention import (flash_mesh_scope,
+                                                seq_parallel_scope)
+        batch_axis = "dp" if n_dp > 1 else None
+        head_axis = "tp" if n_tp > 1 else None
+        if n_sp > 1:
+            attn_ctx = seq_parallel_scope(
+                mesh, "sp", impl=strategy.sequence_parallel_impl,
+                batch_axis=batch_axis, head_axis=head_axis)
+        elif mesh.size > 1:
+            attn_ctx = flash_mesh_scope(mesh, batch_axis, head_axis)
+        else:
+            attn_ctx = contextlib.nullcontext()
         amp_ctx = amp_mod.auto_cast(enable=amp_on,
                                     level="O2" if pure_bf16 else "O1",
                                     dtype="bfloat16")
-        return sp_ctx, amp_ctx
+        return attn_ctx, amp_ctx
 
     def forward_loss(p, st, key, *data):
-        sp_ctx, amp_ctx = _run_contexts()
+        attn_ctx, amp_ctx = _run_contexts()
         with random_mod.key_scope(key):
             with amp_ctx:
-                with sp_ctx:
+                with attn_ctx:
                     out, new_state = functional_call(wrapped, p, st, *data)
         return out, new_state
 
@@ -473,10 +477,10 @@ def compile_train_step(layer, optimizer, strategy: DistributedStrategy,
             # fixed key: eval-mode layers draw no dropout, and any
             # stray randomness must at least be deterministic
             if has_outs:
-                sp_ctx, amp_ctx = _run_contexts()
+                attn_ctx, amp_ctx = _run_contexts()
                 with random_mod.key_scope(jax.random.key(0)):
                     with amp_ctx:
-                        with sp_ctx:
+                        with attn_ctx:
                             (loss, outs), _ = functional_call(
                                 wrapped_eval, p, st, *data)
                 return loss, outs

@@ -2,13 +2,16 @@
 management launch_utils.py:425 TrainerProc / :435 start_local_trainers /
 :526 watch_local_trainers).
 
-On TPU pods the unit is one process per HOST (all local chips belong to
-it), coordinated by jax.distributed — so the launcher starts one worker
-per host entry and exports the same PADDLE_* env protocol the reference
-uses, plus the jax coordinator address.
+On TPU pods the unit is one process per HOST: every local chip belongs
+to it (a chip serves one process at a time, and one process drives all
+the chips of its host), coordinated across hosts by jax.distributed. So
+the launcher starts ONE worker on this node and exports the same
+PADDLE_* env protocol the reference uses, plus the jax coordinator
+address. The reference's --nproc_per_node (one process per GPU) has no
+TPU meaning and is not offered.
 
-Usage: python -m paddle_tpu.distributed.launch --nproc_per_node=1
-           --ips=host1,host2 train.py [args...]
+Usage: python -m paddle_tpu.distributed.launch --ips=host1,host2
+           --node_rank=0 train.py [args...]
 """
 from __future__ import annotations
 
@@ -29,37 +32,27 @@ class TrainerProc:
         self.log_file = log_file
 
 
-def start_local_trainers(script, script_args, nproc, node_rank, nnodes,
-                         master, log_dir=None, hosts=None):
-    """Spawn nproc workers on this node with the PADDLE_* env protocol
-    (launch_utils.py:435). Endpoints pair each host with its local ranks'
-    ports (rank r lives on hosts[r // nproc])."""
-    procs = []
-    world = nproc * nnodes
-    base_port = int(master.split(":")[1])
+def start_local_trainers(script, script_args, node_rank, nnodes, master,
+                         log_dir=None, hosts=None):
+    """Spawn this node's worker with the PADDLE_* env protocol
+    (launch_utils.py:435): rank r lives on hosts[r]."""
+    port = int(master.split(":")[1])
     hosts = hosts or [master.split(":")[0]] * nnodes
-    endpoints = ",".join(
-        f"{hosts[r // nproc]}:{base_port + (r % nproc)}"
-        for r in range(world))
-    for local_rank in range(nproc):
-        rank = node_rank * nproc + local_rank
-        env = dict(os.environ)
-        env.update({
-            "PADDLE_TRAINER_ID": str(rank),
-            "PADDLE_TRAINERS_NUM": str(world),
-            "PADDLE_TRAINER_ENDPOINTS": endpoints,
-            "PADDLE_MASTER_ENDPOINT": master,
-            "PADDLE_LOCAL_RANK": str(local_rank),
-            "FLAGS_selected_tpus": str(local_rank),
-        })
-        log = None
-        if log_dir:
-            os.makedirs(log_dir, exist_ok=True)
-            log = open(os.path.join(log_dir, f"workerlog.{rank}"), "w")
-        p = subprocess.Popen([sys.executable, script] + list(script_args),
-                             env=env, stdout=log or None, stderr=log or None)
-        procs.append(TrainerProc(p, rank, log))
-    return procs
+    env = dict(os.environ)
+    env.update({
+        "PADDLE_TRAINER_ID": str(node_rank),
+        "PADDLE_TRAINERS_NUM": str(nnodes),
+        "PADDLE_TRAINER_ENDPOINTS": ",".join(f"{h}:{port}" for h in hosts),
+        "PADDLE_MASTER_ENDPOINT": master,
+        "PADDLE_LOCAL_RANK": "0",
+    })
+    log = None
+    if log_dir:
+        os.makedirs(log_dir, exist_ok=True)
+        log = open(os.path.join(log_dir, f"workerlog.{node_rank}"), "w")
+    p = subprocess.Popen([sys.executable, script] + list(script_args),
+                         env=env, stdout=log or None, stderr=log or None)
+    return [TrainerProc(p, node_rank, log)]
 
 
 def watch_local_trainers(procs, poll_s=1.0):
@@ -88,7 +81,6 @@ def watch_local_trainers(procs, poll_s=1.0):
 
 def launch(args=None):
     parser = argparse.ArgumentParser("paddle_tpu.distributed.launch")
-    parser.add_argument("--nproc_per_node", type=int, default=1)
     parser.add_argument("--ips", type=str, default="127.0.0.1",
                         help="comma-separated host list")
     parser.add_argument("--node_rank", type=int,
@@ -101,8 +93,7 @@ def launch(args=None):
 
     hosts = ns.ips.split(",")
     master = f"{hosts[0]}:{ns.master_port}"
-    procs = start_local_trainers(ns.script, ns.script_args,
-                                 ns.nproc_per_node, ns.node_rank,
+    procs = start_local_trainers(ns.script, ns.script_args, ns.node_rank,
                                  len(hosts), master, ns.log_dir, hosts=hosts)
     return watch_local_trainers(procs)
 

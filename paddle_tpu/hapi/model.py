@@ -45,8 +45,8 @@ class _AsyncScalar:
     """A loss that stays on device until someone looks at it.
 
     fit() keeps the dispatch pipeline full by NOT fetching the loss every
-    batch (each fetch is a host sync — through a remote-attached TPU it
-    costs a full RTT); callbacks/logs materialise it lazily at log_freq.
+    batch (each fetch is a host sync that drains the in-flight steps);
+    callbacks/logs materialise it lazily at log_freq.
     Reference analog: the monitor fetches fetch_list values only at
     Profiler/log steps, not per batch."""
 
@@ -204,8 +204,7 @@ class Model:
                 "amp_configs is ignored on the strategy training path; "
                 "set strategy.amp=True (+ amp_configs.use_pure_bf16 for "
                 "O2) instead")
-        # wire the persistent XLA compile cache (PADDLE_TPU_COMPILE_CACHE,
-        # default ~/.cache/paddle_tpu/xla) before the first compile
+        # wire the persistent XLA compile cache before the first compile
         compile_cache.setup_compilation_cache()
         self._invalidate()
 

@@ -102,7 +102,7 @@ import jax.numpy as jnp
 from .. import profiler
 from ..core import flags as _flags
 from ..core import monitor
-from ..jit.compile_cache import AotCache
+from ..jit.compile_cache import AotCache, aot_compile
 from ..memory.migration import (HostPageStore, MigrationEngine,
                                 TieredPageAllocator, deserialize_pages,
                                 serialize_pages, tier_metrics)
@@ -380,16 +380,78 @@ def kv_page_bytes(cfg: GPTConfig, page_tokens: int,
     return rows * cfg.head_dim * 4
 
 
-def default_slot_count(cfg: GPTConfig, hbm_fraction: float = 0.5,
+def fit_slot_count(step_bytes, budget: int, upper: int,
+                   start: int = DEFAULT_MAX_SLOTS) -> int:
+    """Largest slot count <= `upper` whose compiled step fits `budget`
+    bytes of HBM. `step_bytes(n)` is the footprint of the largest step
+    of an n-slot engine, or None when the compiler itself ran out of
+    HBM. The footprint is close to linear in n (pools and gather
+    temporaries both grow with it), so proportional moves inside the
+    (largest fitting, smallest failing) bracket settle in two or three
+    compiles."""
+    fits, fails = 0, upper + 1
+    n = min(upper, start)
+    while True:
+        need = step_bytes(n)
+        if need is not None and need <= budget:
+            fits = n
+        else:
+            fails = n
+        nxt = n // 2 if need is None else n * budget // need
+        n = min(max(nxt, 1), fails - 1)
+        if n <= fits:
+            break
+    if fits:
+        return fits
+    if need is None:
+        raise RuntimeError(
+            "decode engine: the paged step does not fit this device's "
+            "HBM even with one KV slot")
+    return 1        # compiles, above the budget: one slot is the floor
+
+
+def default_slot_count(step_jit, params, cfg: GPTConfig, page_tokens: int,
+                       kv_dtype: str = "float32",
+                       hbm_fraction: float = 0.5,
                        fallback: int = DEFAULT_MAX_SLOTS) -> int:
-    """Size the slot pool from live HBM stats: how many full-capacity KV
-    panels fit in `hbm_fraction` of the free bytes. CPU (stats (0, 0))
+    """Size the slot pool from live HBM stats and the COMPILED step: the
+    largest slot count whose biggest step executable (full batch rung x
+    full block-table width) needs no more than what is in use now plus
+    `hbm_fraction` of the free bytes. The compiler's own
+    `memory_analysis()` is the cost model — it sees what arithmetic on
+    logical shapes cannot: tile padding of the [.., heads, head_dim]
+    minor dims and the step's gather temporaries, together several
+    times the pools' logical bytes. A device without memory stats (CPU)
     gets the fixed fallback so tests and benches behave identically."""
     used, limit = monitor.hbm_usage()
     if limit <= 0:
         return fallback
-    free = max(limit - used, 0) * hbm_fraction
-    return max(1, min(int(free // kv_slot_bytes(cfg)), 256))
+    budget = used + int(max(limit - used, 0) * hbm_fraction)
+    L, nh, D = cfg.layers, cfg.heads, cfg.head_dim
+    pages_per_seq = -(-cfg.max_seq_len // page_tokens)
+    i32 = jnp.int32
+
+    def step_bytes(n):
+        pool = kv_pool_sds((L, n * pages_per_seq + 1, page_tokens, nh, D),
+                           kv_dtype)
+        try:
+            exe, _ = aot_compile(
+                step_jit, params, pool, pool,
+                jax.ShapeDtypeStruct((n, pages_per_seq), i32),
+                jax.ShapeDtypeStruct((n,), i32),
+                jax.ShapeDtypeStruct((n,), i32),
+                label=f"decode.sizing:{n}")
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            return None
+        m = exe.memory_analysis()
+        return (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+    # logical K+V bytes per slot bound the count from above
+    upper = max(1, min(int((limit - used) // kv_slot_bytes(cfg)), 256))
+    return fit_slot_count(step_bytes, budget, upper)
 
 
 def kv_capacity_ladder(max_seq_len: int,
@@ -849,10 +911,6 @@ class DecodeEngine:
         self.params = {k: jnp.asarray(v) for k, v in params.items()}
         self.max_new_tokens = int(max_new_tokens)
         self.eos_id = eos_id
-        self.max_slots = int(max_slots) if max_slots \
-            else default_slot_count(cfg, hbm_fraction)
-        self.max_pending = int(max_pending) if max_pending is not None \
-            else 4 * self.max_slots
         self.page_tokens = int(
             page_tokens or _flags.env_value("PADDLE_TPU_DECODE_PAGE_TOKENS"))
         if self.page_tokens < 1:
@@ -861,6 +919,19 @@ class DecodeEngine:
         self.kv_dtype = validate_kv_dtype(
             kv_dtype if kv_dtype is not None
             else _flags.env_value("PADDLE_TPU_DECODE_KV_DTYPE"))
+        prefill_fn, step_fn = gpt_paged_decode_fns(
+            cfg, eps=self.eps, page_tokens=self.page_tokens)
+        # Pool args are donated: every call site rebinds the pools from
+        # the result, so XLA updates the multi-MB pool buffers in place
+        # instead of copying them per dispatch (the copy dominated
+        # step/verify cost on CPU).
+        step_jit = jax.jit(step_fn, donate_argnums=(1, 2))
+        self.max_slots = int(max_slots) if max_slots \
+            else default_slot_count(step_jit, self.params, cfg,
+                                    self.page_tokens, self.kv_dtype,
+                                    hbm_fraction)
+        self.max_pending = int(max_pending) if max_pending is not None \
+            else 4 * self.max_slots
         self.batch_ladder = bucket_ladder(
             self.max_slots, env=_flags.env_value("PADDLE_TPU_DECODE_BUCKETS"))
         self.kv_ladder = kv_capacity_ladder(cfg.max_seq_len,
@@ -899,15 +970,9 @@ class DecodeEngine:
         self._prefix = _PrefixCache(self._alloc, self.page_tokens) \
             if use_prefix else None
 
-        prefill_fn, step_fn = gpt_paged_decode_fns(
-            cfg, eps=self.eps, page_tokens=self.page_tokens)
-        # Pool args are donated: every call site rebinds the pools from
-        # the result, so XLA updates the multi-MB pool buffers in place
-        # instead of copying them per dispatch (the copy dominated
-        # step/verify cost on CPU).
         self._prefill_aot = AotCache(jax.jit(prefill_fn), "decode.prefill")
-        self._step_aot = AotCache(jax.jit(step_fn, donate_argnums=(1, 2)),
-                                  "decode.pstep", donate_argnums=(1, 2))
+        self._step_aot = AotCache(step_jit, "decode.pstep",
+                                  donate_argnums=(1, 2))
         self._write_aot = AotCache(
             jax.jit(_write_kv_pages, donate_argnums=(0, 1)), "decode.pwrite",
             donate_argnums=(0, 1))

@@ -55,9 +55,10 @@ What the router does per request:
 
 ``BackendSupervisor`` optionally owns the fleet: ``--fleet N`` spawns N
 ``serve.py`` daemons from the model prefix, restarts dead ones with
-bounded backoff (sharing one ``PADDLE_TPU_COMPILE_CACHE`` directory so a
-restarted backend warms from the persistent compile cache), and swaps
-them into the routing table live.
+bounded backoff (all resolving the same persistent compile-cache
+directory, so a restarted backend warms from it), and swaps them into
+the routing table live. On a TPU host each backend is pinned to one
+chip and the router process itself never initialises a JAX backend.
 
 Chaos site ``router.forward`` fires once per backend attempt, so tests
 inject wire failures between router and backend deterministically
@@ -94,6 +95,7 @@ from http.client import HTTPConnection
 import numpy as np
 
 from ..core import flags as _flags
+from ..core.place import local_tpu_chip_count, single_chip_env
 from ..observability import (FlightRecorder, SLOEngine, SpanRecorder,
                              TimeSeriesStore, next_request_id,
                              request_id_base, router_objectives)
@@ -488,7 +490,10 @@ class ServeRouter:
         if metrics_port is not None and int(metrics_port) >= 0:
             from ..observability import (AdminServer,
                                          install_default_collectors)
-            install_default_collectors()
+            # no HBM collector: the router owns no device, and a scrape
+            # that called jax.devices() here would take the chips its
+            # backends need
+            install_default_collectors(hbm=False)
             self._varz = TimeSeriesStore()
             self._varz.start()
             self._slo = SLOEngine(self._varz, router_objectives())
@@ -1764,9 +1769,10 @@ class BackendSupervisor:
     from the routing table and respawned with bounded exponential
     backoff — up to ``max_restarts`` times per slot, after which the
     slot is abandoned (the router simply keeps routing around it). All
-    backends share one ``PADDLE_TPU_COMPILE_CACHE`` directory, so a
-    respawned backend warms its bucket ladder from the persistent
-    compile cache instead of recompiling from scratch.
+    backends resolve the same persistent compile-cache directory
+    (jit/compile_cache.py), so a respawned backend warms its bucket
+    ladder from it instead of recompiling from scratch. On a TPU host
+    slot i is pinned to chip i, and more slots than chips is an error.
 
     ``terminate(key)`` SIGTERMs one backend (it drains via serve.py's
     handler) — the rolling-restart primitive: the watcher respawns it
@@ -1783,11 +1789,18 @@ class BackendSupervisor:
         self.serve_args = list(serve_args or [])
         self.max_restarts = int(max_restarts)
         self.start_timeout = float(start_timeout)
+        # children inherit the environment as it is — the compile cache
+        # included: JAX_COMPILATION_CACHE_DIR if set, else every backend
+        # resolves the same fixed in-checkout directory on its own
         self._env = dict(env if env is not None else os.environ)
-        if "PADDLE_TPU_COMPILE_CACHE" not in self._env:
-            import tempfile
-            self._cache_dir = tempfile.mkdtemp(prefix="paddle_tpu_fleet_")
-            self._env["PADDLE_TPU_COMPILE_CACHE"] = self._cache_dir
+        # one backend per TPU chip: slot i is pinned to chip i. This
+        # process never initialises a backend (it would take the chips).
+        self._chips = local_tpu_chip_count(self._env)
+        if self._chips and self.count > self._chips:
+            raise ValueError(
+                f"--fleet {self.count}: this host has {self._chips} TPU "
+                f"chip(s) and a chip serves one process at a time — ask "
+                f"for at most {self._chips} backend(s)")
         self._m = _router_metrics()
         self._stop = threading.Event()
         self._lock = threading.Lock()
@@ -1795,18 +1808,21 @@ class BackendSupervisor:
         self._slots = {}
         self._watch_thread = None
 
-    def _spawn(self) -> _ProcIO:
+    def _spawn(self, slot: int) -> _ProcIO:
         cmd = [sys.executable, "-m", "paddle_tpu.inference.serve",
                self.model_prefix, "--port", "0", "--metrics-port", "0",
                "--stats-interval", "0"] + self.serve_args
+        env = self._env
+        if self._chips:
+            env = dict(env, **single_chip_env(slot))
         proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
                                 stderr=subprocess.STDOUT, text=True,
-                                env=self._env)
+                                env=env)
         return _ProcIO(proc)
 
     def start(self):
         for slot in range(self.count):
-            io = self._spawn()
+            io = self._spawn(slot)
             port, admin = io.wait_serving(self.start_timeout)
             backend = Backend(self.host, port, admin)
             self.router.add_backend(backend)
@@ -1874,7 +1890,7 @@ class BackendSupervisor:
         s["restarts"] += 1
         self._m["backend_restarts"].inc()
         try:
-            io = self._spawn()
+            io = self._spawn(slot)
         except OSError as e:
             print(f"FLEET slot {slot} respawn failed: {e}", flush=True)
             return                       # old dead io stays; retry next tick
@@ -1972,8 +1988,13 @@ def main_router(args) -> int:
             serve_args += ["--request-timeout", str(args.request_timeout)]
         if args.max_queue is not None:
             serve_args += ["--max-queue", str(args.max_queue)]
-        sup = BackendSupervisor(args.model, args.fleet, router,
-                                host=args.host, serve_args=serve_args)
+        try:
+            sup = BackendSupervisor(args.model, args.fleet, router,
+                                    host=args.host, serve_args=serve_args)
+        except (ValueError, RuntimeError) as e:   # more slots than chips
+            print(f"FLEET start failed: {e}", flush=True)
+            router.stop()
+            return 2
         try:
             sup.start()
         except RuntimeError as e:
@@ -2009,4 +2030,4 @@ def main_router(args) -> int:
 
 if __name__ == "__main__":
     from .serve import main
-    main(sys.argv[1:] + ["--router"])
+    sys.exit(main(sys.argv[1:] + ["--router"]))

@@ -49,11 +49,13 @@ lock. See docs/serving.md.
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import os
 import signal
 import socket
 import struct
+import sys
 import threading
 import time
 from concurrent.futures import TimeoutError as FuturesTimeout
@@ -517,6 +519,7 @@ class InferenceServer:
         from .. import profiler
         from ..core import monitor
 
+        compiles = profiler.compile_events()
         st = {
             "engine": "decode" if self._engine is not None
             else ("batched" if self._batched else "serialized"),
@@ -537,7 +540,13 @@ class InferenceServer:
                 "max_request_bytes": max_request_bytes(),
             },
             "warmup_compiles": self.warmup_compiles,
-            "compiles": len(profiler.compile_events()),
+            "compiles": len(compiles),
+            # persistent-cache verdicts of those compiles: a warm
+            # restart shows every one of them under "hit"
+            "compile_cache": dict(collections.Counter(
+                e["cache"] for e in compiles)),
+            "compile_seconds": round(
+                sum(e["compile_s"] for e in compiles), 3),
             "serve": profiler.serve_stats(),
             "device_memory": monitor.all_device_memory_stats(),
         }
@@ -996,8 +1005,9 @@ def main(argv=None):
                          "decode")
     ap.add_argument("--decode-slots", type=int, default=None,
                     help="(decode) KV-cache slot-pool size — concurrent "
-                         "sequences; default sized from free HBM "
-                         "(core.monitor), fixed fallback of 8 on CPU")
+                         "sequences; default: the most whose largest "
+                         "compiled step fits half the free HBM "
+                         "(decode.default_slot_count), 8 on CPU")
     ap.add_argument("--decode-max-new", type=int, default=None,
                     help="(decode) default max new tokens per request "
                          "when the client does not specify one")
@@ -1080,13 +1090,6 @@ def main(argv=None):
         return main_router(args)
     if not args.model:
         ap.error("model prefix is required (or pass --router)")
-    # honor JAX_PLATFORMS for the daemon: a TPU PJRT plugin outranks the
-    # env var during backend registration, so an explicit config update is
-    # the only way `JAX_PLATFORMS=cpu python -m ...serve` stays off-chip
-    platforms = os.environ.get("JAX_PLATFORMS")
-    if platforms:
-        import jax
-        jax.config.update("jax_platforms", platforms)
     srv = InferenceServer(args.model, port=args.port, host=args.host,
                           max_batch_size=args.max_batch,
                           batch_timeout_ms=args.batch_timeout_ms,
@@ -1156,4 +1159,4 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -5,7 +5,7 @@ train step returns futures immediately, and the host only stalls when it
 *reads* a value (``jax.device_get`` / ``block_until_ready``).  A train
 loop that fetches the loss every step therefore serializes host collate,
 dispatch and device compute — the chip idles for a full host round-trip
-per step (on a remote-attached TPU that RTT dominates).  The fix is pure
+per step.  The fix is pure
 reordering of host reads: keep the loss on device, keep up to N steps in
 flight, and resolve metrics only at log/callback boundaries.  Numerics
 are bit-identical to the synchronous loop — nothing about the computation

@@ -5,10 +5,14 @@ Three small pieces shared by the hapi single-device train step and the
 fleet ``CompiledTrainStep`` (SPMD / pipeline / explicit-DP shard_map — all
 strategy paths funnel through ``CompiledTrainStep.step``):
 
-* ``setup_compilation_cache()`` points ``jax_compilation_cache_dir`` at
-  ``PADDLE_TPU_COMPILE_CACHE`` (default ``~/.cache/paddle_tpu/xla``) so a
-  recompile of an identical HLO module is a disk read, not an XLA run.
-  Set the env var to ``0``/``off`` to disable.
+* ``setup_compilation_cache()`` makes sure jax's persistent compilation
+  cache has a directory, so a recompile of an identical HLO module is a
+  disk read, not an XLA run. Placement is jax's own:
+  ``JAX_COMPILATION_CACHE_DIR`` (or a ``jax_compilation_cache_dir`` the
+  caller configured) is left exactly as it is; only when neither is set
+  does the cache go to ``.jax_cache/`` at the root of this checkout — a
+  fixed path, because the path is part of what a later run must find
+  again. ``JAX_ENABLE_COMPILATION_CACHE=false`` disables it.
 * ``aot_compile(jitted, *args)`` replaces the first-step implicit compile
   with an explicit ``.lower().compile()``, timed and reported through
   ``paddle_tpu.profiler.record_compile`` with a cache hit/miss verdict
@@ -32,57 +36,53 @@ __all__ = ["setup_compilation_cache", "suspend_compilation_cache",
            "cache_dir", "aot_compile", "AotCache",
            "RetraceGuard", "RetraceError", "RetraceWarning"]
 
-_DISABLED = ("", "0", "off", "none", "disabled", "false")
+# the one in-code default: <checkout>/.jax_cache (git-ignored)
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-# last directory applied to jax.config (setup is idempotent per dir)
-_configured: list = [None]
+_wired = [False]        # thresholds + default dir applied once per process
+_suspended = [False]    # this module switched the cache off (CPU meshes)
+
+
+def _reset_jax_cache():
+    """jax builds its cache object lazily on the first compile and then
+    never looks at the config again; drop it so a changed setting takes
+    effect."""
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+
+    _cc.reset_cache()
 
 
 def cache_dir() -> Optional[str]:
-    """Resolved persistent-cache directory, or None when disabled."""
-    d = _flags.env_raw("PADDLE_TPU_COMPILE_CACHE")
-    if d is None:
-        d = os.path.join("~", ".cache", "paddle_tpu", "xla")
-    if d.strip().lower() in _DISABLED:
+    """Directory jax's persistent cache is using, or None when it is
+    disabled."""
+    import jax
+
+    if not jax.config.jax_enable_compilation_cache:
         return None
-    return os.path.expanduser(d)
+    return jax.config.jax_compilation_cache_dir
 
 
 def setup_compilation_cache() -> Optional[str]:
-    """Idempotently wire jax's persistent compilation cache.
+    """Idempotently wire jax's persistent compilation cache; returns the
+    active cache directory (None when disabled)."""
+    import jax
 
-    Returns the active cache directory, or None when disabled or when the
-    jax build does not support the persistent cache (never raises — a
-    missing cache only costs compile time)."""
-    d = cache_dir()
-    if d is None or _configured[0] == d:
-        return _configured[0]
-    try:
-        import jax
-
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        # the in-process cache object is created lazily on the FIRST
-        # compile — which usually happened (disabled) during framework
-        # import; reset so the new dir actually takes effect
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc)
-            _cc.reset_cache()
-        except Exception:
-            pass
+    if _suspended[0]:
+        jax.config.update("jax_enable_compilation_cache", True)
+        _suspended[0] = False
+        _reset_jax_cache()
+    if not _wired[0]:
+        _wired[0] = True
         # Default thresholds skip "cheap" (sub-second / small) compiles —
-        # exactly the CPU-test regime; cache everything instead.
-        for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0),
-                         ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(opt, val)
-            except Exception:
-                pass
-    except Exception:
-        return None
-    _configured[0] = d
-    return d
+        # most of a serving ladder; cache everything instead.
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        if jax.config.jax_compilation_cache_dir is None:
+            jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
+        _reset_jax_cache()
+    return cache_dir()
 
 
 def _cache_listing(d: Optional[str]) -> Optional[set]:
@@ -91,30 +91,23 @@ def _cache_listing(d: Optional[str]) -> Optional[set]:
     try:
         return set(os.listdir(d))
     except OSError:
-        return None
+        return set()        # jax creates the directory on its first write
 
 
 def suspend_compilation_cache() -> None:
-    """Detach the persistent cache (until the next
+    """Switch the persistent cache off (until the next
     ``setup_compilation_cache`` call). Used for compiles that must not be
     served from disk — deserializing a multi-device executable on the CPU
     backend corrupts the heap (observed with forced-host-device meshes),
-    so those compiles opt out via ``aot_compile(use_cache=False)``."""
-    if _configured[0] is None:
-        return
-    try:
-        import jax
+    so those compiles opt out via ``aot_compile(use_cache=False)``. The
+    one caller gates it on the CPU backend; a TPU never gets here."""
+    import jax
 
-        jax.config.update("jax_compilation_cache_dir", None)
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc)
-            _cc.reset_cache()
-        except Exception:
-            pass
-    except Exception:
+    if not jax.config.jax_enable_compilation_cache:
         return
-    _configured[0] = None
+    jax.config.update("jax_enable_compilation_cache", False)
+    _suspended[0] = True
+    _reset_jax_cache()
 
 
 def aot_compile(jitted, *args, label: str = "step", use_cache: bool = True,

@@ -8,15 +8,18 @@ qualify (paddle_tpu/ops/pallas/flash_attention.py), else an XLA composition.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ...core.flags import get_flags
 from ...core.tensor import Tensor, apply
 
-__all__ = ["scaled_dot_product_attention", "seq_parallel_scope"]
+__all__ = ["scaled_dot_product_attention", "seq_parallel_scope",
+           "flash_mesh_scope"]
 
 # sequence-parallel routing context: when set (by the fleet strategy
 # compiler or user code), qualifying sdpa calls run ring/Ulysses attention
@@ -24,9 +27,27 @@ __all__ = ["scaled_dot_product_attention", "seq_parallel_scope"]
 _seq_parallel_ctx = [None]   # (mesh, axis, impl, batch_axis, head_axis)
 
 
-class seq_parallel_scope:
+class _RoutingScope:
+    """Context manager that publishes ``self._val`` in the one-element
+    list ``self._slot`` for the duration of the block (scopes nest)."""
+
+    _slot: list
+
+    def __enter__(self):
+        self._prev = self._slot[0]
+        self._slot[0] = self._val
+        return self
+
+    def __exit__(self, *exc):
+        self._slot[0] = self._prev
+        return False
+
+
+class seq_parallel_scope(_RoutingScope):
     """with seq_parallel_scope(mesh, "sp", impl="ring", batch_axis="dp"):
     attention inside routes through distributed.sequence_parallel."""
+
+    _slot = _seq_parallel_ctx
 
     def __init__(self, mesh, axis="sp", impl="ring", batch_axis=None,
                  head_axis=None):
@@ -38,14 +59,23 @@ class seq_parallel_scope:
                              f"'ulysses', got {impl!r}")
         self._val = (mesh, axis, impl, batch_axis, head_axis)
 
-    def __enter__(self):
-        self._prev = _seq_parallel_ctx[0]
-        _seq_parallel_ctx[0] = self._val
-        return self
 
-    def __exit__(self, *exc):
-        _seq_parallel_ctx[0] = self._prev
-        return False
+# mesh routing context for the flash kernel: GSPMD cannot partition a
+# Mosaic custom call ("Mosaic kernels cannot be automatically
+# partitioned"), so a step traced under a dp/tp mesh runs the kernel
+# shard_map-inner on its local [B/dp, T, H/tp, D] shard
+_flash_mesh_ctx = [None]     # (mesh, batch_axis, head_axis)
+
+
+class flash_mesh_scope(_RoutingScope):
+    """with flash_mesh_scope(mesh, batch_axis="dp", head_axis="tp"):
+    qualifying sdpa calls inside run the flash kernel per shard (set by
+    the fleet strategy compiler around its traced step)."""
+
+    _slot = _flash_mesh_ctx
+
+    def __init__(self, mesh, batch_axis=None, head_axis=None):
+        self._val = (mesh, batch_axis, head_axis)
 
 
 def _sdpa_xla(q, k, v, mask, dropout_p, causal, scale, key=None):
@@ -125,25 +155,27 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                       batch_axis=batch_axis, head_axis=head_axis)
             return apply(f, query, key, value, op_name="sp_attention")
 
+    # the kernel tiles the sequence in blocks of >= 128 rows: shapes it
+    # cannot tile take the XLA composition by rule; a call that IS routed
+    # here and then fails raises — it never quietly computes with XLA
     seq_len = query.shape[1]
     use_pallas = (get_flags("use_pallas_attention") and attn_mask is None
                   and dropout_p == 0.0
-                  and seq_len >= get_flags("pallas_attention_min_seq"))
+                  and seq_len >= get_flags("pallas_attention_min_seq")
+                  and seq_len % 128 == 0)
     if use_pallas:
-        try:
-            from ...ops.pallas.flash_attention import flash_attention
-            args = [query, key, value]
-            return apply(
-                lambda q, k, v: flash_attention(q, k, v, causal=is_causal,
-                                                scale=scale),
-                *args, op_name="flash_attention")
-        except (ValueError, ImportError) as e:
-            # expected fallbacks: seq len not divisible by the block size,
-            # or pallas unavailable in this build — surface the reason once
-            # so env-var block tuning mistakes don't silently benchmark XLA
-            import warnings
-            warnings.warn(f"flash_attention unavailable ({e}); falling back "
-                          f"to the XLA attention composition")
+        from ...ops.pallas.flash_attention import flash_attention
+        fn = functools.partial(flash_attention, causal=is_causal,
+                               scale=scale)
+        ctx = _flash_mesh_ctx[0]
+        if ctx is not None:
+            mesh, batch_axis, head_axis = ctx
+            if head_axis and query.shape[2] % int(mesh.shape[head_axis]):
+                head_axis = None       # uneven heads: replicate them
+            spec = P(batch_axis, None, head_axis, None)
+            fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                               out_specs=spec, check_vma=False)
+        return apply(fn, query, key, value, op_name="flash_attention")
 
     args = [query, key, value]
     if attn_mask is not None:

@@ -93,13 +93,17 @@ def _collect_hbm():
         _HBM_LIMIT.labels(device=dev).set(st.get("bytes_limit", 0))
 
 
-def install_default_collectors(registry: MetricsRegistry = REGISTRY):
+def install_default_collectors(registry: MetricsRegistry = REGISTRY,
+                               hbm: bool = True):
     """Register the uptime + per-device-HBM collectors (idempotent).
 
     Explicit rather than import-time because the HBM collector touches
     ``jax.devices()`` at scrape time — the serve daemon and bench opt
-    in; a unit test importing the registry does not pay backend init."""
+    in; a unit test importing the registry does not pay backend init.
+    ``hbm=False`` is for a process that must never initialise a backend
+    (the front router: its backends own the chips)."""
     global _collectors_installed
     registry.add_collector(_collect_uptime)
-    registry.add_collector(_collect_hbm)
+    if hbm:
+        registry.add_collector(_collect_hbm)
     _collectors_installed = True

@@ -4,13 +4,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu only resolves on TPU builds; interpret mode covers CPU tests
-    from jax.experimental.pallas import tpu as pltpu
-    VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    VMEM = None
+VMEM = pltpu.VMEM
 
 NEG_INF = np.float32(-1e30)
 LANE = 128      # TPU lane width: per-row scalars ride a broadcast lane dim
@@ -22,7 +18,28 @@ def on_tpu() -> bool:
 
 
 def interpret() -> bool:
-    return not on_tpu()
+    """Pallas interpret mode, for the CPU backend only (the test suite
+    runs the kernel bodies there). Any other backend that is not a TPU
+    cannot run these kernels at all: a selected kernel raises instead
+    of quietly computing something else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"paddle_tpu Pallas kernels are TPU (Mosaic) kernels; the default "
+        f"JAX backend is {backend!r}. Run on a TPU, or on CPU "
+        f"(JAX_PLATFORMS=cpu) for interpret mode.")
+
+
+def compiler_params(*dimension_semantics: str) -> dict:
+    """``pallas_call`` kwargs naming the grid's dimension semantics for
+    Mosaic; interpret mode takes none."""
+    if interpret():
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=dimension_semantics)}
 
 
 def mxu_dtype():

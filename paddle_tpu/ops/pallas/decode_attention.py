@@ -20,9 +20,9 @@ The paged trio (`paged_decode_attention[_reference]` and its Pallas
 kernel) attends the same math over a PAGED cache: a shared page pool
 plus per-sequence int32 block tables (inference/decode.py's paged
 engine). The Pallas variant walks the block table via scalar-prefetch
-index maps — one grid cell per (batch, head, page), online softmax in
-scratch — so only mapped pages are ever streamed into VMEM; the XLA
-fallback gathers pages with `jnp.take`.
+index maps — one grid cell per (batch, page), every head of the page,
+online softmax in scratch — so only mapped pages are ever streamed into
+VMEM; the XLA path gathers pages with `jnp.take`.
 
 Shapes (cap = KV-cache capacity rung, see inference/decode.py):
 
@@ -39,14 +39,11 @@ import math
 import jax
 import jax.numpy as jnp
 
+from jax.experimental import pallas as pl
+
 from ...core import flags as _flags
 from . import _common
 from ._common import NEG_INF, VMEM, I0 as _I0, pltpu
-
-try:
-    from jax.experimental import pallas as pl
-except Exception:  # pragma: no cover - pallas ships with jax
-    pl = None
 
 _ENV = "PADDLE_TPU_DECODE_KERNEL"
 
@@ -93,10 +90,6 @@ def _decode_attention_pallas(q, k, v, lengths):
     mask = jnp.where(live, 0.0, NEG_INF).astype(jnp.float32)
     mask3 = jnp.repeat(mask[:, None, :], H, axis=0).reshape(BH, 1, cap)
 
-    kw = {}
-    if pltpu is not None and not _common.interpret():
-        kw["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",))
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale),
         grid=(BH,),
@@ -114,7 +107,7 @@ def _decode_attention_pallas(q, k, v, lengths):
                                memory_space=VMEM),
         out_shape=jax.ShapeDtypeStruct((BH, 1, D), q.dtype),
         interpret=_common.interpret(),
-        **kw,
+        **_common.compiler_params("arbitrary"),
     )(q3, k3, v3, mask3)
     return out.reshape(B, H, D)
 
@@ -153,83 +146,85 @@ def paged_decode_attention_reference(q, k_pool, v_pool, tables, lengths):
     return decode_attention_reference(q, k, v, lengths)
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_s, l_s, acc_s, *, scale, pt):
-    """One grid cell per (batch, head, page-slot): walk the block table
-    along the last grid dim with online (flash-style) softmax carried in
-    SMEM/VMEM scratch, so only the pages a sequence actually maps stream
-    through VMEM — no gather materialization."""
-    b = pl.program_id(0)
-    w = pl.program_id(2)
-
+def _online_softmax_page(s, vp, w, pt, length, m_s, l_s, acc_s, o_ref):
+    """One page of the online (flash-style) softmax, all heads at once.
+    s [pt, H, 1] scores, vp [pt, H, D] values; the running max m_s and
+    denominator l_s are [H, 1], the accumulator acc_s [H, D]. Heads stay
+    on sublanes and D on lanes throughout, so no step relayouts."""
     @pl.when(w == 0)
     def _init():
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    qv = q_ref[0, 0]                         # [1, D]
-    kp = k_ref[0, :, 0, :]                   # [pt, D] one page, one head
-    vp = v_ref[0, :, 0, :]
-    s = jax.lax.dot_general(
-        qv, kp, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale        # [1, pt]
-    rows = w * pt + jax.lax.broadcasted_iota(jnp.int32, (1, pt), 1)
-    s = jnp.where(rows < len_ref[b], s, NEG_INF)
-    m_prev = m_s[0, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s))
+    rows = w * pt + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    s = jnp.where(rows < length, s, NEG_INF)
+    m_prev = m_s[...]                                      # [H, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
     corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                                 # [1, pt]
-    m_s[0, 0] = m_new
-    l_s[0, 0] = l_s[0, 0] * corr + jnp.sum(p)
-    acc_s[...] = acc_s[...] * corr + jax.lax.dot(
-        p.astype(vp.dtype), vp, preferred_element_type=jnp.float32)
+    p = jnp.exp(s - m_new[None])                           # [pt, H, 1]
+    m_s[...] = m_new
+    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=0)
+    acc_s[...] = acc_s[...] * corr + jnp.sum(p * vp, axis=0)
 
-    @pl.when(w == pl.num_programs(2) - 1)
+    @pl.when(w == pl.num_programs(1) - 1)
     def _emit():
-        o_ref[0, 0] = (acc_s[...] / l_s[0, 0]).astype(o_ref.dtype)
+        o_ref[0] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
+
+
+def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+                  m_s, l_s, acc_s, *, scale, pt):
+    """One grid cell per (batch, page-slot): walk the block table along
+    the last grid dim with the softmax state carried in VMEM scratch, so
+    only the pages a sequence actually maps stream through VMEM — no
+    gather materialization. A cell holds one whole page, every head of
+    it: Mosaic wants a block's two minor dims to be the array's own
+    (heads, head_dim) or multiples of (8, 128), and one head of one
+    page — (1, head_dim) — is neither."""
+    b = pl.program_id(0)
+    w = pl.program_id(1)
+    kp = k_ref[0].astype(jnp.float32)                      # [pt, H, D]
+    vp = v_ref[0].astype(jnp.float32)
+    qv = q_ref[0].astype(jnp.float32)                      # [H, D]
+    s = jnp.sum(qv[None] * kp, axis=-1, keepdims=True) * scale
+    _online_softmax_page(s, vp, w, pt, len_ref[b], m_s, l_s, acc_s, o_ref)
+
+
+def _paged_grid_spec(B, H, D, W, pt, page_specs):
+    """Grid (batch, page-slot) with (tables, lengths) scalar-prefetched:
+    their VALUES drive the K/V index_map, so each grid cell DMAs exactly
+    the page the block table names — the table walk happens in the
+    pipeline, not the body."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, W),
+        in_specs=[pl.BlockSpec((1, H, D),
+                               lambda b, w, tbl, ln: (b, _I0, _I0))]
+        + page_specs,
+        out_specs=pl.BlockSpec((1, H, D),
+                               lambda b, w, tbl, ln: (b, _I0, _I0)),
+        scratch_shapes=[
+            pltpu.VMEM((H, 1), jnp.float32),     # running max
+            pltpu.VMEM((H, 1), jnp.float32),     # running denominator
+            pltpu.VMEM((H, D), jnp.float32),     # output accumulator
+        ],
+    )
 
 
 def _paged_decode_attention_pallas(q, k_pool, v_pool, tables, lengths):
     B, H, D = q.shape
     P, pt, _, _ = k_pool.shape
     W = tables.shape[1]
-    scale = 1.0 / math.sqrt(D)
-    # scalar-prefetch carries (tables, lengths): their VALUES drive the
-    # K/V index_map, so each grid cell DMAs exactly the page the block
-    # table names — the table walk happens in the pipeline, not the body
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, H, W),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, D),
-                         lambda b, h, w, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, pt, 1, D),
-                         lambda b, h, w, tbl, ln: (tbl[b, w], 0, h, 0)),
-            pl.BlockSpec((1, pt, 1, D),
-                         lambda b, h, w, tbl, ln: (tbl[b, w], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, D),
-                               lambda b, h, w, tbl, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.SMEM((1, 1), jnp.float32),     # running max
-            pltpu.SMEM((1, 1), jnp.float32),     # running denominator
-            pltpu.VMEM((1, D), jnp.float32),     # output accumulator
-        ],
-    )
-    kw = {}
-    if not _common.interpret():
-        kw["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"))
-    out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale, pt=pt),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
+    page = pl.BlockSpec((1, pt, H, D),
+                        lambda b, w, tbl, ln: (tbl[b, w], _I0, _I0, _I0))
+    return pl.pallas_call(
+        functools.partial(_paged_kernel, scale=1.0 / math.sqrt(D), pt=pt),
+        grid_spec=_paged_grid_spec(B, H, D, W, pt, [page, page]),
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=_common.interpret(),
-        **kw,
+        **_common.compiler_params("arbitrary", "arbitrary"),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q.reshape(B, H, 1, D), k_pool, v_pool)
-    return out.reshape(B, H, D)
+      q, k_pool, v_pool)
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, lengths, kernel=None):
@@ -278,40 +273,16 @@ def paged_decode_attention_quant_reference(q, k_pool, k_scale,
 def _paged_quant_kernel(tbl_ref, len_ref, q_ref, k_ref, ks_ref,
                         v_ref, vs_ref, o_ref, m_s, l_s, acc_s,
                         *, scale, pt):
-    """`_paged_kernel` with int8 pages: the scale row rides its own
-    prefetched block and the page dequantizes in-register before the
-    score GEMV / accumulate."""
+    """`_paged_kernel` with int8 pages: each page's scale block
+    [pt, H, 1] rides its own prefetched block and the page dequantizes
+    in-register before the score / accumulate."""
     b = pl.program_id(0)
-    w = pl.program_id(2)
-
-    @pl.when(w == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-
-    qv = q_ref[0, 0]                                       # [1, D]
-    ks = ks_ref[0, 0]                                      # [pt]
-    vs = vs_ref[0, 0]
-    kp = k_ref[0, :, 0, :].astype(jnp.float32) * ks[:, None]   # [pt, D]
-    vp = v_ref[0, :, 0, :].astype(jnp.float32) * vs[:, None]
-    s = jax.lax.dot_general(
-        qv, kp, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale        # [1, pt]
-    rows = w * pt + jax.lax.broadcasted_iota(jnp.int32, (1, pt), 1)
-    s = jnp.where(rows < len_ref[b], s, NEG_INF)
-    m_prev = m_s[0, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                                 # [1, pt]
-    m_s[0, 0] = m_new
-    l_s[0, 0] = l_s[0, 0] * corr + jnp.sum(p)
-    acc_s[...] = acc_s[...] * corr + jax.lax.dot(
-        p, vp, preferred_element_type=jnp.float32)
-
-    @pl.when(w == pl.num_programs(2) - 1)
-    def _emit():
-        o_ref[0, 0] = (acc_s[...] / l_s[0, 0]).astype(o_ref.dtype)
+    w = pl.program_id(1)
+    kp = k_ref[0].astype(jnp.float32) * ks_ref[0]          # [pt, H, D]
+    vp = v_ref[0].astype(jnp.float32) * vs_ref[0]
+    qv = q_ref[0].astype(jnp.float32)                      # [H, D]
+    s = jnp.sum(qv[None] * kp, axis=-1, keepdims=True) * scale
+    _online_softmax_page(s, vp, w, pt, len_ref[b], m_s, l_s, acc_s, o_ref)
 
 
 def _paged_decode_attention_quant_pallas(q, k_pool, k_scale,
@@ -320,47 +291,22 @@ def _paged_decode_attention_quant_pallas(q, k_pool, k_scale,
     B, H, D = q.shape
     P, pt, _, _ = k_pool.shape
     W = tables.shape[1]
-    scale = 1.0 / math.sqrt(D)
-    # scales land lane-major ([P, H, pt]) so each grid cell's scale row
-    # is one contiguous [1, 1, pt] block next to its int8 page
-    ks = jnp.transpose(k_scale, (0, 2, 1))
-    vs = jnp.transpose(v_scale, (0, 2, 1))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, H, W),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, D),
-                         lambda b, h, w, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, pt, 1, D),
-                         lambda b, h, w, tbl, ln: (tbl[b, w], 0, h, 0)),
-            pl.BlockSpec((1, 1, pt),
-                         lambda b, h, w, tbl, ln: (tbl[b, w], h, 0)),
-            pl.BlockSpec((1, pt, 1, D),
-                         lambda b, h, w, tbl, ln: (tbl[b, w], 0, h, 0)),
-            pl.BlockSpec((1, 1, pt),
-                         lambda b, h, w, tbl, ln: (tbl[b, w], h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, D),
-                               lambda b, h, w, tbl, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.SMEM((1, 1), jnp.float32),     # running max
-            pltpu.SMEM((1, 1), jnp.float32),     # running denominator
-            pltpu.VMEM((1, D), jnp.float32),     # output accumulator
-        ],
-    )
-    kw = {}
-    if not _common.interpret():
-        kw["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"))
-    out = pl.pallas_call(
-        functools.partial(_paged_quant_kernel, scale=scale, pt=pt),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
+    page = pl.BlockSpec((1, pt, H, D),
+                        lambda b, w, tbl, ln: (tbl[b, w], _I0, _I0, _I0))
+    # scales ride as [P, pt, H, 1]: heads on sublanes like the page rows
+    # they multiply, so the in-kernel broadcast over D is a lane splat
+    srow = pl.BlockSpec((1, pt, H, 1),
+                        lambda b, w, tbl, ln: (tbl[b, w], _I0, _I0, _I0))
+    return pl.pallas_call(
+        functools.partial(_paged_quant_kernel, scale=1.0 / math.sqrt(D),
+                          pt=pt),
+        grid_spec=_paged_grid_spec(B, H, D, W, pt,
+                                   [page, srow, page, srow]),
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=_common.interpret(),
-        **kw,
+        **_common.compiler_params("arbitrary", "arbitrary"),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q.reshape(B, H, 1, D), k_pool, ks, v_pool, vs)
-    return out.reshape(B, H, D)
+      q, k_pool, k_scale[..., None], v_pool, v_scale[..., None])
 
 
 def paged_decode_attention_quant(q, k_pool, k_scale, v_pool, v_scale,

@@ -39,7 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from ._common import (pltpu, VMEM as _VMEM, interpret as _interpret,
+from . import _common
+from ._common import (pltpu, VMEM as _VMEM, compiler_params as _compiler_params,
                       mxu_dtype as _mxu_dtype, NEG_INF, LANE, I0 as _I0)
 
 
@@ -68,12 +69,9 @@ def _block_sizes(T, D, env_key="PT_FLASH_FWD_BLOCKS"):
     (measured 8.5 ms/layer fwd+bwd vs 3.9 ms at (512,1024) on v5e). The env
     keys PT_FLASH_{FWD,BWD}_BLOCKS are perf-tuning escape hatches.
 
-    (1024, 1024) caps are the long-context sweep's optimum on v5e:
-    every T in {1024..16384} lands >= 46% MFU vs the 42.5-44.5% tail the
-    old (512, 1024) caps left at T >= 4096 (numbers + methodology:
-    benchmarks/RESULTS.md long-context table; reproduce with
-    benchmarks/longctx.py). 2048-wide blocks exceed VMEM at D=64 (the
-    f32 score tile alone is 16 MB)."""
+    (1024, 1024) caps were the long-context sweep's optimum on v5e when
+    last swept (reproduce with benchmarks/longctx.py). 2048-wide blocks
+    exceed VMEM at D=64 (the f32 score tile alone is 16 MB)."""
     if env_key in os.environ:
         return _env_blocks(env_key, T)
     return _pick_block(T, 1024), _pick_block(T, 1024)
@@ -88,7 +86,7 @@ def _bwd_block_sizes(T, D):
     the dk/dv f32 scratch (2*bk*D*4 B). At (1024, 1024):
       D=64 : 12 MB + 1.9 MB + 0.5 MB ~= 14.4 MB -> fits 16 MB VMEM
              (exercised fwd+bwd by the benchmarks/longctx.py training
-             sweep at T=1k..16k, D=64 — the RESULTS.md numbers)
+             sweep at T=1k..16k, D=64)
       D=128: 12 MB + 3.5 MB + 1.0 MB ~= 16.5 MB -> over budget, so wide
              heads cap bq at 512, halving the score tiles to 2 MB each
              (~9.75 MB total) with the same nk==1 fused-path eligibility
@@ -158,10 +156,6 @@ def _fwd(q3, k3, v3, scale, causal):
     nq, nk = T // bq, T // bk
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              block_q=bq, block_k=bk, nk=nk, mxu=_mxu_dtype())
-    kwargs = {}
-    if pltpu is not None and not _interpret():
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     o, lse = pl.pallas_call(
         kern,
         grid=(BH, nq, nk),
@@ -187,9 +181,9 @@ def _fwd(q3, k3, v3, scale, causal):
             pltpu.VMEM((bq, LANE), jnp.float32),
             pltpu.VMEM((bq, LANE), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
-        ] if pltpu is not None else [],
-        interpret=_interpret(),
-        **kwargs,
+        ],
+        interpret=_common.interpret(),
+        **_compiler_params("parallel", "parallel", "arbitrary"),
     )(q3, k3, v3)
     return o, lse
 
@@ -325,10 +319,7 @@ def _bwd(scale, causal, res, g):
                     axis=-1)
     delta = jnp.broadcast_to(delta[..., None], (BH, T, LANE))
 
-    kwargs = {}
-    if pltpu is not None and not _interpret():
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    kwargs = _compiler_params("parallel", "parallel", "arbitrary")
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -351,9 +342,8 @@ def _bwd(scale, causal, res, g):
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, _I0),
                                memory_space=_VMEM),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q3.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)]
-        if pltpu is not None else [],
-        interpret=_interpret(),
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        interpret=_common.interpret(),
         **kwargs,
     )(q3, k3, v3, do3, lse, delta)
 
@@ -388,8 +378,8 @@ def _bwd(scale, causal, res, g):
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
-        ] if pltpu is not None else [],
-        interpret=_interpret(),
+        ],
+        interpret=_common.interpret(),
         **kwargs,
     )(q3, k3, v3, do3, lse, o3)
     return dq, dk, dv
@@ -460,11 +450,6 @@ def _bwd_fused(scale, causal, res, g):
     assert nk == 1, "fused backward requires a single k sweep"
     do3 = g      # delta is computed in-kernel from (do, o) blocks
 
-    kwargs = {}
-    if pltpu is not None and not _interpret():
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"))
-
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, nq=nq, mxu=_mxu_dtype(),
@@ -500,8 +485,8 @@ def _bwd_fused(scale, causal, res, g):
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
-        ] if pltpu is not None else [],
-        interpret=_interpret(),
-        **kwargs,
+        ],
+        interpret=_common.interpret(),
+        **_compiler_params("parallel", "arbitrary", "arbitrary"),
     )(q3, k3, v3, do3, lse, o3)
     return dq, dk, dv

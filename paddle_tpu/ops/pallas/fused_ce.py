@@ -34,7 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from ._common import (pltpu, VMEM as _VMEM, on_tpu as _on_tpu,
+from . import _common
+from ._common import (pltpu, VMEM as _VMEM, compiler_params as _compiler_params,
                       mxu_dtype as _mxu_dtype, NEG_INF, LANE, I0 as _I0)
 
 
@@ -108,10 +109,6 @@ def _fwd_pallas(x, w, labels, V):
     lbl2 = labels.astype(jnp.int32).reshape(N, 1)
     kern = functools.partial(_fwd_kernel, bn=bn, bv=bv, nv=nv, V=V,
                              mxu=_mxu_dtype())
-    kwargs = {}
-    if pltpu is not None and _on_tpu():
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
     lse, lab = pl.pallas_call(
         kern,
         grid=(nn, nv),
@@ -134,9 +131,9 @@ def _fwd_pallas(x, w, labels, V):
             pltpu.VMEM((bn, LANE), jnp.float32),
             pltpu.VMEM((bn, LANE), jnp.float32),
             pltpu.VMEM((bn, 1), jnp.float32),
-        ] if pltpu is not None else [],
-        interpret=not _on_tpu(),
-        **kwargs,
+        ],
+        interpret=_common.interpret(),
+        **_compiler_params("parallel", "arbitrary"),
     )(x, w, lbl2)
     return lse[:, 0], lab[:, 0]
 
@@ -212,10 +209,7 @@ def _bwd_pallas(x, w, labels, lse, g, V):
     lse2 = jnp.broadcast_to(lse[:, None], (N, LANE))
     g2 = jnp.broadcast_to(g.astype(jnp.float32)[:, None], (N, LANE))
     mxu = _mxu_dtype()
-    kwargs = {}
-    if pltpu is not None and _on_tpu():
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+    kwargs = _compiler_params("parallel", "arbitrary")
 
     dx = pl.pallas_call(
         functools.partial(_bwd_dx_kernel, bn=bn, bv=bv, nv=nv, V=V, mxu=mxu),
@@ -232,9 +226,8 @@ def _bwd_pallas(x, w, labels, lse, g, V):
         out_specs=pl.BlockSpec((bn, H), lambda i, j: (i, _I0),
                                memory_space=_VMEM),
         out_shape=jax.ShapeDtypeStruct((N, H), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bn, H), jnp.float32)]
-        if pltpu is not None else [],
-        interpret=not _on_tpu(),
+        scratch_shapes=[pltpu.VMEM((bn, H), jnp.float32)],
+        interpret=_common.interpret(),
         **kwargs,
     )(x, w, lbl2, lse2, g2)
 
@@ -253,9 +246,8 @@ def _bwd_pallas(x, w, labels, lse, g, V):
         out_specs=pl.BlockSpec((bv, H), lambda i, j: (i, _I0),
                                memory_space=_VMEM),
         out_shape=jax.ShapeDtypeStruct((Vp, H), w.dtype),
-        scratch_shapes=[pltpu.VMEM((bv, H), jnp.float32)]
-        if pltpu is not None else [],
-        interpret=not _on_tpu(),
+        scratch_shapes=[pltpu.VMEM((bv, H), jnp.float32)],
+        interpret=_common.interpret(),
         **kwargs,
     )(x, w, lbl2, lse2, g2)
     return dx, dw
@@ -308,8 +300,8 @@ def _pad_vocab(w, bv=1024):
     return w
 
 
-def _pallas_ok(N, H):
-    return _on_tpu() and N % 128 == 0 and H % 128 == 0
+def _tileable(N, H):
+    return N % 128 == 0 and H % 128 == 0
 
 
 @jax.custom_vjp
@@ -365,12 +357,15 @@ def linear_cross_entropy(x, w, labels, fused=None):
     fused=None picks the Pallas kernel on TPU when the logits matrix is
     large enough that avoiding its HBM materialisation beats the recompute
     matmuls (measured crossover ~V=64k at H<=1024 on v5e); True forces the
-    kernel (shapes permitting), False forces the XLA path.
+    kernel (interpret mode on the CPU backend) and raises when the shapes
+    cannot be tiled, False forces the XLA path.
     """
     N, H = x.shape
     V = w.shape[0]
     if fused is None:
-        fused = _pallas_ok(N, H) and V >= 65536
-    elif fused:
-        fused = _pallas_ok(N, H)
+        fused = _common.on_tpu() and _tileable(N, H) and V >= 65536
+    elif fused and not _tileable(N, H):
+        raise ValueError(
+            f"linear_cross_entropy(fused=True): rows {N} and hidden {H} "
+            f"must both be multiples of 128 for the Pallas kernel")
     return _lce_pallas(x, w, labels) if fused else _lce_xla(x, w, labels)

@@ -6,7 +6,7 @@ data_format="NHWC" (channels-last), the layout the TPU's convolution
 tiling natively prefers — XLA:TPU re-lays out NCHW operands internally,
 so the gap is small on big batches, but NHWC skips those relayout copies
 and is the recommended layout for input pipelines that can produce it
-(benchmarks/RESULTS.md config-2 notes carry the measured comparison)."""
+(the size of the gap: not measured on the current installation)."""
 from __future__ import annotations
 
 from ... import nn

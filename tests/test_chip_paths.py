@@ -1,0 +1,229 @@
+"""Nothing on the main paths hides the device (PR 21 bring-up).
+
+What a CPU can check of it: a failed accelerator is a non-zero exit, not
+a CPU re-run; a selected kernel that cannot run raises; the decode
+engine's HBM-derived slot sizing follows the compiled step; one process
+per chip — the router parent stays off JAX and pins its children; and
+chip_smoke.py refuses to "smoke on CPU".
+"""
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+
+import paddle_tpu as paddle
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(cmd, timeout=300, **env):
+    return subprocess.run(
+        cmd, capture_output=True, text=True, timeout=timeout, cwd=REPO,
+        env=dict(os.environ, PYTHONPATH=REPO, **env))
+
+
+# -- a failed accelerator is a traceback and a non-zero exit ----------------
+
+@pytest.mark.parametrize("script", [
+    ["bench.py"], ["benchmarks/serve_bench.py", "--requests", "4"]])
+def test_bench_backend_failure_exits_nonzero(script):
+    """A backend that cannot initialise raises out of the benchmark: no
+    JSON line, no rc 0, no second life on the CPU."""
+    p = _run([sys.executable] + script, JAX_PLATFORMS="no_such_backend")
+    assert p.returncode != 0
+    assert "no_such_backend" in p.stderr
+    assert not p.stdout.strip(), p.stdout
+    assert "retrying on CPU" not in p.stderr
+
+
+def test_chip_smoke_refuses_cpu_and_parent_stays_off_jax():
+    code = ("import sys, chip_smoke\n"
+            "rc = chip_smoke.main([])\n"
+            "from jax._src import xla_bridge\n"
+            "print('PARENT_BACKEND', xla_bridge.backends_are_initialized())\n"
+            "sys.exit(rc)\n")
+    p = _run([sys.executable, "-c", code], JAX_PLATFORMS="cpu")
+    assert p.returncode != 0
+    assert "no TPU" in p.stderr
+    assert "PARENT_BACKEND False" in p.stdout
+    assert '"ok"' not in p.stdout
+
+
+# -- a selected kernel that cannot run is an error ---------------------------
+
+def test_interpret_mode_is_for_the_cpu_backend_only(monkeypatch):
+    from paddle_tpu.ops.pallas import _common
+    assert _common.interpret() is True                 # this suite: CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _common.interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="TPU .Mosaic. kernels"):
+        _common.interpret()
+
+
+def test_routed_flash_kernel_failure_raises(monkeypatch):
+    """sdpa used to catch the kernel's ValueError, warn and compute with
+    XLA; a call routed to the kernel now fails loudly."""
+    import paddle_tpu.nn.functional as F
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def boom(*a, **k):
+        raise ValueError("bad block override")
+
+    monkeypatch.setattr(fa, "flash_attention", boom)
+    x = paddle.to_tensor(np.zeros((1, 512, 1, 32), np.float32))
+    with pytest.raises(ValueError, match="bad block override"):
+        F.scaled_dot_product_attention(x, x, x, is_causal=True)
+    # a length the kernel cannot tile is not routed to it at all
+    y = paddle.to_tensor(np.zeros((1, 520, 1, 32), np.float32))
+    assert F.scaled_dot_product_attention(
+        y, y, y, is_causal=True).shape == [1, 520, 1, 32]
+
+
+def test_fleet_step_aot_failure_raises(monkeypatch):
+    """CompiledTrainStep used to swallow a failed AOT compile and keep
+    the implicit jit path."""
+    import paddle_tpu.optimizer as opt
+    from paddle_tpu.distributed.fleet import DistributedStrategy
+    from paddle_tpu.distributed.fleet.compiler import compile_train_step
+    from paddle_tpu.jit import compile_cache
+    from paddle_tpu.models import GPT, gpt_tiny
+
+    paddle.seed(0)
+    m = GPT(gpt_tiny())
+    s = DistributedStrategy()
+    prog = compile_train_step(
+        m, opt.Adam(learning_rate=1e-3, parameters=list(m.parameters())),
+        s, loss_method="loss", mesh=s.build_mesh(devices=jax.devices()[:1]))
+
+    def boom(*a, **k):
+        raise RuntimeError("compile exploded")
+
+    monkeypatch.setattr(compile_cache, "aot_compile", boom)
+    ids = np.zeros((2, 16), np.int32)
+    with pytest.raises(RuntimeError, match="compile exploded"):
+        prog.step(ids, ids, lr=1e-3)
+
+
+# -- slot sizing follows the compiled step ----------------------------------
+
+def _linear_step(fixed, per_slot, device_limit):
+    calls = []
+
+    def step_bytes(n):
+        calls.append(n)
+        need = fixed + per_slot * n
+        return None if need > device_limit else need
+
+    return step_bytes, calls
+
+
+def test_fit_slot_count_converges_on_the_budget():
+    from paddle_tpu.inference.decode import fit_slot_count
+    GB = 10 ** 9
+    # GPT-2 124M on a v5e, roughly: 0.5 GB of weights, 0.4 GB a slot
+    f, calls = _linear_step(GB // 2, 4 * GB // 10, 16 * GB)
+    n = fit_slot_count(f, 8 * GB, upper=100)
+    assert GB // 2 + 4 * GB // 10 * n <= 8 * GB
+    assert n >= 17 and len(calls) <= 4
+    # the logical-bytes upper bound caps it
+    f, calls = _linear_step(GB // 10, GB // 100, 16 * GB)
+    assert fit_slot_count(f, 8 * GB, upper=5) == 5 and calls == [5]
+    # the compiler runs out of HBM at the first sizes tried: halve
+    f, calls = _linear_step(5 * GB, 4 * GB, 16 * GB)
+    assert fit_slot_count(f, 10 * GB, upper=6) == 1
+    # one slot compiles but is over the budget: one slot is the floor
+    f, _ = _linear_step(GB, 8 * GB, 16 * GB)
+    assert fit_slot_count(f, 4 * GB, upper=50) == 1
+    # not even one slot compiles
+    f, _ = _linear_step(5 * GB, 12 * GB, 16 * GB)
+    with pytest.raises(RuntimeError, match="does not fit"):
+        fit_slot_count(f, 10 * GB, upper=6)
+
+
+def test_hbm_derived_slot_count_uses_compiled_footprint(monkeypatch):
+    """The HBM-derived path (never taken on CPU before: no memory stats
+    means the 8-slot fallback) driven with made-up stats: the engine
+    sizes itself so the largest compiled step fits the budget."""
+    from paddle_tpu import profiler
+    from paddle_tpu.core import monitor
+    from paddle_tpu.inference import decode
+    from paddle_tpu.models import GPT, gpt_tiny
+
+    paddle.seed(0)
+    model = GPT(gpt_tiny())
+    used, limit = 1 << 20, 48 << 20
+    monkeypatch.setattr(monitor, "hbm_usage", lambda device=None:
+                        (used, limit))
+    profiler.reset_compile_events()
+    eng = decode.DecodeEngine(model, page_tokens=8)
+    try:
+        sized = [e["label"] for e in profiler.compile_events()
+                 if e["label"].startswith("decode.sizing:")]
+        assert sized and sized[-1] == f"decode.sizing:{eng.max_slots}"
+        logical = (limit - used) // decode.kv_slot_bytes(eng.cfg)
+        assert 1 <= eng.max_slots < logical      # padding + temporaries
+    finally:
+        eng.stop()
+    # and a backend failure on that path surfaces instead of reading as
+    # "CPU, 8 slots"
+    def boom(device=None):
+        raise RuntimeError("backend exploded")
+
+    monkeypatch.setattr(monitor, "hbm_usage", boom)
+    with pytest.raises(RuntimeError, match="backend exploded"):
+        decode.DecodeEngine(model, page_tokens=8)
+
+
+# -- one process per chip ---------------------------------------------------
+
+def test_fleet_backends_are_pinned_one_per_chip(monkeypatch):
+    from paddle_tpu.core import place
+    from paddle_tpu.inference import router
+
+    assert place.local_tpu_chip_count({"JAX_PLATFORMS": "cpu"}) == 0
+    assert place.single_chip_env(2)["TPU_VISIBLE_CHIPS"] == "2"
+
+    monkeypatch.setattr(router, "local_tpu_chip_count", lambda env: 2)
+    with pytest.raises(ValueError, match="2 TPU chip"):
+        router.BackendSupervisor("prefix", 3, router=None, env={})
+
+    spawned = []
+
+    class FakePopen:
+        pid, stdout = 0, []
+
+        def __init__(self, cmd, env=None, **kw):
+            spawned.append(env)
+
+    monkeypatch.setattr(router.subprocess, "Popen", FakePopen)
+    sup = router.BackendSupervisor("prefix", 2, router=None,
+                                   env={"KEEP": "1"})
+    for slot in range(2):
+        sup._spawn(slot)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in spawned] == ["0", "1"]
+    assert all(e["TPU_PROCESS_BOUNDS"] == "1,1,1" and e["KEEP"] == "1"
+               for e in spawned)
+
+
+def test_router_process_never_initialises_a_backend():
+    """The router owns no device: serving its admin plane (collectors run
+    on every scrape) must not call jax.devices() — on a TPU host that
+    would take the chips its backends need."""
+    code = (
+        "import urllib.request\n"
+        "from paddle_tpu.inference.router import ServeRouter\n"
+        "r = ServeRouter([], port=0, metrics_port=0)\n"
+        "for path in ('/metrics', '/statusz'):\n"
+        "    urllib.request.urlopen(\n"
+        "        f'http://127.0.0.1:{r.metrics_port}{path}').read()\n"
+        "r.stop()\n"
+        "from jax._src import xla_bridge\n"
+        "print('BACKEND', xla_bridge.backends_are_initialized())\n")
+    p = _run([sys.executable, "-c", code], JAX_PLATFORMS="cpu")
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert "BACKEND False" in p.stdout

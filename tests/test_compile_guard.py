@@ -3,6 +3,7 @@ XLA-cache hit/miss detection across two Model.prepare cycles, the retrace
 guard (one structured warning on a mid-fit batch-shape change;
 PADDLE_TPU_RETRACE=error escalates), and the fleet mesh fail-fast
 warning."""
+import os
 import warnings
 
 import numpy as np
@@ -31,9 +32,25 @@ def _model(optimizer_cls=opt.Adam):
     return m
 
 
-def test_cache_miss_then_hit_across_prepares(tmp_path, monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE", str(tmp_path))
-    compile_cache._configured[0] = None      # force re-wire to the tmpdir
+@pytest.fixture
+def cache_config():
+    """Set jax's persistent-cache config for one test (the caller — not
+    the framework — chooses the directory) and restore it afterwards."""
+    import jax
+    keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache")
+    saved = {k: getattr(jax.config, k) for k in keys}
+
+    def set_(**kw):
+        for k, v in kw.items():
+            jax.config.update(k, v)
+        compile_cache._reset_jax_cache()
+
+    yield set_
+    set_(**saved)
+
+
+def test_cache_miss_then_hit_across_prepares(tmp_path, cache_config):
+    cache_config(jax_compilation_cache_dir=str(tmp_path))
     m1 = _model()
     m1.train_batch([X[:16]], [Y[:16]])
     assert m1._compile_stats["cache"] == "miss"
@@ -50,13 +67,58 @@ def test_cache_miss_then_hit_across_prepares(tmp_path, monkeypatch):
     assert "hapi.train_step" in labels
 
 
-def test_cache_disabled_via_env(tmp_path, monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE", "off")
-    compile_cache._configured[0] = None
+def test_cache_disabled_by_jax_switch(cache_config):
+    """JAX_ENABLE_COMPILATION_CACHE=false (jax's own switch) is the one
+    way to turn the cache off; the verdict then reads "off"."""
+    cache_config(jax_enable_compilation_cache=False)
     assert compile_cache.cache_dir() is None
     m = _model()
     m.train_batch([X[:16]], [Y[:16]])
     assert m._compile_stats["cache"] == "off"
+
+
+def test_cache_placement_is_jaxs_or_the_checkout(tmp_path, cache_config,
+                                                 monkeypatch):
+    """A directory jax already has (JAX_COMPILATION_CACHE_DIR, which jax
+    reads into its config at import, or a caller's config.update) is
+    never replaced in code; with none, the cache goes to the one fixed
+    in-checkout path — never a temp/pid/time-derived one, never $HOME."""
+    import jax
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.REPO_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+    monkeypatch.setattr(compile_cache, "_wired", [False])
+    cache_config(jax_compilation_cache_dir=str(tmp_path))     # "env set"
+    assert compile_cache.setup_compilation_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+    monkeypatch.setattr(compile_cache, "_wired", [False])
+    cache_config(jax_compilation_cache_dir=None)              # "unset"
+    assert compile_cache.setup_compilation_cache() == \
+        compile_cache.REPO_CACHE_DIR
+
+
+def test_fleet_children_inherit_the_cache_env(monkeypatch):
+    """The router's backend supervisor hands its children the parent's
+    environment untouched: JAX_COMPILATION_CACHE_DIR survives as set, no
+    per-fleet temp directory is made up, and the removed
+    PADDLE_TPU_COMPILE_CACHE is not reintroduced."""
+    import tempfile
+
+    from paddle_tpu.inference import router
+
+    def no_mkdtemp(*a, **k):
+        raise AssertionError("fleet cache must not be a temp directory")
+
+    monkeypatch.setattr(tempfile, "mkdtemp", no_mkdtemp)
+    for env in ({"JAX_PLATFORMS": "cpu",
+                 "JAX_COMPILATION_CACHE_DIR": "/some/shared/dir"},
+                {"JAX_PLATFORMS": "cpu"}):
+        sup = router.BackendSupervisor("prefix", 2, router=None, env=env)
+        assert sup._env == env
 
 
 def test_retrace_guard_warns_once_and_recompiles(monkeypatch):
@@ -156,9 +218,8 @@ def test_fleet_init_warns_on_mesh_failure():
         fleet.init(strategy=s)
 
 
-def test_strategy_path_records_compile(tmp_path, monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE", str(tmp_path))
-    compile_cache._configured[0] = None
+def test_strategy_path_records_compile(tmp_path, cache_config):
+    cache_config(jax_compilation_cache_dir=str(tmp_path))
     from paddle_tpu import profiler
     from paddle_tpu.distributed.fleet import DistributedStrategy
     profiler.reset_compile_events()

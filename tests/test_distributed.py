@@ -172,9 +172,10 @@ def test_compiled_step_dp_sharding_tp():
     l1 = float(np.asarray(jax.device_get(prog.step(ids, ids, lr=1e-3))))
     l2 = float(np.asarray(jax.device_get(prog.step(ids, ids, lr=1e-3))))
     assert np.isfinite(l1) and l2 < l1
-    # qkv weight is tp-sharded on its output dim
+    # qkv weight is tp-sharded on its output dim (scan-over-layers
+    # stacks block params on a leading [layers] axis)
     qkv = [k for k in prog.params if "qkv.weight" in k][0]
-    assert prog.params[qkv].sharding.spec == P(None, "tp")
+    assert prog.params[qkv].sharding.spec == P(None, None, "tp")
     # adam moment of a big replicated-in-tp param is ZeRO-sharded over dp
     wte = [k for k in prog.params if "wte.weight" in k][0]
     assert prog.opt_state[wte]["moment1"].sharding.spec[0] in ("tp", "dp")
@@ -812,7 +813,7 @@ def test_compiled_step_tp_x_sp_hybrid():
            for _ in range(3)]
     np.testing.assert_allclose(seq, hyb, atol=3e-4)
     qkv = [k for k in prog2.params if "qkv.weight" in k][0]
-    assert prog2.params[qkv].sharding.spec == P(None, "tp")
+    assert prog2.params[qkv].sharding.spec == P(None, None, "tp")
 
 
 def test_sp_uneven_heads_fall_back_to_replicated():
@@ -1610,3 +1611,62 @@ def test_pipeline_sp_ep_matches_sequential():
     pse = [float(jax.device_get(p2.step(ids, labels, lr=1e-3)))
            for _ in range(3)]
     np.testing.assert_allclose(seq, pse, rtol=1e-3, atol=1e-2)
+
+
+def _eqns_with_shard_map_depth(jaxpr, depth=0):
+    """Yield (primitive name, number of enclosing shard_maps) for every
+    equation of a jaxpr, descending into sub-jaxprs (scan bodies,
+    custom_vjp calls, shard_map bodies...)."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, depth
+        inner = depth + (eqn.primitive.name == "shard_map")
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns_with_shard_map_depth(sub, inner)
+
+
+def test_flash_kernel_runs_shard_map_inner_under_dp_tp_mesh():
+    """GSPMD cannot partition a Mosaic custom call ("Mosaic kernels
+    cannot be automatically partitioned"), so a dp x tp step at a
+    sequence length that routes to the flash kernel (T=512, the routing
+    threshold) must wrap it in a shard_map. Interpret mode lowers to
+    plain HLO and partitions by itself on CPU, so only the structure can
+    fail here: every pallas_call of the traced step sits inside a
+    shard_map — and the loss matches the XLA-attention step."""
+    from paddle_tpu.models import GPT, GPTConfig
+
+    T = 512
+    ids = np.random.default_rng(0).integers(0, 256, (4, T)).astype(np.int32)
+
+    def build():
+        paddle.seed(0)
+        m = GPT(GPTConfig(vocab_size=256, max_seq_len=T, hidden=32,
+                          layers=1, heads=2))
+        m.eval()
+        s = DistributedStrategy()
+        s.tensor_parallel = True
+        s.hybrid_configs.mp_degree = 2
+        s.hybrid_configs.dp_degree = 2
+        mesh = s.build_mesh(devices=jax.devices()[:4])
+        adam = opt.Adam(learning_rate=1e-3, parameters=list(m.parameters()))
+        return compile_train_step(m, adam, s, loss_method="loss", mesh=mesh)
+
+    prog = build()
+    args = (prog.params, prog.state, prog.opt_state, jax.random.key(0),
+            jnp.float32(1e-3), (jnp.asarray(ids), jnp.asarray(ids)))
+    found = [d for name, d in _eqns_with_shard_map_depth(
+        jax.make_jaxpr(prog._step)(*args).jaxpr) if name == "pallas_call"]
+    assert found, "T=512 did not route to the flash kernel"
+    assert all(d >= 1 for d in found), \
+        f"pallas_call outside a shard_map (depths {found})"
+    flash_loss = float(jax.device_get(prog.step(ids, ids, lr=1e-3)))
+
+    old = paddle.get_flags("use_pallas_attention")
+    paddle.set_flags({"use_pallas_attention": False})
+    try:
+        xla_loss = float(jax.device_get(build().step(ids, ids, lr=1e-3)))
+    finally:
+        paddle.set_flags({"use_pallas_attention": old})
+    np.testing.assert_allclose(flash_loss, xla_loss, rtol=2e-3)

@@ -268,16 +268,19 @@ def test_stat_shims_registry_backed():
     monitor.stat_reset()
 
 
-def test_device_memory_stats_never_raise(monkeypatch):
+def test_device_memory_stats_surface_backend_failure(monkeypatch):
+    """A backend that cannot initialise raises (it must not read as a
+    CPU with no stats); a device that cannot report stays empty."""
     import jax
 
     def boom():
         raise RuntimeError("backend exploded")
 
     monkeypatch.setattr(jax, "devices", boom)
-    assert monitor.device_memory_stats() == {}
-    assert monitor.all_device_memory_stats() == {}
-    assert monitor.hbm_usage() == (0, 0)
+    for probe in (monitor.device_memory_stats,
+                  monitor.all_device_memory_stats, monitor.hbm_usage):
+        with pytest.raises(RuntimeError, match="backend exploded"):
+            probe()
 
     class BadDevice:
         def memory_stats(self):

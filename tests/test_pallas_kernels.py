@@ -187,11 +187,16 @@ def test_flash_env_blocks_must_divide():
         del os.environ["PT_FLASH_FWD_BLOCKS"]
 
 
-def test_linear_cross_entropy_pallas_kernels_interpret(monkeypatch):
-    """Force the Pallas path (interpret mode on CPU) to cover the actual
-    kernels incl. vocab padding, not just the XLA fallback."""
+def test_linear_cross_entropy_pallas_kernels_interpret():
+    """fused=True runs the Pallas path (interpret mode on CPU): covers the
+    actual kernels incl. vocab padding, not just the XLA composition —
+    and shapes the kernel cannot tile are an error, not a quiet XLA run."""
     from paddle_tpu.ops.pallas import fused_ce
-    monkeypatch.setattr(fused_ce, "_pallas_ok", lambda N, H: True)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        fused_ce.linear_cross_entropy(jnp.zeros((100, 128)),
+                                      jnp.zeros((384, 128)),
+                                      jnp.zeros((100,), jnp.int32),
+                                      fused=True)
     rng = np.random.default_rng(3)
     N, H, V = 128, 128, 700    # pads to 1024 internally
     x = jnp.asarray(rng.normal(size=(N, H)) * 0.1, jnp.float32)
