@@ -1289,14 +1289,30 @@ class DecodeEngine:
 
     def _loop(self):
         while True:
-            newly, victims = [], []
+            with _RING.span("decode.loop", {"admits": 0, "active": 0}) as it:
+                if not self._loop_once(it.args):
+                    it.drop()            # the stop is no iteration
+                    return
+
+    def _loop_once(self, counts: dict) -> bool:
+        """One scheduler iteration: wait for work, schedule, admit, step.
+        False once the engine is stopped. `counts` is the `decode.loop`
+        span's args. Every phase is a ring span on this thread
+        (docs/observability.md lists them), so an iteration is tiled:
+        what no span covers is the loop's own overhead."""
+        newly, victims = [], []
+        with _RING.span("decode.schedule", {}) as sched:
             with self._cond:
                 while (not self._stop and not self._pending
                        and not self._paused and not self._active
                        and not self._migrating and not self._handoff_q):
-                    self._cond.wait(timeout=0.1)
+                    with _RING.span("decode.idle"):
+                        self._cond.wait(timeout=0.1)
                 if self._stop:
-                    return
+                    sched.drop()
+                    return False
+                sched.args["pending"] = len(self._pending)
+                sched.args["paused"] = len(self._paused)
                 self._refill_quota()
                 newly, victims = self._schedule()
                 self._admitting = list(newly) + list(victims)
@@ -1305,52 +1321,55 @@ class DecodeEngine:
                     # everything queued is quota-blocked (or parked on
                     # an in-flight refetch): wait for the bucket refill
                     # / migration wake instead of spinning
-                    self._cond.wait(timeout=0.02)
-            try:
-                if self._handoff_q:
-                    self._handoff_drain()
-                if self._migrating:
-                    self._tier_poll()
-                for vic in victims:
-                    self._preempt(vic)
-                for req in newly:
-                    if len(self._active) >= self.max_slots:
-                        # a preemption was abandoned (chaos) and its
-                        # candidate has no slot: requeue at the front
-                        with self._cond:
-                            if req.preempts:
-                                self._paused.appendleft(req)
-                            else:
-                                self._pending.appendleft(req)
-                        continue
-                    t_adm = time.perf_counter()
-                    admitted = self._admit(req)
-                    _RING.complete("decode.admit", t_adm,
-                                   time.perf_counter(), {"req": req.id})
-                    if admitted:
-                        self._active.append(req)
-                        self._m["tenant_admissions"].labels(
-                            tenant=req.tenant).inc()
-                        if req.preempts:
-                            self._m["preempt_resumes"].inc()
-                if self._admitting:
+                    with _RING.span("decode.idle"):
+                        self._cond.wait(timeout=0.02)
+        try:
+            if self._handoff_q:
+                self._handoff_drain()
+            if self._migrating:
+                self._tier_poll()
+            for vic in victims:
+                self._preempt(vic)
+            for req in newly:
+                if len(self._active) >= self.max_slots:
+                    # a preemption was abandoned (chaos) and its
+                    # candidate has no slot: requeue at the front
                     with self._cond:
-                        self._admitting = []
-                if newly or victims:
-                    self._update_gauges()
-                if self._active:
-                    self._step_once()
-            except Exception as exc:  # engine-level failure: fail the
-                # batch (typed), free its pages, keep serving newcomers
-                err = exc if isinstance(exc, TypedServeError) else \
-                    TypedServeError(ERR_UNAVAILABLE,
-                                    f"decode scheduler failure: {exc}")
-                for req in self._active:
-                    req.stream._push_error(err)
-                    self._m["evictions"].labels(reason="error").inc()
-                    self._release_pages(req)
-                self._active = []
+                        if req.preempts:
+                            self._paused.appendleft(req)
+                        else:
+                            self._pending.appendleft(req)
+                    continue
+                with _RING.span("decode.admit", {"req": req.id}) as adm:
+                    admitted = self._admit(req, adm.args)
+                    adm.args["ok"] = admitted
+                counts["admits"] += 1
+                if admitted:
+                    self._active.append(req)
+                    self._m["tenant_admissions"].labels(
+                        tenant=req.tenant).inc()
+                    if req.preempts:
+                        self._m["preempt_resumes"].inc()
+            if self._admitting:
+                with self._cond:
+                    self._admitting = []
+            if newly or victims:
                 self._update_gauges()
+            counts["active"] = len(self._active)
+            if self._active:
+                self._step_once()
+        except Exception as exc:  # engine-level failure: fail the
+            # batch (typed), free its pages, keep serving newcomers
+            err = exc if isinstance(exc, TypedServeError) else \
+                TypedServeError(ERR_UNAVAILABLE,
+                                f"decode scheduler failure: {exc}")
+            for req in self._active:
+                req.stream._push_error(err)
+                self._m["evictions"].labels(reason="error").inc()
+                self._release_pages(req)
+            self._active = []
+            self._update_gauges()
+        return True
 
     # ------------------------------------------------- QoS scheduling
 
@@ -2031,8 +2050,9 @@ class DecodeEngine:
 
     # ------------------------------------------------------- admission
 
-    def _admit(self, req: _Req) -> bool:
-        """Give the request KV pages and a first token source.
+    def _admit(self, req: _Req, note: dict) -> bool:
+        """Give the request KV pages and a first token source. `note`
+        is the `decode.admit` span's args, filled as they are learnt.
 
         Prefix hit: map the cached pages (refcount++), queue the
         uncached prompt tail to be fed through the batched decode step
@@ -2052,7 +2072,29 @@ class DecodeEngine:
         pt = self.page_tokens
         self._ensure_pool()
         req.t_admit = time.monotonic()
+        note["plen"] = plen
+        note["queued_ms"] = 1e3 * (req.t_admit - req.t_submit)
+        with _RING.span("decode.admit.lookup"):
+            usable, hit_pages = self._prefix_map(req, toks)
+        if usable is None:
+            return False         # parked in _migrating, no slot held
+        note["hit_tokens"] = usable
+        if usable:
+            req.pages = hit_pages
+            req.cache_len = usable
+            req.last_tok = toks[usable]
+            req.input_tail = deque(toks[usable + 1:])
+            req.feeding = True
+            return True
+        return self._admit_prefill(req, toks, note)
 
+    def _prefix_map(self, req: _Req, toks: List[int]):
+        """Prefix-cache lookup for an admission: (usable tokens, the
+        pages mapped for them), (0, []) on a miss or with the cache
+        off, (None, []) when the request was parked on a host-tier
+        refetch instead."""
+        plen = len(toks)
+        pt = self.page_tokens
         usable, hit_pages = 0, []
         owner = self._owner_for(req)
         if self._prefix is not None:
@@ -2070,7 +2112,7 @@ class DecodeEngine:
                         and self._tier_fetch(req, chain):
                     for p in hit_pages:
                         self._alloc.release(p, owner=owner)
-                    return False     # parked in _migrating, no slot held
+                    return None, []
             # at least one prompt token is always re-fed so the step
             # has logits to sample the first generated token from
             usable = min(hit_tokens, plen - 1)
@@ -2082,17 +2124,16 @@ class DecodeEngine:
             self._m["prefix_hits" if usable else "prefix_misses"].inc()
             if usable:
                 self._m["prefix_hit_tokens"].inc(usable)
+        return usable, hit_pages
 
-        if usable:
-            req.pages = hit_pages
-            req.cache_len = usable
-            req.last_tok = toks[usable]
-            req.input_tail = deque(toks[usable + 1:])
-            req.feeding = True
-            return True
-
-        # miss: full prefill at the prompt's kv rung
-        rung = next_bucket(plen, self.kv_ladder)
+    def _admit_prefill(self, req: _Req, toks: List[int],
+                       note: dict) -> bool:
+        """The miss path of `_admit`: B=1 prefill at the prompt's kv
+        rung, the K/V panel through the host into fresh pages, the
+        first token. Each phase is a `decode.admit.*` span."""
+        plen = len(toks)
+        pt = self.page_tokens
+        rung = note["rung"] = next_bucket(plen, self.kv_ladder)
         inp = np.zeros((1, rung), np.int32)
         inp[0, :plen] = toks
         exe = self._prefill_aot.get_or_compile(
@@ -2103,38 +2144,56 @@ class DecodeEngine:
         t0 = time.perf_counter()
         logits, k, v = exe(self.params, jnp.asarray(inp),
                            jnp.asarray([plen], np.int32))
-        row = np.asarray(logits)[0]
+        with _RING.span("decode.admit.logits_pull"):
+            row = np.asarray(logits)[0]
         req.prefill_s = time.perf_counter() - t0
         self._m["prefills"].inc()
         self._m["prefill_latency"].observe(req.prefill_s)
-        try:
-            pages = self._alloc_pages(-(-plen // pt), req)
-        except TypedServeError as err:
-            req.stream._push_error(err)
-            self._m["evictions"].labels(reason="exhausted").inc()
-            return False
+        n_pages = -(-plen // pt)
+        with _RING.span("decode.admit.alloc", {"pages": n_pages}):
+            try:
+                pages = self._alloc_pages(n_pages, req)
+            except TypedServeError as err:
+                req.stream._push_error(err)
+                self._m["evictions"].labels(reason="exhausted").inc()
+                return False
         # scatter the panel into the pages (zero padding past plen —
         # rung garbage must never enter the pool; table padding -> null)
         L, nh, D = self.cfg.layers, self.cfg.heads, self.cfg.head_dim
         w = -(-rung // pt)
-        ids = np.zeros(w, np.int32)
-        ids[:len(pages)] = pages
-        krows = np.zeros((L, w * pt, nh, D), np.float32)
-        vrows = np.zeros_like(krows)
-        krows[:, :plen] = np.asarray(k)[:, 0, :plen]
-        vrows[:, :plen] = np.asarray(v)[:, 0, :plen]
+        with _RING.span("decode.admit.kv_pull", {}) as pull:
+            k, v = np.asarray(k), np.asarray(v)
+            pull.args["bytes"] = k.nbytes + v.nbytes
+        with _RING.span("decode.admit.repack", {}) as repack:
+            ids = np.zeros(w, np.int32)
+            ids[:len(pages)] = pages
+            krows = np.zeros((L, w * pt, nh, D), np.float32)
+            vrows = np.zeros_like(krows)
+            krows[:, :plen] = k[:, 0, :plen]
+            vrows[:, :plen] = v[:, 0, :plen]
+            repack.args["bytes"] = krows.nbytes + vrows.nbytes
         wexe = self._write_aot.get_or_compile(
             self._kpool, self._vpool,
             jax.ShapeDtypeStruct((L, w, pt, nh, D), jnp.float32),
             jax.ShapeDtypeStruct((L, w, pt, nh, D), jnp.float32),
             jax.ShapeDtypeStruct((w,), jnp.int32),
             key=("pwrite", w))
+        with _RING.span("decode.admit.upload",
+                        {"bytes": krows.nbytes + vrows.nbytes + ids.nbytes}):
+            krows = jnp.asarray(krows.reshape(L, w, pt, nh, D))
+            vrows = jnp.asarray(vrows.reshape(L, w, pt, nh, D))
+            ids = jnp.asarray(ids)
         self._kpool, self._vpool = wexe(
-            self._kpool, self._vpool,
-            jnp.asarray(krows.reshape(L, w, pt, nh, D)),
-            jnp.asarray(vrows.reshape(L, w, pt, nh, D)),
-            jnp.asarray(ids))
+            self._kpool, self._vpool, krows, vrows, ids)
         req.pages = pages
+        with _RING.span("decode.admit.emit"):
+            return self._admit_emit(req, toks, row)
+
+    def _admit_emit(self, req: _Req, toks: List[int],
+                    row: np.ndarray) -> bool:
+        """Sample and push the first token of a prefilled request, and
+        seed the prefix cache with its prompt pages."""
+        plen = len(toks)
         if not req.generated:        # resumes already saw first-token
             self._m["ttft"].observe(time.monotonic() - req.t_submit)
         try:
@@ -2153,7 +2212,8 @@ class DecodeEngine:
         self._m["tokens"].inc()
         self._note_token(req)
         if self._prefix is not None:
-            self._prefix.insert(toks, pages[:plen // pt])
+            self._prefix.insert(
+                toks, req.pages[:plen // self.page_tokens])
         eos = req.eos_id is not None and tok == req.eos_id
         req.stream._push_token(tok, eos)
         _RING.instant("decode.emit", {"req": req.id})
@@ -2167,21 +2227,73 @@ class DecodeEngine:
     # ------------------------------------------------------------ step
 
     def _step_once(self):
-        t_tick = time.perf_counter()
+        """One tick: every active slot advances one position. The tick
+        is a `decode.step` ring span tiled by `decode.step.provision`,
+        `decode.step.build`, `exec:decode.pstep`, `decode.step.pull`
+        and `decode.sample`; a tick that dispatches nothing writes no
+        `decode.step`."""
+        with _RING.span("decode.step", {}) as tick:
+            with _RING.span("decode.step.provision", {}) as prov:
+                prov.args["new_pages"] = self._provision_rows()
+            reqs = self._active
+            if not reqs:
+                tick.drop()
+                return
+            with _RING.span("decode.step.build"):
+                b_rung = next_bucket(len(reqs), self.batch_ladder)
+                w_rung = next_bucket(max(len(r.pages) for r in reqs),
+                                     self.page_ladder)
+                tables = np.zeros((b_rung, w_rung), np.int32)  # pad -> null
+                ltok = np.zeros(b_rung, np.int32)
+                clen = np.zeros(b_rung, np.int32)
+                for j, req in enumerate(reqs):
+                    tables[j, :len(req.pages)] = req.pages
+                    ltok[j] = req.last_tok
+                    clen[j] = req.cache_len
+                exe = self._step_aot.get_or_compile(
+                    self.params, self._kpool, self._vpool,
+                    jax.ShapeDtypeStruct((b_rung, w_rung), jnp.int32),
+                    jax.ShapeDtypeStruct((b_rung,), jnp.int32),
+                    jax.ShapeDtypeStruct((b_rung,), jnp.int32),
+                    key=("pstep", b_rung, w_rung))
+                tables, ltok, clen = (jnp.asarray(tables),
+                                      jnp.asarray(ltok), jnp.asarray(clen))
+            t0 = time.perf_counter()
+            logits, self._kpool, self._vpool = exe(
+                self.params, self._kpool, self._vpool, tables, ltok, clen)
+            with _RING.span("decode.step.pull", {}) as pull:
+                lognp = np.asarray(logits)
+                pull.args["bytes"] = lognp.nbytes
+            self._m["step_latency"].observe(time.perf_counter() - t0)
+            self._last_b_rung, self._last_w_rung = b_rung, w_rung
+            self._steps += 1
+            self._m["steps"].inc()
+            with _RING.span("decode.sample", {"reqs": len(reqs)}):
+                finished = self._sample_rows(reqs, lognp)
+            tick.args.update(batch=len(reqs), b_rung=b_rung, w_rung=w_rung)
+        if finished:
+            done = {r.id for r in finished}
+            self._active = [r for r in reqs if r.id not in done]
+            self._update_gauges()
+
+    def _provision_rows(self) -> int:
+        """Provision every active slot's write target for row
+        cache_len: a fresh page at a page boundary, a copy-on-write if
+        the target page is shared. A slot the pool cannot serve fails
+        alone. Returns the pages taken."""
         pt = self.page_tokens
-        # provision the write target for row cache_len: a fresh page at
-        # a page boundary, a copy-on-write if the target page is shared
+        taken = 0
         victims = []
         for req in self._active:
             slot = req.cache_len // pt
             try:
                 if slot >= len(req.pages):
                     req.pages.extend(self._alloc_pages(1, req))
+                    taken += 1
                 elif self._alloc.refcount(req.pages[slot]) > 1:
-                    t_cow = time.perf_counter()
-                    self._cow(req, slot)
-                    _RING.complete("decode.cow", t_cow,
-                                   time.perf_counter(), {"req": req.id})
+                    with _RING.span("decode.cow", {"req": req.id}):
+                        self._cow(req, slot)
+                    taken += 1
             except TypedServeError as err:
                 req.stream._push_error(err)
                 self._m["evictions"].labels(reason="exhausted").inc()
@@ -2191,35 +2303,13 @@ class DecodeEngine:
             dead = {r.id for r in victims}
             self._active = [r for r in self._active if r.id not in dead]
             self._update_gauges()
-        reqs = self._active
-        if not reqs:
-            return
-        b_rung = next_bucket(len(reqs), self.batch_ladder)
-        w_rung = next_bucket(max(len(r.pages) for r in reqs),
-                             self.page_ladder)
-        tables = np.zeros((b_rung, w_rung), np.int32)   # pad -> null page
-        ltok = np.zeros(b_rung, np.int32)
-        clen = np.zeros(b_rung, np.int32)
-        for j, req in enumerate(reqs):
-            tables[j, :len(req.pages)] = req.pages
-            ltok[j] = req.last_tok
-            clen[j] = req.cache_len
-        exe = self._step_aot.get_or_compile(
-            self.params, self._kpool, self._vpool,
-            jax.ShapeDtypeStruct((b_rung, w_rung), jnp.int32),
-            jax.ShapeDtypeStruct((b_rung,), jnp.int32),
-            jax.ShapeDtypeStruct((b_rung,), jnp.int32),
-            key=("pstep", b_rung, w_rung))
-        t0 = time.perf_counter()
-        logits, self._kpool, self._vpool = exe(
-            self.params, self._kpool, self._vpool,
-            jnp.asarray(tables), jnp.asarray(ltok), jnp.asarray(clen))
-        lognp = np.asarray(logits)
-        self._m["step_latency"].observe(time.perf_counter() - t0)
-        self._last_b_rung, self._last_w_rung = b_rung, w_rung
-        self._steps += 1
-        self._m["steps"].inc()
-        t_sample = time.perf_counter()
+        return taken
+
+    def _sample_rows(self, reqs: List[_Req], lognp: np.ndarray):
+        """Advance every slot past the step just run: feed the next
+        prompt-tail token, or sample, push and account one new token.
+        Returns the requests that ended."""
+        pt = self.page_tokens
         finished = []
         for j, req in enumerate(reqs):
             req.cache_len += 1
@@ -2260,15 +2350,7 @@ class DecodeEngine:
                 self._finish(req, "eos" if eos else "length")
                 self._release_pages(req)
                 finished.append(req)
-        now = time.perf_counter()
-        _RING.complete("decode.sample", t_sample, now, {"reqs": len(reqs)})
-        _RING.complete("decode.step", t_tick, now,
-                       {"batch": len(reqs), "b_rung": b_rung,
-                        "w_rung": w_rung})
-        if finished:
-            done = {r.id for r in finished}
-            self._active = [r for r in reqs if r.id not in done]
-            self._update_gauges()
+        return finished
 
     def _finish(self, req: _Req, reason: str):
         req.stream._push_done()
@@ -2315,23 +2397,24 @@ class DecodeEngine:
         return int(self._req_rng(req, pos).choice(p.shape[0], p=p))
 
     def _update_gauges(self):
-        n = len(self._active)
-        self._m["active"].set(n)
-        self._m["occupancy"].set(n / max(self.max_slots, 1))
-        self._m["preempted_waiting"].set(len(self._paused))
-        ps = self._alloc.stats()
-        self._m["page_pool_size"].set(ps["pages_total"])
-        self._m["page_in_use"].set(ps["pages_used"])
-        self._m["page_shared"].set(ps["pages_shared"])
-        self._m["page_fragmentation"].set(ps["fragmentation"])
-        if self._prefix is not None:
-            self._m["prefix_cached_pages"].set(
-                self._prefix.stats()["cached_pages"])
-        if self._tm is not None:
-            self._tm["resident"].labels(tier="device").set(
-                ps["pages_used"])
-            self._tm["resident"].labels(tier="host").set(
-                ps.get("host_pages_used", 0))
+        with _RING.span("decode.gauges"):
+            n = len(self._active)
+            self._m["active"].set(n)
+            self._m["occupancy"].set(n / max(self.max_slots, 1))
+            self._m["preempted_waiting"].set(len(self._paused))
+            ps = self._alloc.stats()
+            self._m["page_pool_size"].set(ps["pages_total"])
+            self._m["page_in_use"].set(ps["pages_used"])
+            self._m["page_shared"].set(ps["pages_shared"])
+            self._m["page_fragmentation"].set(ps["fragmentation"])
+            if self._prefix is not None:
+                self._m["prefix_cached_pages"].set(
+                    self._prefix.stats()["cached_pages"])
+            if self._tm is not None:
+                self._tm["resident"].labels(tier="device").set(
+                    ps["pages_used"])
+                self._tm["resident"].labels(tier="host").set(
+                    ps.get("host_pages_used", 0))
 
 
 # ------------------------------------------------- speculative decoding
@@ -2581,9 +2664,9 @@ class SpecDecodeEngine(DecodeEngine):
 
     # ------------------------------------------------------- admission
 
-    def _admit(self, req: _Req) -> bool:
+    def _admit(self, req: _Req, note: dict) -> bool:
         req.spec_k = self.k_ladder[-1]      # start optimistic, adapt down
-        if not super()._admit(req):
+        if not super()._admit(req, note):
             return False
         if not req.feeding:
             # prefill miss: the target panel is in the pages; mirror the

@@ -152,45 +152,47 @@ class _ProfiledExecutable:
     Wraps one compiled executable: each call is timed twice — the call
     itself (JAX dispatches asynchronously, so this is host dispatch
     cost) and ``block_until_ready`` on the outputs (device execution).
-    Both land in the tracez event ring (one "X" span per dispatch) and
-    the profilez ``paddle_tpu_exec_*`` aggregates, keyed by the owning
-    cache's label.  Every current AotCache call site reads the outputs
-    on the host immediately after dispatching, so blocking here moves
-    the wait, it does not add one.  A poisoned dispatch is NOT re-raised
-    from the hook — it surfaces at the caller's read with its original
-    traceback, exactly as without the wrapper.
+    Both land in the tracez event ring (one live ``exec:<label>`` span
+    per dispatch, so it is a profiler annotation too whenever a
+    profiler session is on) and the profilez ``paddle_tpu_exec_*``
+    aggregates, keyed by the owning cache's label.  Every current
+    AotCache call site reads the outputs on the host immediately after
+    dispatching, so blocking here moves the wait, it does not add one.
+    A poisoned dispatch is NOT re-raised from the hook — it surfaces at
+    the caller's read with its original traceback, exactly as without
+    the wrapper.
     """
 
-    __slots__ = ("_exe", "_label", "_donate")
+    __slots__ = ("_exe", "_label", "_span_name", "_donate")
 
     def __init__(self, exe, label: str, donate_argnums: Tuple[int, ...]):
         self._exe = exe
         self._label = label
+        self._span_name = f"exec:{label}"
         self._donate = donate_argnums
 
     def __getattr__(self, name):      # cost_analysis() etc. pass through
         return getattr(self._exe, name)
 
     def __call__(self, *args):
+        import jax
+
+        from ..observability import profilez as _profilez
+        from ..observability import tracez as _tracez
+
         donated = 0
         for i in self._donate:
             if i < len(args):
                 donated += int(getattr(args[i], "nbytes", 0) or 0)
-        t0 = time.perf_counter()
-        out = self._exe(*args)
-        t1 = time.perf_counter()
-        try:
-            import jax
-
-            jax.block_until_ready(out)
-        except Exception:
-            pass                       # deferred failure: caller's read
-        t2 = time.perf_counter()
-        from ..observability import profilez as _profilez
-        from ..observability import tracez as _tracez
-
-        _tracez.RING.complete(f"exec:{self._label}", t0, t2)
-        _profilez.PROFILER.observe(self._label, t1 - t0, t2 - t1, donated)
+        with _tracez.RING.span(self._span_name) as span:
+            out = self._exe(*args)
+            t1 = time.perf_counter()
+            try:
+                jax.block_until_ready(out)
+            except Exception:
+                pass                   # deferred failure: caller's read
+        _profilez.PROFILER.observe(self._label, t1 - span.t0,
+                                   span.t1 - t1, donated)
         return out
 
 

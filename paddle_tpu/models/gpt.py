@@ -1184,15 +1184,20 @@ def gpt_paged_decode_fns(cfg: GPTConfig, eps: float = 1e-5,
             q = q.reshape(B, nh, D)
             k_new = k_new.reshape(B, nh, D)
             v_new = v_new.reshape(B, nh, D)
-            k_pool = _kv_pool_write(k_pool, i, page_idx, offset, k_new)
-            v_pool = _kv_pool_write(v_pool, i, page_idx, offset, v_new)
-            o = _paged_attend(
-                q, _kv_pool_layer(k_pool, i), _kv_pool_layer(v_pool, i),
-                tables, lengths).reshape(B, -1)
+            with jax.named_scope("pool_write"):
+                k_pool = _kv_pool_write(k_pool, i, page_idx, offset, k_new)
+                v_pool = _kv_pool_write(v_pool, i, page_idx, offset, v_new)
+            with jax.named_scope("attention"):  # "page_gather" inside it
+                o = _paged_attend(
+                    q, _kv_pool_layer(k_pool, i),
+                    _kv_pool_layer(v_pool, i), tables,
+                    lengths).reshape(B, -1)
             x = x + _qmm(bp, "attn.proj.weight", o) + bp["attn.proj.bias"]
-            x = _ffn(bp, x)
-        xf = _pp_ln(x, head["ln_f.weight"], head["ln_f.bias"], eps)
-        logits = xf @ embed["wte.weight"].T
+            with jax.named_scope("mlp"):
+                x = _ffn(bp, x)
+        with jax.named_scope("head"):
+            xf = _pp_ln(x, head["ln_f.weight"], head["ln_f.bias"], eps)
+            logits = xf @ embed["wte.weight"].T
         return logits, k_pool, v_pool
 
     prefill, _ = gpt_decode_fns(cfg, eps=eps)

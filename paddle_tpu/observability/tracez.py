@@ -3,7 +3,7 @@
 The fleet already answers "how much" (metrics, /varz) and "how bad"
 (/alertz, stall dumps); tracez answers "what happened, in order".  Every
 process keeps one :data:`RING` — a fixed-capacity, overwrite-on-wrap
-event ring the hot paths write begin/end/instant/counter events into:
+event ring the hot paths write span and instant events into:
 the dynamic batcher's form/pad/execute/unpad, the decode engine's tick
 phases, the async step pipeline's dispatch/block, every AOT'd
 executable's dispatch (via ``jit.compile_cache``), and the router's
@@ -11,6 +11,16 @@ pick/forward/reply.  Recording one event is a tuple build plus one slot
 assignment under a lock — no I/O, no allocation beyond the tuple, no
 device work — so the ring can stay armed in production (< 2 µs/event on
 CPU; ``PADDLE_TPU_TRACEZ_CAPACITY=0`` turns it into a no-op).
+
+**Two clocks, one primitive.** :meth:`TraceRing.span` is the live
+form: for its extent it also holds a ``jax.profiler.TraceAnnotation``
+of the same name, so whenever a profiler session is on (a benchmark's
+traced run, ``paddle_tpu.profiler.start_trace``, an operator's attach)
+the span is in the host plane of the same ``.xplane.pb`` that holds the
+device's ``XLA Modules`` / ``XLA Ops`` — on the profiler's clock, over
+the device rows.  With no session the annotation costs about half a
+microsecond.  :meth:`TraceRing.complete` stays for spans whose ends are
+only known afterwards.
 
 **Clock model.** Events carry ``time.perf_counter()`` timestamps
 (monotonic, immune to NTP steps); each ring records a *wall-clock
@@ -37,7 +47,6 @@ import os
 import sys
 import threading
 import time
-from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..core import flags as _flags
@@ -61,9 +70,9 @@ class TraceRing:
     """Bounded in-process event ring with a wall-clock anchor.
 
     Events are tuples ``(ph, name, ts, dur, tid, args)`` where ``ph`` is
-    the Chrome trace-event phase ("X" complete, "B"/"E" begin/end, "i"
-    instant, "C" counter), ``ts``/``dur`` are ``perf_counter`` seconds,
-    and ``args`` is an optional small dict.  The ring never grows and
+    the Chrome trace-event phase ("X" complete, "i" instant),
+    ``ts``/``dur`` are ``perf_counter`` seconds, and ``args`` is an
+    optional small dict.  The ring never grows and
     never blocks its writer beyond one uncontended lock: when full, the
     oldest event is overwritten (``dropped`` counts the losses).
     """
@@ -98,37 +107,22 @@ class TraceRing:
             self._buf[self._n % cap] = evt
             self._n += 1
 
-    def begin(self, name: str, args: Optional[dict] = None) -> float:
-        """Open a span on the calling thread; returns the begin time so
-        the caller can also feed a duration elsewhere."""
-        t = time.perf_counter()
-        self.record("B", name, t, 0.0, args)
-        return t
-
-    def end(self, name: str):
-        self.record("E", name, time.perf_counter())
-
     def complete(self, name: str, t0: float, t1: float,
                  args: Optional[dict] = None):
-        """One finished span as a single "X" event (cheaper than B+E and
-        immune to a lost half when the ring wraps mid-span)."""
+        """One finished span as a single "X" event, for a span whose
+        ends are only known afterwards (or lie on two threads)."""
         self.record("X", name, t0, t1 - t0, args)
 
     def instant(self, name: str, args: Optional[dict] = None):
         self.record("i", name, time.perf_counter(), 0.0, args)
 
-    def counter(self, name: str, value: float):
-        # the value rides in the dur slot: no dict allocation on the
-        # hot path; the exporter moves it into args
-        self.record("C", name, time.perf_counter(), float(value))
-
-    @contextmanager
-    def span(self, name: str, args: Optional[dict] = None):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.complete(name, t0, time.perf_counter(), args)
+    def span(self, name: str, args: Optional[dict] = None) -> "_Span":
+        """A live span: ``with ring.span(name, args) as s`` writes one
+        "X" event when the block ends and holds a profiler annotation
+        of the same name meanwhile. ``s.args`` may be filled inside the
+        block (counts known only at the end); ``s.drop()`` keeps the
+        event out of the ring."""
+        return _Span(self, name, args)
 
     # -- reads ------------------------------------------------------------
 
@@ -179,10 +173,8 @@ class TraceRing:
         for ph, name, ts, dur, tid, args in events:
             key = f"{names.get(tid, 'unknown')} ({tid})"
             row = {"t": round(self.wall(ts), 6), "ph": ph, "name": name}
-            if ph in ("X", "B") and dur:
+            if ph == "X" and dur:
                 row["dur_ms"] = round(dur * 1e3, 3)
-            if ph == "C":
-                row["value"] = dur
             if args:
                 row["args"] = args
             by_thread.setdefault(key, []).append(row)
@@ -208,12 +200,10 @@ class TraceRing:
                 "ts": round(self.wall(ts) * 1e6, 3)}
             if ph == "X":
                 e["dur"] = round(dur * 1e6, 3)
-            elif ph == "C":
-                e["args"] = {"value": dur}
             elif ph == "i":
                 e["s"] = "t"
             if args:
-                e.setdefault("args", {}).update(args)
+                e["args"] = dict(args)
             rows.append(e)
         for tid in sorted(seen_tids):
             out.append({"ph": "M", "pid": self.pid, "tid": tid,
@@ -227,6 +217,50 @@ class TraceRing:
                              "events": len(events),
                              "events_recorded": total,
                              "events_dropped": self.dropped}}
+
+
+_annotation = None      # jax.profiler.TraceAnnotation, imported on first use
+
+
+class _Span:
+    """What :meth:`TraceRing.span` returns. The annotation is entered
+    before the first clock reading and left after the second, so the
+    ring's span lies inside the profiler's. A ring of capacity 0 enters
+    no annotation and records nothing; ``t0`` and ``t1`` are read all
+    the same, for a caller that feeds the times elsewhere."""
+
+    __slots__ = ("_ring", "name", "args", "t0", "t1", "_ann", "_keep")
+
+    def __init__(self, ring: "TraceRing", name: str, args: Optional[dict]):
+        self._ring = ring
+        self.name = name
+        self.args = args
+        self._keep = True
+
+    def __enter__(self):
+        global _annotation
+        self._ann = None
+        if self._ring.capacity:
+            if _annotation is None:
+                from jax.profiler import TraceAnnotation
+
+                _annotation = TraceAnnotation
+            self._ann = _annotation(self.name)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._keep:
+            self._ring.record("X", self.name, self.t0, self.t1 - self.t0,
+                              self.args)
+        return False
+
+    def drop(self):
+        self._keep = False
 
 
 # ---------------------------------------------------------------------------

@@ -141,8 +141,9 @@ def paged_decode_attention_reference(q, k_pool, v_pool, tables, lengths):
     contiguous [B, W*pt, H, D] view, reuse the masked-softmax math."""
     B, W = tables.shape
     P, pt, H, D = k_pool.shape
-    k = jnp.take(k_pool, tables, axis=0).reshape(B, W * pt, H, D)
-    v = jnp.take(v_pool, tables, axis=0).reshape(B, W * pt, H, D)
+    with jax.named_scope("page_gather"):
+        k = jnp.take(k_pool, tables, axis=0).reshape(B, W * pt, H, D)
+        v = jnp.take(v_pool, tables, axis=0).reshape(B, W * pt, H, D)
     return decode_attention_reference(q, k, v, lengths)
 
 

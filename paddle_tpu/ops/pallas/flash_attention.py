@@ -158,6 +158,7 @@ def _fwd(q3, k3, v3, scale, causal):
                              block_q=bq, block_k=bk, nk=nk, mxu=_mxu_dtype())
     o, lse = pl.pallas_call(
         kern,
+        name="flash_attention_fwd",
         grid=(BH, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, _I0),
@@ -324,6 +325,7 @@ def _bwd(scale, causal, res, g):
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, nk=nk, mxu=_mxu_dtype()),
+        name="flash_attention_bwd_dq",
         grid=(BH, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, _I0),
@@ -350,6 +352,7 @@ def _bwd(scale, causal, res, g):
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, nq=nq, mxu=_mxu_dtype()),
+        name="flash_attention_bwd_dkv",
         grid=(BH, nk, nq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, j, _I0),
@@ -454,6 +457,7 @@ def _bwd_fused(scale, causal, res, g):
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, nq=nq, mxu=_mxu_dtype(),
                           emit_dq=True),
+        name="flash_attention_bwd_fused",
         grid=(BH, nk, nq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, j, _I0),

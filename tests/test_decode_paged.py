@@ -239,12 +239,18 @@ def test_page_exhaustion_fails_only_victim(gpt_models):
     eng = DecodeEngine(model, max_slots=2, max_new_tokens=8,
                        page_tokens=4, num_pages=5, prefix_cache=False)
     try:
-        s1 = eng.submit(p1, max_new_tokens=6)
-        import time
-        time.sleep(0.3)                  # p1 admits + starts stepping
-        s2 = eng.submit(p2, max_new_tokens=6)
-        with pytest.raises(TypedServeError) as ei:
-            s2.result(timeout=120)
+        # p1's second token ends its first decode step, which took its
+        # third page (row 8): from there until p1 finishes four ticks
+        # later, one page is free. Wait for that token (a sleep raced
+        # the engine's first compile), and slow p1's later ticks so
+        # that p2 is scheduled well before p1 gives its pages back.
+        with chaos.inject("decode.stream:3+:Hang@0.05"):
+            s1 = eng.submit(p1, max_new_tokens=6)
+            for _ in range(2):
+                assert s1.next_event(timeout=120)[0] == "token"
+            s2 = eng.submit(p2, max_new_tokens=6)
+            with pytest.raises(TypedServeError) as ei:
+                s2.result(timeout=120)
         assert ei.value.code == ERR_RESOURCE_EXHAUSTED
         # the denial carries its forensics: pool label, the denied
         # owner tag (this slot, default tenant), and requested/free
@@ -260,6 +266,96 @@ def test_page_exhaustion_fails_only_victim(gpt_models):
             == _ref_greedy(model, p2, 6)
     finally:
         eng.stop()
+
+
+def test_ring_spans_tile_the_scheduler_loop():
+    """The engine thread's ring spans after a short run: every
+    `decode.loop` contains its schedule, admissions and tick and counts
+    them; a miss admission is tiled by its seven phases, in order, and
+    carries how long the request queued; the tick's phases cover at
+    least 95% of a `decode.step`."""
+    import time
+
+    from paddle_tpu.observability.tracez import RING
+
+    # deep enough that a tick takes 10 ms on a CPU: the dispatch hook's
+    # own bookkeeping between the phases is some 0.1 ms of every tick
+    paddle.seed(7)
+    model = GPT(GPTConfig(vocab_size=4096, max_seq_len=64, hidden=256,
+                          layers=16, heads=4))
+    rng = np.random.RandomState(5)
+    eng = DecodeEngine(model, max_slots=2, max_new_tokens=8, page_tokens=4)
+    try:
+        tid = eng._thread.ident
+        t_start = time.perf_counter()
+        streams = [eng.submit(rng.randint(0, 512, size=n), max_new_tokens=8)
+                   for n in (5, 9, 6)]
+        for st in streams:
+            assert len(st.result(timeout=120)) == 8
+    finally:
+        eng.stop()
+    spans = {}
+    for ph, name, ts, dur, etid, args in RING.snapshot()[0]:
+        if ph == "X" and etid == tid and ts + dur >= t_start:
+            spans.setdefault(name, []).append((ts, ts + dur, args or {}))
+
+    def inside(name, outer):
+        return [c for c in spans.get(name, [])
+                if outer[0] <= c[0] and c[1] <= outer[1]]
+
+    loops = spans["decode.loop"]
+    admits, ticks = spans["decode.admit"], spans["decode.step"]
+    assert len(admits) == 3 and len(ticks) >= 8
+    for name in ("decode.admit", "decode.step"):
+        assert sum(len(inside(name, lp)) for lp in loops) \
+            == len(spans[name]), name        # none outside an iteration
+    for lp in loops:
+        (sched,) = inside("decode.schedule", lp)
+        assert set(sched[2]) == {"pending", "paused"}
+        assert lp[2]["admits"] == len(inside("decode.admit", lp))
+        assert len(inside("decode.step", lp)) == (lp[2]["active"] > 0)
+    assert sum(s[2]["pending"] for s in spans["decode.schedule"]) >= 3
+    assert spans["decode.idle"]              # it waited for the first
+
+    phases = ["decode.admit.lookup", "exec:decode.prefill",
+              "decode.admit.logits_pull", "decode.admit.alloc",
+              "decode.admit.kv_pull", "decode.admit.repack",
+              "decode.admit.upload", "exec:decode.pwrite",
+              "decode.admit.emit"]
+    for adm, plen in zip(admits, (5, 9, 6)):
+        args = adm[2]
+        assert args["plen"] == plen and args["ok"] is True
+        assert args["queued_ms"] >= 0 and args["hit_tokens"] == 0
+        assert args["rung"] >= plen
+        inner = [inside(name, adm) for name in phases]
+        assert all(len(c) == 1 for c in inner), inner
+        starts = [c[0][0] for c in inner]
+        assert starts == sorted(starts)
+        assert inner[3][0][2] == {"pages": -(-plen // 4)}
+        assert inner[4][0][2]["bytes"] > 0 and inner[6][0][2]["bytes"] \
+            > inner[5][0][2]["bytes"] > 0
+
+    tiles = ["decode.step.provision", "decode.step.build",
+             "exec:decode.pstep", "decode.step.pull", "decode.sample"]
+    shares = []
+    for tick in ticks:
+        assert set(tick[2]) == {"batch", "b_rung", "w_rung"}
+        inner = [inside(name, tick) for name in tiles]
+        assert all(len(c) == 1 for c in inner), inner
+        covered = sum(c[0][1] - c[0][0] for c in inner)
+        shares.append((covered / (tick[1] - tick[0]), covered,
+                       tick[1] - tick[0]))
+        assert inner[3][0][2]["bytes"] > 0
+    # every tick, but for the odd one in which a loaded machine took
+    # the thread off the CPU between two spans (six test workers share
+    # these cores): the median tick and the ticks taken together
+    shares.sort()
+    assert shares[len(shares) // 2][0] >= 0.95, shares
+    assert sum(c for _, c, _ in shares) \
+        >= 0.95 * sum(d for _, _, d in shares), shares
+    assert shares[0][0] >= 0.5, shares
+    assert sum(t[2]["new_pages"]
+               for t in spans["decode.step.provision"]) >= 3
 
 
 def test_chaos_page_alloc_mid_decode(gpt_models):

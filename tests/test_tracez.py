@@ -116,12 +116,13 @@ def test_ring_record_overhead_under_2us():
 
 def test_chrome_trace_schema():
     ring = TraceRing(capacity=32, component="testcomp", pid=77)
-    with ring.span("work", {"k": 1}):
+    with ring.span("work", {"k": 1}) as work:
         time.sleep(0.002)
+        work.args["n"] = 3              # a count known only at the end
     ring.instant("mark", {"m": 2})
-    ring.counter("queue_depth", 5.0)
-    ring.begin("open")
-    ring.end("open")
+    with ring.span("dropped") as gone:
+        gone.drop()
+    ring.complete("after", work.t0, work.t1)
     doc = ring.chrome_trace()
     assert set(doc) == {"traceEvents", "displayTimeUnit", "metadata"}
     assert doc["displayTimeUnit"] == "ms"
@@ -133,22 +134,84 @@ def test_chrome_trace_schema():
               if e["ph"] == "M" and e["name"] == "thread_name"]
     assert len(tnames) == 1             # single-threaded test
     rows = [e for e in evs if e["ph"] != "M"]
-    assert [e["ph"] for e in rows] == ["X", "i", "C", "B", "E"]
+    assert [(e["ph"], e["name"]) for e in rows] == [
+        ("X", "work"), ("i", "mark"), ("X", "after")]
     x = rows[0]
     assert x["name"] == "work" and x["cat"] == "testcomp"
     assert x["pid"] == 77 and x["dur"] >= 2000      # µs
-    assert x["args"]["k"] == 1
+    assert x["args"] == {"k": 1, "n": 3}
     i = rows[1]
     assert i["s"] == "t" and i["args"]["m"] == 2
-    c = rows[2]
-    assert c["args"]["value"] == 5.0
+    assert rows[2]["dur"] == x["dur"] and "args" not in rows[2]
     # timestamps are anchored wall-clock µs: inside this test's window
     now_us = time.time() * 1e6
     for e in rows:
         assert now_us - 60e6 < e["ts"] < now_us + 60e6
     md = doc["metadata"]
-    assert md["events_recorded"] == 5 and md["events_dropped"] == 0
+    assert md["events_recorded"] == 3 and md["events_dropped"] == 0
     json.dumps(doc)                     # fully serializable
+    assert not {"begin", "end", "counter"} & set(dir(TraceRing))
+
+
+def test_span_is_a_profiler_annotation_too(tmp_path):
+    """One primitive on both clocks: under a jax.profiler session a
+    `span()` leaves an event of its name in the host plane of the same
+    xplane file that holds the device rows; a ring of capacity 0 leaves
+    neither."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    ring, off = TraceRing(capacity=8), TraceRing(capacity=0)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1          # what the benchmark traces with
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with ring.span("tracez.test.outer", {"k": 1}):
+            with ring.span("tracez.test.inner"):
+                time.sleep(0.002)
+        with off.span("tracez.test.off"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("tracez.test."):
+                        host[ev.name] = (ev.start_ns, ev.duration_ns)
+    assert set(host) == {"tracez.test.outer", "tracez.test.inner"}
+    (o0, od), (i0, idur) = host["tracez.test.outer"], \
+        host["tracez.test.inner"]
+    assert o0 <= i0 and i0 + idur <= o0 + od and idur >= 2e6    # ns
+    events, _ = ring.snapshot()
+    assert [e[1] for e in events] == ["tracez.test.inner",
+                                      "tracez.test.outer"]
+    # the ring's span lies inside the profiler's: same extent, two clocks
+    assert events[1][3] * 1e9 <= od + 1e3
+    assert off.snapshot() == ([], 0)
+
+
+def test_span_overhead_under_5us_without_a_session():
+    """`span()` with no profiler session on: two clock reads, one
+    annotation that records nothing, one ring write."""
+    ring = TraceRing(capacity=1 << 14)
+    n = 20000
+    best = float("inf")
+    for _ in range(5):
+        ring.clear()
+        t0 = time.perf_counter()
+        for _i in range(n):
+            with ring.span("bench"):
+                pass
+        best = min(best, (time.perf_counter() - t0) / n)
+    assert ring.total == n
+    assert best < 5e-6, f"{best * 1e6:.3f} µs/span"
 
 
 def test_merge_skew_corrected_timeline():
