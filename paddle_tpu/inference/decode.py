@@ -13,16 +13,17 @@ KV cache instead of per-slot contiguous panels:
     them — capacity growth is a wider block table, never a cache copy
     (the contiguous engine re-packed the whole pool on every rung
     change);
-  * the compute core is `models.gpt.gpt_paged_decode_fns` — `prefill`
-    builds a request's K/V panel in one pass (panel rows are then
-    scattered into pool pages), `paged_step` advances EVERY active
+  * the compute core is `models.gpt.gpt_paged_prefill_fns` — one
+    dispatch builds a request's K/V panel and writes it into the
+    request's pool pages, so no K or V crosses to the host — and
+    `gpt_paged_decode_fns`' `paged_step`, which advances EVERY active
     request one token, writing through the block table and attending
     via `ops.pallas.decode_attention.paged_decode_attention`;
-  * all device entry points run through an `AotCache` — prefill per
-    prompt rung, the step per (batch-rung x page-rung) bucket, page
-    writes per page rung, plus one traced-scalar copy-on-write
-    executable — so after `warmup()` a steady-state token stream
-    compiles nothing, across any admission/eviction churn;
+  * all device entry points run through an `AotCache` — the fused
+    prefill per prompt rung, the step per (batch-rung x page-rung)
+    bucket, plus one traced-scalar copy-on-write executable — so after
+    `warmup()` a steady-state token stream compiles nothing, across
+    any admission/eviction churn;
   * **prefix sharing**: a hash trie caches page-aligned prompt
     prefixes. A second request with the same system prompt maps the
     cached pages (refcount++) and only prefills its tail — the tail
@@ -115,8 +116,7 @@ from ..observability import counter, gauge, histogram
 from ..observability import memz as _memz
 from ..observability.spans import SpanRecorder, next_request_id
 from ..observability.tracez import RING as _RING
-from ..quant.kv import (kv_pool_sds, kv_pool_zeros, quantize_kv,
-                        validate_kv_dtype)
+from ..quant.kv import kv_pool_sds, kv_pool_zeros, validate_kv_dtype
 from ..quant.ptq import is_quantized as _params_quantized
 from ..quant.ptq import quantize_params
 from ..testing import chaos
@@ -147,7 +147,9 @@ def _decode_metrics():
                 "Batched decode steps executed (one per token column)"),
             "prefills": counter(
                 "paddle_tpu_decode_prefills_total",
-                "Requests admitted through the prefill phase"),
+                "Fused prefill-into-pages dispatches: one per miss "
+                "admission (none if its page allocation fails first) "
+                "or KV-handoff export"),
             "evictions": counter(
                 "paddle_tpu_decode_cache_evictions_total",
                 "KV-cache slot evictions by reason",
@@ -160,7 +162,9 @@ def _decode_metrics():
                 "Sequences currently holding a KV slot"),
             "prefill_latency": histogram(
                 "paddle_tpu_decode_prefill_latency_seconds",
-                "Prefill execution latency per admitted request"),
+                "Fused prefill-into-pages latency per dispatch: prefill "
+                "plus the K/V page write until the pools are ready, "
+                "plus the first token's logits pull on an admission"),
             "step_latency": histogram(
                 "paddle_tpu_decode_step_latency_seconds",
                 "Batched decode-step execution latency"),
@@ -860,18 +864,8 @@ class _PrefixCache:
                     "orphaned": self._orphaned}
 
 
-# Pure pool entry points (jit + AotCache'd by the engine): K and V move
-# together so one executable covers both writes. Rows arrive fp32 from
-# prefill; an int8 (data, scale) pool quantizes them inside the same
-# executable, so the host never materializes a quantized panel.
-
-def _write_kv_pages(k_pool, v_pool, k_rows, v_rows, page_ids):
-    if isinstance(k_pool, tuple):
-        k_rows = quantize_kv(k_rows)
-        v_rows = quantize_kv(v_rows)
-    return (write_pages(k_pool, k_rows, page_ids),
-            write_pages(v_pool, v_rows, page_ids))
-
+# Pure pool entry point (jit + AotCache'd by the engine): K and V move
+# together so one executable covers both copies.
 
 def _copy_kv_page(k_pool, v_pool, src, dst):
     return (copy_page(k_pool, src, dst), copy_page(v_pool, src, dst))
@@ -919,8 +913,13 @@ class DecodeEngine:
         self.kv_dtype = validate_kv_dtype(
             kv_dtype if kv_dtype is not None
             else _flags.env_value("PADDLE_TPU_DECODE_KV_DTYPE"))
-        prefill_fn, step_fn = gpt_paged_decode_fns(
+        _, step_fn = gpt_paged_decode_fns(
             cfg, eps=self.eps, page_tokens=self.page_tokens)
+        # `jit_prefill` in a device trace, beside its ring label
+        # `exec:decode.prefill` (the draft's is `jit_paged_prefill` /
+        # `exec:decode.dprefill`): a reader of both pairs them by name
+        prefill_fn = gpt_paged_prefill_fns(
+            cfg, eps=self.eps, page_tokens=self.page_tokens, name="prefill")
         # Pool args are donated: every call site rebinds the pools from
         # the result, so XLA updates the multi-MB pool buffers in place
         # instead of copying them per dispatch (the copy dominated
@@ -970,12 +969,11 @@ class DecodeEngine:
         self._prefix = _PrefixCache(self._alloc, self.page_tokens) \
             if use_prefix else None
 
-        self._prefill_aot = AotCache(jax.jit(prefill_fn), "decode.prefill")
+        self._prefill_aot = AotCache(
+            jax.jit(prefill_fn, donate_argnums=(1, 2)), "decode.prefill",
+            donate_argnums=(1, 2))
         self._step_aot = AotCache(step_jit, "decode.pstep",
                                   donate_argnums=(1, 2))
-        self._write_aot = AotCache(
-            jax.jit(_write_kv_pages, donate_argnums=(0, 1)), "decode.pwrite",
-            donate_argnums=(0, 1))
         self._copy_aot = AotCache(
             jax.jit(_copy_kv_page, donate_argnums=(0, 1)), "decode.pcow",
             donate_argnums=(0, 1))
@@ -1154,29 +1152,46 @@ class DecodeEngine:
         with self._cond:
             self._cond.notify_all()
 
+    # One prefill path (`gpt_paged_prefill_fns`): the target model's
+    # admission, the KV-handoff export and the speculative engine's
+    # draft all dispatch it through these two.
+
+    def _prefill_exe(self, aot, params, k_pool, v_pool, rung):
+        """`aot`'s fused prefill-into-pages executable for one kv rung
+        (the pools may be arrays or their ShapeDtypeStructs)."""
+        i32 = jnp.int32
+        return aot.get_or_compile(
+            params, k_pool, v_pool,
+            jax.ShapeDtypeStruct((1, rung), i32),
+            jax.ShapeDtypeStruct((1, -(-rung // self.page_tokens)), i32),
+            jax.ShapeDtypeStruct((1,), i32),
+            key=("prefill", 1, rung))
+
+    def _prefill_into_pages(self, aot, params, k_pool, v_pool, toks, pages):
+        """One dispatch: `toks` prefilled at their kv rung and their K/V
+        written into `pages` of the (donated) pools; table padding aims
+        at the null page. Returns (logits [1, V], k_pool, v_pool), all
+        on the device."""
+        plen = len(toks)
+        rung = next_bucket(plen, self.kv_ladder)
+        inp = np.zeros((1, rung), np.int32)
+        inp[0, :plen] = toks
+        table = np.zeros((1, -(-rung // self.page_tokens)), np.int32)
+        table[0, :len(pages)] = pages
+        exe = self._prefill_exe(aot, params, k_pool, v_pool, rung)
+        return exe(params, k_pool, v_pool, jnp.asarray(inp),
+                   jnp.asarray(table), jnp.asarray([plen], np.int32))
+
     def warmup(self, verbose: bool = False) -> int:
-        """AOT-compile the prefill prompt rungs, the page-write rungs,
-        the copy-on-write executable, and the decode
+        """AOT-compile the fused prefill-into-pages prompt rungs, the
+        copy-on-write executable, and the decode
         (batch-rung x page-rung) cross product (capped, largest rungs
         first dropped last). Returns the number of fresh compiles."""
         before = len(profiler.compile_events())
-        L, nh, D = self.cfg.layers, self.cfg.heads, self.cfg.head_dim
-        i32, f32 = jnp.int32, jnp.float32
+        i32 = jnp.int32
         pool = self._pool_sds()
-        pt = self.page_tokens
         for r in self.kv_ladder:
-            self._prefill_aot.get_or_compile(
-                self.params,
-                jax.ShapeDtypeStruct((1, r), i32),
-                jax.ShapeDtypeStruct((1,), i32),
-                key=("prefill", 1, r))
-        for w in self.page_ladder:
-            self._write_aot.get_or_compile(
-                pool, pool,
-                jax.ShapeDtypeStruct((L, w, pt, nh, D), f32),
-                jax.ShapeDtypeStruct((L, w, pt, nh, D), f32),
-                jax.ShapeDtypeStruct((w,), i32),
-                key=("pwrite", w))
+            self._prefill_exe(self._prefill_aot, self.params, pool, pool, r)
         self._copy_aot.get_or_compile(
             pool, pool,
             jax.ShapeDtypeStruct((), i32), jax.ShapeDtypeStruct((), i32),
@@ -1894,8 +1909,8 @@ class DecodeEngine:
         """Device pages holding `toks`' first `n_full` full pages, one
         reference each held for the caller (attributed to `job`'s
         ``("handoff", id)`` tag): the cached chain when the trie
-        already covers them, else one prefill + scatter (which also
-        seeds the trie — the next export of this prompt is pure
+        already covers them, else one fused prefill-into-pages (which
+        also seeds the trie — the next export of this prompt is pure
         gather)."""
         pt = self.page_tokens
         owner = self._owner_for(job)
@@ -1906,40 +1921,16 @@ class DecodeEngine:
             return hit_pages[:n_full]
         for p in hit_pages:
             self._alloc.release(p, owner=owner)
-        plen = len(toks)
-        rung = next_bucket(plen, self.kv_ladder)
-        inp = np.zeros((1, rung), np.int32)
-        inp[0, :plen] = toks
-        exe = self._prefill_aot.get_or_compile(
-            self.params,
-            jax.ShapeDtypeStruct((1, rung), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-            key=("prefill", 1, rung))
+        pages = self._alloc_pages(n_full, job)
         t0 = time.perf_counter()
-        _, k, v = exe(self.params, jnp.asarray(inp),
-                      jnp.asarray([plen], np.int32))
+        # the partial last page's rows fall on the null page: only full
+        # pages travel. (An AotCache call returns once its outputs are
+        # ready, so the latency below is prefill + page write.)
+        _, self._kpool, self._vpool = self._prefill_into_pages(
+            self._prefill_aot, self.params, self._kpool, self._vpool,
+            toks, pages)
         self._m["prefills"].inc()
         self._m["prefill_latency"].observe(time.perf_counter() - t0)
-        pages = self._alloc_pages(n_full, job)
-        L, nh, D = self.cfg.layers, self.cfg.heads, self.cfg.head_dim
-        w = next_bucket(n_full, self.page_ladder)
-        ids = np.zeros(w, np.int32)
-        ids[:n_full] = pages
-        krows = np.zeros((L, w * pt, nh, D), np.float32)
-        vrows = np.zeros_like(krows)
-        krows[:, :n_full * pt] = np.asarray(k)[:, 0, :n_full * pt]
-        vrows[:, :n_full * pt] = np.asarray(v)[:, 0, :n_full * pt]
-        wexe = self._write_aot.get_or_compile(
-            self._kpool, self._vpool,
-            jax.ShapeDtypeStruct((L, w, pt, nh, D), jnp.float32),
-            jax.ShapeDtypeStruct((L, w, pt, nh, D), jnp.float32),
-            jax.ShapeDtypeStruct((w,), jnp.int32),
-            key=("pwrite", w))
-        self._kpool, self._vpool = wexe(
-            self._kpool, self._vpool,
-            jnp.asarray(krows.reshape(L, w, pt, nh, D)),
-            jnp.asarray(vrows.reshape(L, w, pt, nh, D)),
-            jnp.asarray(ids))
         self._prefix.insert(toks[:n_full * pt], pages)
         return pages
 
@@ -2056,9 +2047,9 @@ class DecodeEngine:
 
         Prefix hit: map the cached pages (refcount++), queue the
         uncached prompt tail to be fed through the batched decode step
-        — no prefill, no device work here at all. Miss: classic B=1
-        prefill at the prompt rung, scatter the panel into fresh pages,
-        deliver the first sampled token immediately. True if the
+        — no prefill, no device work here at all. Miss: fresh pages,
+        one fused B=1 prefill-into-pages dispatch at the prompt rung,
+        the first sampled token delivered immediately. True if the
         request now occupies a decode slot.
 
         A preempted request resumes through this same path over
@@ -2128,64 +2119,30 @@ class DecodeEngine:
 
     def _admit_prefill(self, req: _Req, toks: List[int],
                        note: dict) -> bool:
-        """The miss path of `_admit`: B=1 prefill at the prompt's kv
-        rung, the K/V panel through the host into fresh pages, the
-        first token. Each phase is a `decode.admit.*` span."""
+        """The miss path of `_admit`: fresh pages, then ONE dispatch
+        that prefills at the prompt's kv rung and writes the K/V panel
+        into those pages where it was computed, then the first token.
+        Each phase is a `decode.admit.*` span (the dispatch is
+        `exec:decode.prefill`)."""
         plen = len(toks)
-        pt = self.page_tokens
-        rung = note["rung"] = next_bucket(plen, self.kv_ladder)
-        inp = np.zeros((1, rung), np.int32)
-        inp[0, :plen] = toks
-        exe = self._prefill_aot.get_or_compile(
-            self.params,
-            jax.ShapeDtypeStruct((1, rung), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-            key=("prefill", 1, rung))
+        note["rung"] = next_bucket(plen, self.kv_ladder)
+        n_pages = -(-plen // self.page_tokens)
+        with _RING.span("decode.admit.alloc", {"pages": n_pages}):
+            try:
+                req.pages = self._alloc_pages(n_pages, req)
+            except TypedServeError as err:
+                req.stream._push_error(err)
+                self._m["evictions"].labels(reason="exhausted").inc()
+                return False
         t0 = time.perf_counter()
-        logits, k, v = exe(self.params, jnp.asarray(inp),
-                           jnp.asarray([plen], np.int32))
+        logits, self._kpool, self._vpool = self._prefill_into_pages(
+            self._prefill_aot, self.params, self._kpool, self._vpool,
+            toks, req.pages)
         with _RING.span("decode.admit.logits_pull"):
             row = np.asarray(logits)[0]
         req.prefill_s = time.perf_counter() - t0
         self._m["prefills"].inc()
         self._m["prefill_latency"].observe(req.prefill_s)
-        n_pages = -(-plen // pt)
-        with _RING.span("decode.admit.alloc", {"pages": n_pages}):
-            try:
-                pages = self._alloc_pages(n_pages, req)
-            except TypedServeError as err:
-                req.stream._push_error(err)
-                self._m["evictions"].labels(reason="exhausted").inc()
-                return False
-        # scatter the panel into the pages (zero padding past plen —
-        # rung garbage must never enter the pool; table padding -> null)
-        L, nh, D = self.cfg.layers, self.cfg.heads, self.cfg.head_dim
-        w = -(-rung // pt)
-        with _RING.span("decode.admit.kv_pull", {}) as pull:
-            k, v = np.asarray(k), np.asarray(v)
-            pull.args["bytes"] = k.nbytes + v.nbytes
-        with _RING.span("decode.admit.repack", {}) as repack:
-            ids = np.zeros(w, np.int32)
-            ids[:len(pages)] = pages
-            krows = np.zeros((L, w * pt, nh, D), np.float32)
-            vrows = np.zeros_like(krows)
-            krows[:, :plen] = k[:, 0, :plen]
-            vrows[:, :plen] = v[:, 0, :plen]
-            repack.args["bytes"] = krows.nbytes + vrows.nbytes
-        wexe = self._write_aot.get_or_compile(
-            self._kpool, self._vpool,
-            jax.ShapeDtypeStruct((L, w, pt, nh, D), jnp.float32),
-            jax.ShapeDtypeStruct((L, w, pt, nh, D), jnp.float32),
-            jax.ShapeDtypeStruct((w,), jnp.int32),
-            key=("pwrite", w))
-        with _RING.span("decode.admit.upload",
-                        {"bytes": krows.nbytes + vrows.nbytes + ids.nbytes}):
-            krows = jnp.asarray(krows.reshape(L, w, pt, nh, D))
-            vrows = jnp.asarray(vrows.reshape(L, w, pt, nh, D))
-            ids = jnp.asarray(ids)
-        self._kpool, self._vpool = wexe(
-            self._kpool, self._vpool, krows, vrows, ids)
-        req.pages = pages
         with _RING.span("decode.admit.emit"):
             return self._admit_emit(req, toks, row)
 
@@ -2611,14 +2568,9 @@ class SpecDecodeEngine(DecodeEngine):
         super().warmup(verbose=False)
         i32 = jnp.int32
         pool, dpool = self._pool_sds(), self._dpool_sds()
-        pt = self.page_tokens
         for r in self.kv_ladder:
-            self._dprefill_aot.get_or_compile(
-                self._draft_params, dpool, dpool,
-                jax.ShapeDtypeStruct((1, r), i32),
-                jax.ShapeDtypeStruct((1, -(-r // pt)), i32),
-                jax.ShapeDtypeStruct((1,), i32),
-                key=("dprefill", 1, r))
+            self._prefill_exe(self._dprefill_aot, self._draft_params,
+                              dpool, dpool, r)
         self._dcopy_aot.get_or_compile(
             dpool, dpool,
             jax.ShapeDtypeStruct((), i32), jax.ShapeDtypeStruct((), i32),
@@ -2685,24 +2637,9 @@ class SpecDecodeEngine(DecodeEngine):
         the rows hold committed K/V — the one thing every mapper of a
         shared prefix page agrees on."""
         seq = (req.prompt + req.generated)[:req.cache_len]
-        plen = len(seq)
-        pt = self.page_tokens
-        rung = next_bucket(plen, self.kv_ladder)
-        toks = np.zeros((1, rung), np.int32)
-        toks[0, :plen] = seq
-        w = -(-rung // pt)
-        tables = np.zeros((1, w), np.int32)
-        tables[0, :len(req.pages)] = req.pages
-        exe = self._dprefill_aot.get_or_compile(
-            self._draft_params, self._dkpool, self._dvpool,
-            jax.ShapeDtypeStruct((1, rung), jnp.int32),
-            jax.ShapeDtypeStruct((1, w), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-            key=("dprefill", 1, rung))
-        _, self._dkpool, self._dvpool = exe(
-            self._draft_params, self._dkpool, self._dvpool,
-            jnp.asarray(toks), jnp.asarray(tables),
-            jnp.asarray([plen], np.int32))
+        _, self._dkpool, self._dvpool = self._prefill_into_pages(
+            self._dprefill_aot, self._draft_params,
+            self._dkpool, self._dvpool, seq, req.pages)
 
     def _preempt_stash(self, req: _Req):
         """Stash only PROMPT-region pages at preemption. Generated-region

@@ -1133,9 +1133,10 @@ def gpt_paged_decode_fns(cfg: GPTConfig, eps: float = 1e-5,
                          page_tokens: int = 16):
     """Pure `(prefill, paged_step)` over a PAGED KV cache.
 
-    `prefill` is gpt_decode_fns' — the contiguous panel it returns is
-    written into pool pages by the engine. The step replaces the
-    per-slot contiguous panel with a shared page pool + block tables:
+    `prefill` is gpt_decode_fns' — `gpt_paged_prefill_fns` wraps it to
+    land the contiguous panel it returns in pool pages. The step
+    replaces the per-slot contiguous panel with a shared page pool +
+    block tables:
 
     paged_step(params,
                k_pool, v_pool [layers, P, page_tokens, heads, head_dim],
@@ -1312,25 +1313,35 @@ def gpt_paged_verify_fns(cfg: GPTConfig, eps: float = 1e-5,
 
 
 def gpt_paged_prefill_fns(cfg: GPTConfig, eps: float = 1e-5,
-                          page_tokens: int = 16):
+                          page_tokens: int = 16,
+                          name: str = "paged_prefill"):
     """Pure fused prefill-into-pages: one executable computes the
     prompt's K/V panel (the parallel `gpt_decode_fns` prefill) AND
-    scatters its rows into pool pages — replacing the three-hop
-    prefill -> host panel copy -> page-write admission path with a
-    single dispatch.
+    scatters it into pool pages, so an admission is a single dispatch
+    and nothing of K or V crosses to the host. The target model's
+    admission, the KV-handoff export and the draft model's prefill all
+    run it.
 
     paged_prefill(params,
                   k_pool, v_pool [layers, P, page_tokens, heads, head_dim],
                   toks   [1, R] int32 (prompt padded to the rung),
-                  tables [1, W] int32 (W = ceil(R / page_tokens)),
+                  tables [1, W] int32 (W >= ceil(R / page_tokens)),
                   n      [1]    int32 (true prompt length)
         -> (logits [1, V], k_pool, v_pool)
 
-    Row r lands at page tables[0, r//pt], offset r%pt; padding rows at
-    or past `n` redirect to the null page, so a short prompt in a wide
-    rung never dirties pages it does not own. `logits` is the prefill's
+    The panel is cut into whole pages and page j lands on tables[0, j]
+    (`write_pages`, one index per page): rows at or past `n` are written
+    as zeros, so rung garbage never enters the pool and a page's tail
+    holds nothing stale; table padding aims at the null page, which
+    takes whatever falls there. An int8 pool quantizes the pages per
+    (row, head) inside the same executable. `logits` is the prefill's
     last-position head — callers that only want the K/V ignore it.
+
+    `name` is the returned function's name, and so the compiled
+    program's in a device trace (`jit_<name>`): an engine that runs two
+    of these (target and draft) names them apart.
     """
+    from ..memory.page_allocator import write_pages
     pt = int(page_tokens)
     prefill, _ = gpt_decode_fns(cfg, eps=eps)
 
@@ -1338,17 +1349,21 @@ def gpt_paged_prefill_fns(cfg: GPTConfig, eps: float = 1e-5,
         R = toks.shape[1]
         W = tables.shape[1]
         logits, k, v = prefill(params, toks, n)
-        rows = jnp.arange(R, dtype=jnp.int32)
-        valid = rows < n[0]
-        slot = jnp.minimum(rows // pt, W - 1)
-        page_idx = jnp.where(valid, tables[0, slot], 0)
-        offset = rows % pt
-        k_pool = _kv_pool_write(k_pool, slice(None), page_idx, offset,
-                                k[:, 0])
-        v_pool = _kv_pool_write(v_pool, slice(None), page_idx, offset,
-                                v[:, 0])
-        return logits, k_pool, v_pool
+        live = (jnp.arange(R, dtype=jnp.int32) < n[0])[None, :, None, None]
 
+        def pages(panel):              # [L, 1, R, nh, D] -> [L, W, pt, nh, D]
+            rows = jnp.where(live, panel[:, 0], 0.0)
+            rows = jnp.pad(rows, ((0, 0), (0, W * pt - R), (0, 0), (0, 0)))
+            rows = rows.reshape(rows.shape[0], W, pt, *rows.shape[2:])
+            if isinstance(k_pool, tuple):
+                from ..quant.kv import quantize_kv
+                return quantize_kv(rows)
+            return rows
+
+        return (logits, write_pages(k_pool, pages(k), tables[0]),
+                write_pages(v_pool, pages(v), tables[0]))
+
+    paged_prefill.__name__ = paged_prefill.__qualname__ = name
     return paged_prefill
 
 

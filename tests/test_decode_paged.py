@@ -108,6 +108,142 @@ def test_kv_capacity_ladder_floor_follows_page_size():
     assert kv_capacity_ladder(8, floor=16) == [8]
 
 
+# ------------------------------------------ fused prefill-into-pages
+
+_PT = 4
+_FUSED_CFG = GPTConfig(vocab_size=256, max_seq_len=30, hidden=32, layers=3,
+                       heads=2, scan_layers=False)
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+@pytest.mark.parametrize(
+    "plen", [1, _PT - 1, _PT, _PT + 1, 16, 29],
+    ids=["one", "pt-1", "pt", "pt+1", "rung-last", "rung-not-page-multiple"])
+def test_fused_prefill_writes_what_the_three_hops_wrote(plen, kv_dtype):
+    """`gpt_paged_prefill_fns` against the path it replaced: the logits
+    are `gpt_decode_fns.prefill`'s, the request's pages hold
+    `write_pages` of the zero-padded panel (quantized per (row, head)
+    for an int8 pool) bit for bit, and every other page but the null
+    page is untouched."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import framework
+    from paddle_tpu.inference.batching import next_bucket
+    from paddle_tpu.models.gpt import gpt_decode_fns, gpt_paged_prefill_fns
+    from paddle_tpu.quant.kv import quantize_kv
+
+    cfg, pt = _FUSED_CFG, _PT
+    paddle.seed(11)
+    params = {k: jnp.asarray(v)
+              for k, v in framework.param_arrays(GPT(cfg)).items()}
+    rung = next_bucket(plen, kv_capacity_ladder(cfg.max_seq_len, floor=pt))
+    assert rung == {16: 16, 29: 30}.get(plen, rung)
+    w = -(-rung // pt)
+    n_pages = -(-plen // pt)
+    rs = np.random.RandomState(plen)
+    toks = np.zeros((1, rung), np.int32)
+    toks[0, :plen] = rs.randint(0, cfg.vocab_size, size=plen)
+    # garbage in the rung's padding must not reach the pool
+    toks[0, plen:] = rs.randint(0, cfg.vocab_size, size=rung - plen)
+    P = w + 4
+    pages = list(rs.permutation(np.arange(1, P))[:n_pages])
+    table = np.zeros((1, w), np.int32)
+    table[0, :n_pages] = pages
+    shape = (cfg.layers, P, pt, cfg.heads, cfg.head_dim)
+
+    def dirty_pool(seed):
+        r = np.random.RandomState(seed)
+        if kv_dtype == "int8":
+            return (jnp.asarray(r.randint(-127, 128, size=shape), jnp.int8),
+                    jnp.asarray(r.rand(*shape[:-1]), jnp.float32))
+        return jnp.asarray(r.randn(*shape), jnp.float32)
+
+    k0, v0 = dirty_pool(1), dirty_pool(2)
+    n = jnp.asarray([plen], jnp.int32)
+    fused = jax.jit(gpt_paged_prefill_fns(cfg, page_tokens=pt))
+    logits, k1, v1 = fused(params, k0, v0, jnp.asarray(toks),
+                           jnp.asarray(table), n)
+
+    prefill, _ = gpt_decode_fns(cfg)
+    want_logits, k, v = jax.jit(prefill)(params, jnp.asarray(toks), n)
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(want_logits))
+    others = [p for p in range(1, P) if p not in pages]
+    for got, before, panel in ((k1, k0, k), (v1, v0, v)):
+        rows = np.zeros((cfg.layers, w * pt, cfg.heads, cfg.head_dim),
+                        np.float32)
+        rows[:, :plen] = np.asarray(panel)[:, 0, :plen]
+        rows = jnp.asarray(rows.reshape(cfg.layers, w, pt, cfg.heads,
+                                        cfg.head_dim))
+        if kv_dtype == "int8":
+            rows = jax.jit(quantize_kv)(rows)
+        want = jax.jit(write_pages)(before, rows, jnp.asarray(table[0]))
+        for g, wnt, b in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                             jax.tree.leaves(before)):
+            g, wnt, b = np.asarray(g), np.asarray(wnt), np.asarray(b)
+            assert g.dtype == b.dtype and g.shape == b.shape
+            np.testing.assert_array_equal(g[:, pages], wnt[:, pages])
+            np.testing.assert_array_equal(g[:, others], b[:, others])
+        # the tail of a partial page is zero, not what the page held
+        data = np.asarray(jax.tree.leaves(got)[0])
+        tail = data[:, pages[-1], plen - (n_pages - 1) * pt:]
+        assert not tail.any()
+
+
+def test_admission_is_one_dispatch_and_no_host_trip(gpt_models):
+    """A miss admission's ring: `exec:decode.prefill` once, and nothing
+    of the K/V panel's old trip through numpy or its second dispatch."""
+    from paddle_tpu.observability.tracez import RING
+
+    eng = DecodeEngine(gpt_models["tiny-scan"], max_slots=2,
+                       max_new_tokens=4, page_tokens=4)
+    try:
+        tid = eng._thread.ident
+        assert len(eng.submit(np.arange(1, 8),
+                              max_new_tokens=3).result(timeout=120)) == 3
+    finally:
+        eng.stop()
+    events = [(name, ts, ts + dur) for ph, name, ts, dur, etid, _ in
+              RING.snapshot()[0] if ph == "X" and etid == tid]
+    (adm,) = [e for e in events if e[0] == "decode.admit"][-1:]
+    inner = [name for name, t0, t1 in events
+             if adm[1] <= t0 and t1 <= adm[2] and name != "decode.admit"
+             and not name.startswith("compile:")]      # it was not warmed
+    assert inner.count("exec:decode.prefill") == 1
+    assert sorted(inner) == sorted(
+        ["decode.admit.lookup", "decode.admit.alloc", "exec:decode.prefill",
+         "decode.admit.logits_pull", "decode.admit.emit"])
+    for gone in ("decode.admit.kv_pull", "decode.admit.repack",
+                 "decode.admit.upload", "exec:decode.pwrite"):
+        assert not any(name == gone for name, _, _ in events), gone
+
+
+def test_prefill_program_is_named_for_the_trace(gpt_models):
+    """A device trace calls a program `jit_<function name>`; readers of
+    the trace pair the target's fused prefill with its ring label
+    `exec:decode.prefill` as `jit_prefill`, and the step as
+    `jit_paged_step`. Any other `name=` goes through the same way."""
+    import jax
+
+    from paddle_tpu.models.gpt import gpt_paged_prefill_fns
+
+    eng = DecodeEngine(gpt_models["tiny-scan"], max_slots=2,
+                       max_new_tokens=4, page_tokens=4)
+    try:
+        pool = eng._pool_sds()
+        exe = eng._prefill_exe(eng._prefill_aot, eng.params, pool, pool,
+                               eng.kv_ladder[0])
+        assert exe.as_text().startswith("HloModule jit_prefill,")
+        assert eng._prefill_aot._label == "decode.prefill"
+        assert eng._step_aot._jitted.__name__ == "paged_step"
+    finally:
+        eng.stop()
+    assert gpt_paged_prefill_fns(eng.cfg).__name__ == "paged_prefill"
+    draft = jax.jit(gpt_paged_prefill_fns(eng.cfg, name="draft_prefill"))
+    assert draft.__name__ == "draft_prefill"
+
+
 # ------------------------------------- paged == contiguous equivalence
 
 @pytest.mark.parametrize("name", [n for n, _ in _CFGS])
@@ -271,7 +407,7 @@ def test_page_exhaustion_fails_only_victim(gpt_models):
 def test_ring_spans_tile_the_scheduler_loop():
     """The engine thread's ring spans after a short run: every
     `decode.loop` contains its schedule, admissions and tick and counts
-    them; a miss admission is tiled by its seven phases, in order, and
+    them; a miss admission is tiled by its five phases, in order, and
     carries how long the request queued; the tick's phases cover at
     least 95% of a `decode.step`."""
     import time
@@ -317,10 +453,8 @@ def test_ring_spans_tile_the_scheduler_loop():
     assert sum(s[2]["pending"] for s in spans["decode.schedule"]) >= 3
     assert spans["decode.idle"]              # it waited for the first
 
-    phases = ["decode.admit.lookup", "exec:decode.prefill",
-              "decode.admit.logits_pull", "decode.admit.alloc",
-              "decode.admit.kv_pull", "decode.admit.repack",
-              "decode.admit.upload", "exec:decode.pwrite",
+    phases = ["decode.admit.lookup", "decode.admit.alloc",
+              "exec:decode.prefill", "decode.admit.logits_pull",
               "decode.admit.emit"]
     for adm, plen in zip(admits, (5, 9, 6)):
         args = adm[2]
@@ -331,9 +465,7 @@ def test_ring_spans_tile_the_scheduler_loop():
         assert all(len(c) == 1 for c in inner), inner
         starts = [c[0][0] for c in inner]
         assert starts == sorted(starts)
-        assert inner[3][0][2] == {"pages": -(-plen // 4)}
-        assert inner[4][0][2]["bytes"] > 0 and inner[6][0][2]["bytes"] \
-            > inner[5][0][2]["bytes"] > 0
+        assert inner[1][0][2] == {"pages": -(-plen // 4)}
 
     tiles = ["decode.step.provision", "decode.step.build",
              "exec:decode.pstep", "decode.step.pull", "decode.sample"]

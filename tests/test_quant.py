@@ -19,9 +19,9 @@ import paddle_tpu.nn.functional as F
 import paddle_tpu.optimizer as opt
 from paddle_tpu import framework, profiler
 from paddle_tpu.inference.decode import (DecodeEngine, SpecDecodeEngine,
-                                         _copy_kv_page, _write_kv_pages,
-                                         kv_page_bytes, load_for_decode,
-                                         save_for_decode)
+                                         _copy_kv_page, kv_page_bytes,
+                                         load_for_decode, save_for_decode)
+from paddle_tpu.memory.page_allocator import write_pages
 from paddle_tpu.models.gpt import GPT, GPTConfig, gpt_tiny
 from paddle_tpu.quant import (Int8Linear, PTQ, QAT, QATLinear, SCALE_SUFFIX,
                               dequantize_kv, dequantize_params,
@@ -231,9 +231,11 @@ def test_kv_dtype_validation_and_page_bytes_math():
 
 
 def test_int8_pool_write_and_copy_pytree():
-    """The int8 pool is a (data, scale) pytree: the engine's write/COW
-    entry points must quantize rows in-executable and move both leaves
-    together, leaving untouched pages zero in both."""
+    """The int8 pool is a (data, scale) pytree: `write_pages` lands
+    quantized rows (the fused prefill quantizes them in-executable;
+    test_decode_paged compares the two bit for bit) and the engine's
+    COW entry point moves both leaves together, leaving untouched pages
+    zero in both."""
     shape = (2, 4, 3, 2, 8)                    # [L, P, pt, H, D]
     kp = kv_pool_zeros(shape, "int8")
     vp = kv_pool_zeros(shape, "int8")
@@ -244,8 +246,9 @@ def test_int8_pool_write_and_copy_pytree():
         rng2.normal(size=(2, 2, 3, 2, 8)).astype(np.float32))
     v_rows = jnp.asarray(
         rng2.normal(size=(2, 2, 3, 2, 8)).astype(np.float32))
-    kp, vp = _write_kv_pages(kp, vp, k_rows, v_rows,
-                             jnp.asarray([2, 1], jnp.int32))
+    ids = jnp.asarray([2, 1], jnp.int32)
+    kp = write_pages(kp, quantize_kv(k_rows), ids)
+    vp = write_pages(vp, quantize_kv(v_rows), ids)
     got = dequantize_kv(kp[0][:, 2], kp[1][:, 2])
     err = np.abs(np.asarray(got) - np.asarray(k_rows[:, 0]))
     assert (err <= np.asarray(kp[1][:, 2])[..., None] * 0.5 + 1e-7).all()
