@@ -15,8 +15,7 @@ import time
 
 import numpy as np
 
-from chipbench import harness, stats, traffic, weights
-from chipbench.reference import gpt as reference
+from chipbench import harness, stats, traffic
 
 
 class Marks:
@@ -38,47 +37,24 @@ def run(bench, cell, mix, seed, seconds, trace, t_process_start,
     clock = harness.SetupClock(t_process_start)
     devs = harness.require_devices(cell["chips"], require_tpu)
     import jax
-    import jax.numpy as jnp
 
     import paddle_tpu as paddle
-    import paddle_tpu.nn as nn
     import paddle_tpu.optimizer as opt
-    from paddle_tpu import framework, profiler
+    from paddle_tpu import profiler
     from paddle_tpu.distributed.fleet import DistributedStrategy
     from paddle_tpu.hapi import Model, callbacks as hapi_cbks
     from paddle_tpu.io import DataLoader, IterableDataset
     from paddle_tpu.jit.compile_cache import setup_compilation_cache
-    from paddle_tpu.models.gpt import GPT, GPTConfig
     from paddle_tpu.static import InputSpec
 
     setup_compilation_cache()
     clock.mark("import")
-    _, sizes = harness.load_config(bench, cell["config"])
+    _, sizes, family = harness.load_config(bench, cell["config"])
     B, T = int(mix["batch_size"]), int(mix["seq_len"])
     if mix["optimizer"] != "adam" or mix["amp"] != "O2":
         raise ValueError("train driver: only adam under AMP O2 is wired")
-    cfg = GPTConfig(vocab_size=sizes["vocab_size"],
-                    max_seq_len=sizes["max_seq_len"],
-                    hidden=sizes["hidden"], layers=sizes["layers"],
-                    heads=sizes["heads"])
     marks = Marks()
     preroll = int(mix["preroll_steps"])
-
-    class LMLoss(nn.Layer):
-        """forward(ids, labels) -> the GPT's LM loss."""
-
-        def __init__(self, m):
-            super().__init__()
-            self.m = m
-
-        def forward(self, ids, labels):
-            return self.m.loss(ids, labels)
-
-        def param_shardings(self, params, mesh_axis_tp="tp"):
-            inner = self.m.param_shardings(
-                {k[len("m."):]: v for k, v in params.items()},
-                mesh_axis_tp=mesh_axis_tp)
-            return {"m." + k: spec for k, spec in inner.items()}
 
     class Batches(IterableDataset):
         """Samples of seeded batches, B at a time, until the deadline
@@ -118,19 +94,14 @@ def run(bench, cell, mix, seed, seconds, trace, t_process_start,
 
     # weights: the program's own seeded constructor (the product path)
     paddle.seed(seed % (2 ** 31 - 1))
-    net = LMLoss(GPT(cfg))
-    net.train()
-    initial = {k[len("m."):]: v
-               for k, v in framework.param_arrays(net).items()}
+    net, initial = family.training_net(sizes)
     jax.block_until_ready(initial)
     clock.mark("weights")
 
     ids0, labels0 = traffic.train_batch(B, T, sizes["vocab_size"], seed, 0)
-    ref_params = weights.to_reference(initial)
-    ref_loss_fn = jax.jit(reference.loss, static_argnums=(3, 4))
-    ref_loss = float(np.mean([float(ref_loss_fn(
-        ref_params, jnp.asarray(ids0[i]), jnp.asarray(labels0[i]),
-        sizes["heads"], sizes["eps"])) for i in range(B)]))
+    ref_params = family.to_reference(initial)
+    ref_loss = float(np.mean([family.reference_loss(
+        ref_params, ids0[i], labels0[i], sizes) for i in range(B)]))
     del ref_params, initial
     clock.mark("reference_check")
 
@@ -161,7 +132,10 @@ def run(bench, cell, mix, seed, seconds, trace, t_process_start,
     rate = stats.rate(tokens, marks.t_open, marks.t_close)
     loss_err = abs(marks.first_loss - ref_loss)
     finite = bool(losses) and all(math.isfinite(v) for v in losses)
-    correct = (loss_err <= reference.LOSS_TOL and compiles_in_window == 0
+    checks = {"loss_abs_err": (loss_err, family.LOSS_TOL),
+              "compiles_in_window": (compiles_in_window, 0),
+              "losses_not_finite": (0 if finite else 1, 0)}
+    correct = (loss_err <= family.LOSS_TOL and compiles_in_window == 0
                and finite)
     values = {"train_tokens_per_s": rate, "setup_s": marks.setup_s}
     print("SETUP " + json.dumps(
@@ -180,6 +154,6 @@ def run(bench, cell, mix, seed, seconds, trace, t_process_start,
          "largest_temp_bytes": temp_bytes,
          "end_to_end": values}), flush=True)
     return harness.result_line(
-        bench, cell, mix, sizes, (marks.t_open, marks.t_close), trace,
-        values, correct, steps, 0 if finite else 1, devs, traced, temp_bytes,
-        ring=None, step_timeline=profiler.step_timeline())
+        bench, cell, mix, sizes, family, (marks.t_open, marks.t_close),
+        trace, values, correct, steps, 0 if finite else 1, devs, traced,
+        temp_bytes, checks, ring=None, step_timeline=profiler.step_timeline())

@@ -1,31 +1,10 @@
-"""Operations and bytes the algorithms need, computed from shapes.
-
-Kept here so that the yardstick does not move with the model file:
-``train_flops_per_token`` is a copy of ``GPT.flops_per_token``
-(6N + 12*L*H*T), and the kernel counts are what the mathematics needs,
+"""Operations and bytes the algorithms need, computed from shapes: the
+pieces that know no architecture. What a kernel's mathematics needs,
 not what an implementation happens to do: recomputed operations and
-padded or re-laid-out bytes do not count.
+padded or re-laid-out bytes do not count. The counts of a whole model
+(parameters, FLOPs per trained token, bytes of a decode step) belong to
+its family, ``chipbench/families/<family>.py``.
 """
-
-
-def gpt_param_count(cfg):
-    """Parameters of the pre-LN GPT with a tied head. `cfg` has
-    vocab_size, max_seq_len, hidden, layers (ffn is 4x hidden)."""
-    V, P, C, L = (cfg["vocab_size"], cfg["max_seq_len"], cfg["hidden"],
-                  cfg["layers"])
-    F = 4 * C
-    per_block = (C * 3 * C + 3 * C) + (C * C + C) + (C * F + F) \
-        + (F * C + C) + 4 * C
-    return V * C + P * C + L * per_block + 2 * C
-
-
-def train_flops_per_token(cfg, seq_len):
-    """Forward + backward FLOPs per trained token: 6 per parameter for
-    the weight matmuls, plus the attention score and value matmuls at
-    12 * layers * hidden * seq_len (2*T*hidden each, forward; x3 with
-    the backward pass)."""
-    return 6 * gpt_param_count(cfg) + 12 * cfg["layers"] * cfg["hidden"] \
-        * seq_len
 
 
 def flash_attention_cost(batch, heads, seq_len, head_dim, causal=True,
@@ -45,27 +24,6 @@ def flash_attention_cost(batch, heads, seq_len, head_dim, causal=True,
     if backward:
         return 4 * matmul, 8 * panel + 2 * rowstat
     return 2 * matmul, 4 * panel + rowstat
-
-
-def decode_weight_bytes(cfg, dtype_bytes=4):
-    """Bytes of weights one decode step must read: every block and the
-    tied head (the whole embedding matrix); of the position table only
-    one row per sequence, which is not counted."""
-    n = gpt_param_count(cfg) - cfg["max_seq_len"] * cfg["hidden"]
-    return n * dtype_bytes
-
-
-def kv_bytes_per_token(cfg, dtype_bytes=4):
-    """K and V of one cached position, all layers."""
-    return cfg["layers"] * 2 * cfg["hidden"] * dtype_bytes
-
-
-def decode_step_bytes(cfg, live_tokens, dtype_bytes=4):
-    """Least HBM traffic of one decode step over rows whose caches hold
-    `live_tokens` positions together: the weights once, plus the live
-    K/V rows once."""
-    return decode_weight_bytes(cfg, dtype_bytes) \
-        + live_tokens * kv_bytes_per_token(cfg, dtype_bytes)
 
 
 def roofline_share(flops, nbytes, seconds, peak):

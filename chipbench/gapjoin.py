@@ -3,10 +3,12 @@
 A reader gets the program's ring (host clock) and the normalised device
 planes of the trace (profiler clock), and nothing that ties the two
 clocks. What ties them is the order of dispatch: the device runs
-programs in the order the host dispatched them, and the three programs
-the engine compiles ahead of time each leave an ``exec:`` event in the
-ring. The traced sequence of those programs is placed in the ring's by
-kind and duration, searched only near the host time at which the
+programs in the order the host dispatched them, and the programs the
+engine compiles ahead of time each leave an ``exec:`` event in the
+ring; which programs those are is the family's to say (``PROGRAMS``:
+device program -> (ring event, its letter in the ``IDLE`` line)). The
+traced sequence of those programs is placed in the ring's by kind and
+duration, searched only near the host time at which the
 harness started the profiler. Device time ``t`` before program *k* is
 then host time ``t - start_dev[k] + start_ring[k]``: a gap that ends
 where program *k* starts is the host interval of the same length that
@@ -18,11 +20,6 @@ import json
 
 from . import ringread, spanread, trace_reduce
 
-PROGRAMS = {"paged_step": "exec:decode.pstep",
-            "prefill": "exec:decode.prefill",
-            "write_kv_pages": "exec:decode.pwrite"}
-LETTER = {"exec:decode.pstep": "s", "exec:decode.prefill": "p",
-          "exec:decode.pwrite": "w"}
 EARLY_S = 0.5           # the search starts this long before the start
 LATE_S = 2.0            # and ends this long after the traced seconds
 DEVICE_OVER_HOST_S = 5e-4   # a program cannot outlast the call that
@@ -30,24 +27,26 @@ DEVICE_OVER_HOST_S = 5e-4   # a program cannot outlast the call that
 GAP_FLOOR_NS = 1000.0   # as trace_reduce.idle_gaps
 
 
-def device_programs(trace):
+def device_programs(trace, named):
     """(modules, programs) of the first chip: every executed program as
-    (start_s, end_s) in time order, and those of `PROGRAMS` as
-    (ring name, start_s, duration_s)."""
+    (start_s, end_s) in time order, and those that `named` (device
+    program -> ring name) holds as (ring name, start_s, duration_s)."""
     planes = trace_reduce.device_planes(trace)
     if not planes:
         return [], []
     mods = sorted(trace_reduce._line(planes[0], trace_reduce.MODULES_LINE),
                   key=lambda e: e[1])
-    programs = [(PROGRAMS[trace_reduce.module_name(name)],
+    programs = [(named[trace_reduce.module_name(name)],
                  start / 1e9, dur / 1e9)
                 for name, start, dur in mods
-                if trace_reduce.module_name(name) in PROGRAMS]
+                if trace_reduce.module_name(name) in named]
     return [(s / 1e9, (s + d) / 1e9) for _, s, d in mods], programs
 
 
-def ring_programs(ring):
-    return sorted((start, dur, name) for name in LETTER
+def ring_programs(ring, names):
+    """The ring's `names` events as (start_s, duration_s, name), in
+    time order."""
+    return sorted((start, dur, name) for name in names
                   for start, dur in ringread.spans(ring, name))
 
 
@@ -86,10 +85,12 @@ def idle_intervals(modules, programs, calls, offset):
     return out
 
 
-def join(ring, trace, t_near, trace_seconds):
+def join(ring, trace, t_near, trace_seconds, family):
     """Seconds of device idleness per covering span, or (None, why)."""
-    modules, programs = device_programs(trace)
-    calls = ring_programs(ring)
+    letter = dict(family.PROGRAMS.values())
+    modules, programs = device_programs(
+        trace, {prog: event for prog, (event, _) in family.PROGRAMS.items()})
+    calls = ring_programs(ring, letter)
     if not programs or not calls:
         return None, None
     t_from, t_to = t_near - EARLY_S, t_near + trace_seconds + LATE_S
@@ -98,8 +99,8 @@ def join(ring, trace, t_near, trace_seconds):
         near = [c for c in calls
                 if t_from <= c[0] <= t_to + trace_seconds]
         return None, {"placements": len(fits),
-                      "device": "".join(LETTER[p[0]] for p in programs),
-                      "ring": "".join(LETTER[c[2]] for c in near)}
+                      "device": "".join(letter[p[0]] for p in programs),
+                      "ring": "".join(letter[c[2]] for c in near)}
     pieces = spanread.segments(ring, spanread.engine_thread(ring))
     out = {"idle_s": 0.0, "admit_s": 0.0, "tick_s": 0.0, "unnamed_s": 0.0,
            "programs": len(programs), "leaf_s": {}}
@@ -131,7 +132,8 @@ def joined(ctx):
         length = float((ctx.get("mix") or {}).get("trace_seconds", 3.0))
         t_near = ctx["t_open"] + max(
             (ctx["t_close"] - ctx["t_open"] - length) / 2.0, 0.0)
-        got, why = join(ctx.get("ring"), ctx.get("trace"), t_near, length)
+        got, why = join(ctx.get("ring"), ctx.get("trace"), t_near, length,
+                        ctx["family"])
         ctx["gapjoin"] = got
         if got is not None or why is not None:
             line = why if got is None else dict(

@@ -1,9 +1,11 @@
 """What every driver shares: the cell's files, the device check, the
 set-up clock, the mid-window profiler, the per-layer metric readers and
 the result line."""
+import gc
 import importlib.util
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -15,6 +17,7 @@ from . import peaks, trace_reduce
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 NO_CHIP_RC = 3
+_FAMILY_NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 
 
 def load_benchmark():
@@ -37,20 +40,96 @@ def cell_metrics(bench, cell_name, group):
             if "workloads" not in m or cell_name in m["workloads"]]
 
 
+# Where `load_family` looks for `<family>.py`, first match wins. A test
+# that brings a stand-in family appends its directory.
+FAMILY_PATH = [os.path.join(HERE, "families")]
+
+
+def load_family(name):
+    """The module that owns everything architecture-specific of the
+    family `name` (``chipbench/families/gpt.py`` shows the members)."""
+    if not isinstance(name, str) or not _FAMILY_NAME.match(name):
+        raise ValueError(f"chipbench: not a family name: {name!r}")
+    for base in FAMILY_PATH:
+        path = os.path.join(base, name + ".py")
+        if os.path.exists(path):
+            return _load_file(path, "chipbench_family_" + name)
+    raise ValueError(f"chipbench: no family {name!r} under {FAMILY_PATH}")
+
+
+# The kinds of cut that the guide model-configs, section 4, allows (the
+# chip's share of a stated deployment, and depth), each with the floor
+# a cut configuration keeps to: held here, published -> whether it
+# holds. A family's `CUTS` says of which kind a key of its file is; a
+# key it does not list is a width or a shape, and is never cut.
+CUT_FLOORS = {
+    "depth": ("at least four layers after the leading dense ones",
+              lambda held, published, dense: held >= dense + 4),
+    "experts": ("at least 8 routed experts",
+                lambda held, published, dense: held >= 8),
+    "vocabulary": ("at least an eighth of the vocabulary",
+                   lambda held, published, dense: 8 * held >= published),
+    "heads": ("at least one head", lambda held, published, dense: held >= 1),
+}
+
+
+def cut_problems(reduced, raw, cuts):
+    """What is wrong with a configuration file `raw` as a statement of
+    how it was cut to size (guide model-configs, section 4), as a list
+    of sentences; empty when nothing is. `reduced`: the list of its
+    `BENCHMARK.json` entry; `cuts`: the family's `CUTS`, {key of the
+    file: kind of cut, a key of `CUT_FLOORS`}."""
+    bad = [f"the file lacks {key!r}"
+           for key in ("source", "family", "assumed", "deployment")
+           if key not in raw]
+    published = raw.get("published", {})
+    deployment = raw.get("deployment")
+    deployment = deployment if isinstance(deployment, dict) else {}
+    for key in reduced:
+        if cuts.get(key) not in CUT_FLOORS:
+            bad.append(f"{key!r} is not a key its family lets be cut "
+                       f"({sorted(cuts)}): no width is ever cut")
+        elif key not in raw:
+            bad.append(f"reduced key {key!r} is not a key of the file")
+        elif key not in published:
+            bad.append(f"\"published\" lacks the source's {key!r}")
+        elif not raw[key] < published[key]:
+            bad.append(f"{key!r} is listed as reduced and is not below "
+                       f"its published value")
+        else:
+            floor, holds = CUT_FLOORS[cuts[key]]
+            if not holds(raw[key], published[key], int(
+                    deployment.get("leading_dense_layers", 0))):
+                bad.append(f"{key!r} = {raw[key]} of {published[key]}: a "
+                           f"cut keeps {floor}")
+    for key in published:
+        if key not in reduced:
+            bad.append(f"{key!r} has a published value and is not in "
+                       f"\"reduced\"")
+    if reduced:
+        chips = deployment.get("chips_per_layer")
+        if not isinstance(chips, int) or chips < 1:
+            bad.append("a cut file's \"deployment\" is an object whose "
+                       "\"chips_per_layer\" says over how many chips a "
+                       "layer is divided")
+    return bad
+
+
 def load_config(bench, name):
-    """(raw config file, sizes as the formulas and drivers use them)."""
+    """(raw configuration file, its sizes as its family reads them, the
+    family's module). A file whose cut is not declared as
+    `cut_problems` wants it is refused."""
     entry = next(c for c in bench["configs"] if c["name"] == name)
     with open(os.path.join(ROOT, entry["file"])) as f:
         raw = json.load(f)
-    sizes = {
-        "vocab_size": int(raw["assumed"]["padded_vocab_size"]),
-        "max_seq_len": int(raw["n_positions"]),
-        "hidden": int(raw["n_embd"]),
-        "layers": int(raw["n_layer"]),
-        "heads": int(raw["n_head"]),
-        "eps": float(raw["layer_norm_epsilon"]),
-    }
-    return raw, sizes
+    if "family" not in raw:
+        raise ValueError(f"{entry['file']}: a configuration names its "
+                         f"\"family\"")
+    family = load_family(raw["family"])
+    bad = cut_problems(entry.get("reduced", []), raw, family.CUTS)
+    if bad:
+        raise ValueError(f"{entry['file']}: " + "; ".join(bad))
+    return raw, family.sizes(raw), family
 
 
 def load_driver(name):
@@ -174,6 +253,33 @@ class MidWindowTrace:
             shutil.rmtree(self._dir, ignore_errors=True)
 
 
+class GcWatch:
+    """The interpreter's garbage collections while it is installed:
+    `pauses` holds (generation, seconds) of each. A full collection
+    walks every container object of the process and stops every thread
+    meanwhile, the engine's too, so a run that reads low shows here."""
+
+    def __init__(self):
+        self.pauses = []
+        self._t0 = None
+        gc.callbacks.append(self._note)
+
+    def _note(self, phase, info):
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.pauses.append((info["generation"],
+                                time.perf_counter() - self._t0))
+
+    def stop(self):
+        gc.callbacks.remove(self._note)
+        full = [s for gen, s in self.pauses if gen == 2]
+        return {"collections": len(self.pauses), "full": len(full),
+                "full_ms": round(1e3 * sum(full), 1),
+                "longest_ms": round(1e3 * max(
+                    (s for _, s in self.pauses), default=0.0), 1)}
+
+
 def ring_events(t_open, t_close):
     """The program's always-on event ring between the marks, or None
     when the ring dropped events (a metric read from a ring with holes
@@ -201,19 +307,20 @@ def read_metrics(names, ctx):
     return out
 
 
-def result_line(bench, cell, mix, sizes, window, trace, end_to_end,
+def result_line(bench, cell, mix, sizes, family, window, trace, end_to_end,
                 correct, attempted, failed, devs, traced=None, temp_bytes=0,
-                **seen):
+                checks=None, **seen):
     """The last line of standard output: with `trace` off the cell's
     end-to-end metrics, with it on its per-layer metrics, each read by
     its own file from what the driver has `seen` (ring events, records,
     counters) and from the trace, plus the breakdown of the traced
-    seconds."""
+    seconds. `checks`, {name: (number, limit)}, is every number that
+    decided `correct` beside its limit; it comes last in the line."""
     device = device_json(devs, temp_bytes)
     group, values = "end_to_end", end_to_end
     if trace:
         group = "per_layer"
-        ctx = dict(seen, cell=cell, mix=mix, sizes=sizes,
+        ctx = dict(seen, cell=cell, mix=mix, sizes=sizes, family=family,
                    t_open=window[0], t_close=window[1], trace=traced,
                    end_to_end=end_to_end, device=device, chips=len(devs),
                    peak=peaks.peak(devs[0].device_kind)
@@ -229,4 +336,25 @@ def result_line(bench, cell, mix, sizes, window, trace, end_to_end,
         device["busy_s"], device["window_s"] = \
             trace_reduce.device_busy(traced)
         out["breakdown"] = trace_reduce.breakdown(traced)
+    out["checks"] = {name: {"value": value, "limit": limit}
+                     for name, (value, limit) in (checks or {}).items()}
     return out
+
+
+def add_check(out, name, value, limit):
+    """One more number that decides `correct`, read after the result
+    line was built: `value` (None: nothing could be compared) has to be
+    at most `limit`."""
+    out["checks"][name] = {"value": value, "limit": limit}
+    out["correct"] = bool(out["correct"] and value is not None
+                          and value <= limit)
+
+
+def print_checks(out):
+    """Each number compared beside its limit, one to a line: the last
+    lines of a run's standard error. Of a run that is not correct the
+    driver's record keeps the end of standard error and the end of the
+    result line and nothing else, so both carry them."""
+    for name, c in out["checks"].items():
+        print(f"CHECK {name} = {c['value']!r} limit {c['limit']!r}",
+              file=sys.stderr, flush=True)

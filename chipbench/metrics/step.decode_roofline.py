@@ -1,20 +1,19 @@
 """Roofline share of the decode step: the bytes one step must move
-(the weights once, plus the live K/V rows of its sequences:
-``formulas.decode_step_bytes``) over the chip's peak bytes/s, in the
-step's mean device time from the trace (the ``paged_step`` program).
-The step is bound by bytes: at a handful of rows its FLOPs are
-nothing.
+(for a dense model the weights once, plus the live cached rows of its
+sequences: the family's ``decode_step_bytes``) over the chip's peak
+bytes/s, in the step's mean device time from the trace (the family's
+``STEP_PROGRAM``). The step is bound by bytes: at a handful of rows
+its FLOPs are nothing.
 
 Live rows and their context lengths are the client's own count: every
 token event inside the window is one row of one step, whose cache then
 held the prompt plus the tokens before it."""
 from chipbench import formulas, stats, trace_reduce
 
-STEP_PROGRAM = r"paged_step"
-
 
 def read(ctx):
-    durs = trace_reduce.module_durations(ctx["trace"], STEP_PROGRAM)
+    family = ctx["family"]
+    durs = trace_reduce.module_durations(ctx["trace"], family.STEP_PROGRAM)
     if not durs or ctx.get("peak") is None:
         return None
     contexts = [r["plen"] + i for r in ctx["records"]
@@ -25,7 +24,8 @@ def read(ctx):
     if not contexts or steps <= 0:
         return None
     live_tokens = sum(contexts) / steps       # per step, over its rows
-    need = formulas.decode_step_bytes(ctx["sizes"], live_tokens)
+    need = family.decode_step_bytes(ctx["sizes"], live_tokens,
+                                    rows=len(contexts) / steps)
     share, _ = formulas.roofline_share(0, need, stats.mean(durs),
                                        ctx["peak"])
     return share

@@ -3,12 +3,16 @@
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> \
         --trace <0|1>
 
-Looks the cell up in BENCHMARK.json, loads ``configs/<config>.json`` and
-``traffic/<traffic>.json`` by name, runs the driver the traffic file
-names (``drivers/<driver>.py``), and prints one JSON object as the last
+Looks the cell up in BENCHMARK.json, loads ``configs/<config>.json``
+(and through its ``"family"`` key ``families/<family>.py``, which owns
+all that knows the architecture) and ``traffic/<traffic>.json`` by
+name, runs the driver the traffic file names
+(``drivers/<driver>.py``), and prints one JSON object as the last
 line of standard output: with ``--trace 0`` the cell's end-to-end
 metrics, with ``--trace 1`` its per-layer metrics (one reader each under
-``metrics/``) and the breakdown of the traced seconds. It runs on the
+``metrics/``) and the breakdown of the traced seconds; every number
+that decided ``correct`` comes last in it, beside its limit, and again
+as the last lines of standard error. It runs on the
 machine it is started on and needs the chips the cell asks for: without
 them it exits non-zero and prints no result.
 """
@@ -44,6 +48,7 @@ def main(argv=None):
                      seconds=float(seconds), trace=bool(args.trace),
                      t_process_start=T_PROCESS_START)
     print(json.dumps(out), flush=True)
+    harness.print_checks(out)
     return 0
 
 

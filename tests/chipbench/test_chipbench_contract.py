@@ -74,6 +74,8 @@ def test_entries_have_just_their_keys(bench):
 
 
 def test_every_cell_finds_its_files(bench):
+    from chipbench import harness
+
     configs = {c["name"]: c for c in bench["configs"]}
     used = set()
     for w in bench["workloads"]:
@@ -82,10 +84,11 @@ def test_every_cell_finds_its_files(bench):
         assert cfg["file"].startswith("chipbench/configs/")
         with open(os.path.join(ROOT, cfg["file"])) as f:
             raw = json.load(f)
-        for key in ("n_embd", "n_layer", "n_head", "n_positions",
-                    "vocab_size", "assumed"):
-            assert key in raw, (cfg["file"], key)
-        assert not cfg["reduced"], "no width or depth is cut"
+        family = harness.load_family(raw["family"])
+        # the cut, if any, is declared as the guide's section 4 wants it
+        assert harness.cut_problems(cfg["reduced"], raw, family.CUTS) == []
+        assert raw["source"] == cfg["source"]
+        assert family.sizes(raw)["vocab_size"] > 0
         path = os.path.join(ROOT, "chipbench", "traffic",
                             w["traffic"] + ".json")
         with open(path) as f:
@@ -96,6 +99,80 @@ def test_every_cell_finds_its_files(bench):
     assert used == set(configs), "a configuration no cell uses"
     files = [c["file"] for c in bench["configs"]]
     assert len(files) == len(set(files))
+    assert configs["gpt2-124m"]["reduced"] == []
+
+
+# A cut as the guide's example makes it: depth, the experts held here
+# and the vocabulary slice reduced, every width as published, the
+# layers shared by eight chips.
+CUT = {"source": "https://example.org/config.json", "family": "gpt",
+       "hidden_size": 2048, "moe_intermediate_size": 1408,
+       "num_hidden_layers": 6, "n_routed_experts": 8, "vocab_size": 12800,
+       "published": {"num_hidden_layers": 27, "n_routed_experts": 64,
+                     "vocab_size": 102400},
+       "assumed": {}, "deployment": {
+           "chips_per_layer": 8,
+           "how": "experts and vocabulary rows over eight chips"}}
+CUT_KEYS = ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+CUTS = {"num_hidden_layers": "depth", "n_routed_experts": "experts",
+        "vocab_size": "vocabulary", "num_attention_heads": "heads"}
+
+
+def _without(d, key):
+    return {k: v for k, v in d.items() if k != key}
+
+
+def _held(**held):
+    return dict(CUT, **held)
+
+
+@pytest.mark.parametrize("reduced,raw,said", [
+    (CUT_KEYS, CUT, None),                                   # a good cut
+    ([], _without(CUT, "published"), None),                  # no cut
+    (CUT_KEYS + ["hidden_size"], dict(
+        CUT, hidden_size=1024,
+        published=dict(CUT["published"], hidden_size=2048)),
+     "'hidden_size' is not a key its family lets be cut"),
+    (CUT_KEYS + ["kv_lora_rank"], dict(
+        CUT, kv_lora_rank=128,
+        published=dict(CUT["published"], kv_lora_rank=512)),
+     "'kv_lora_rank' is not a key its family lets be cut"),
+    (CUT_KEYS, dict(CUT, published=_without(CUT["published"], "vocab_size")),
+     "lacks the source's 'vocab_size'"),
+    (CUT_KEYS, dict(CUT, published=dict(CUT["published"], vocab_size=12800)),
+     "'vocab_size' is listed as reduced and is not below"),
+    (CUT_KEYS + ["num_attention_heads"], CUT,
+     "'num_attention_heads' is not a key of the file"),
+    (CUT_KEYS[:2], CUT, "'vocab_size' has a published value"),
+    (CUT_KEYS, dict(CUT, deployment="eight chips share each layer"),
+     "chips_per_layer"),
+    (CUT_KEYS, _without(CUT, "assumed"), "the file lacks 'assumed'"),
+    # the guide's floors: what is left is still the model
+    (CUT_KEYS, _held(num_hidden_layers=3), "at least four layers"),
+    (CUT_KEYS, dict(CUT, num_hidden_layers=4, deployment=dict(
+        CUT["deployment"], leading_dense_layers=1)), "at least four layers"),
+    (CUT_KEYS, _held(n_routed_experts=4), "at least 8 routed experts"),
+    (CUT_KEYS, _held(vocab_size=12799), "at least an eighth"),
+])
+def test_a_cut_is_declared_and_keeps_to_its_floors(reduced, raw, said):
+    from chipbench import harness
+
+    bad = harness.cut_problems(reduced, raw, CUTS)
+    if said is None:
+        assert bad == []
+    else:
+        assert len(bad) == 1 and said in bad[0], bad
+
+
+def test_every_family_says_what_may_be_cut():
+    from chipbench import harness
+
+    for base in harness.FAMILY_PATH:
+        for f in sorted(os.listdir(base)):
+            if f.endswith(".py"):
+                family = harness.load_family(f[:-3])
+                assert family.CUTS and set(family.CUTS.values()) <= set(
+                    harness.CUT_FLOORS), f
 
 
 def test_four_chip_cells_within_their_share(bench):

@@ -1,7 +1,9 @@
 """The yardstick's arithmetic: operations, bytes, peaks."""
 import pytest
 
-from chipbench import formulas, peaks
+from chipbench import formulas, harness, peaks
+
+gpt = harness.load_family("gpt")
 
 TINY = {"vocab_size": 512, "max_seq_len": 128, "hidden": 64, "layers": 2,
         "heads": 4}
@@ -17,20 +19,20 @@ def test_param_count_is_the_programs():
 
     paddle.seed(0)
     m = GPT(gpt_tiny())
-    assert formulas.gpt_param_count(TINY) == m.num_params()
-    assert formulas.train_flops_per_token(TINY, 128) == \
+    assert gpt.param_count(TINY) == m.num_params()
+    assert gpt.train_flops_per_token(TINY, 128) == \
         m.flops_per_token(128)
 
 
 @pytest.mark.parametrize("cfg,lo,hi", [(GPT2, 120e6, 130e6),
                                        (GPT3, 1.30e9, 1.33e9)])
 def test_param_count_magnitudes(cfg, lo, hi):
-    assert lo < formulas.gpt_param_count(cfg) < hi
+    assert lo < gpt.param_count(cfg) < hi
 
 
 def test_train_flops_per_token_gpt2():
-    n = formulas.gpt_param_count(GPT2)
-    f = formulas.train_flops_per_token(GPT2, 1024)
+    n = gpt.param_count(GPT2)
+    f = gpt.train_flops_per_token(GPT2, 1024)
     assert f == 6 * n + 12 * 12 * 768 * 1024
     assert 855e6 < f < 865e6           # 860.1 MFLOP per token
 
@@ -64,12 +66,21 @@ def test_attention_flops_agree_with_the_model_formula():
 
 
 def test_decode_step_bytes():
-    w = formulas.decode_weight_bytes(GPT3)
+    w = gpt.decode_weight_bytes(GPT3)
     assert 5.2e9 < w < 5.3e9           # 5.24 GB of float32 weights
-    assert formulas.kv_bytes_per_token(GPT3) == 24 * 2 * 2048 * 4
-    assert formulas.decode_step_bytes(GPT3, 0) == w
-    assert formulas.decode_step_bytes(GPT3, 1000) - w == \
+    assert gpt.kv_bytes_per_token(GPT3) == 24 * 2 * 2048 * 4
+    assert gpt.decode_step_bytes(GPT3, 0) == w
+    assert gpt.decode_step_bytes(GPT3, 1000) - w == \
         1000 * 24 * 2 * 2048 * 4
+    # a dense model reads every weight whatever its rows are
+    assert gpt.decode_step_bytes(GPT3, 1000, rows=6) == \
+        gpt.decode_step_bytes(GPT3, 1000)
+
+
+def test_the_family_hands_the_kernel_its_own_heads():
+    assert gpt.flash_attention_costs(GPT2, 8, 1024) == [
+        formulas.flash_attention_cost(8, 12, 1024, 64, causal=True,
+                                      backward=b) for b in (False, True)]
 
 
 def test_roofline_share_says_which_bound():

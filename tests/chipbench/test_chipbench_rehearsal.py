@@ -82,6 +82,9 @@ def test_serve_driver_end_to_end(serve_plain):
     assert set(out["metrics"]) == {"serve_tokens_per_s", "ttft_p50_ms",
                                    "itl_p95_ms", "setup_s"}
     assert all(m["value"] > 0 for m in out["metrics"].values())
+    # what the window served, held against the reference after it
+    gap = out["checks"]["served_gap_mean"]
+    assert 0 <= gap["value"] < 1e-6 < gap["limit"]
 
 
 def test_serve_driver_traced_reports_per_layer_metrics_only(serve_traced):
@@ -139,6 +142,31 @@ def test_reachable_rungs_come_from_the_traffic_and_the_public_ladders():
     kv, pages, batch = serve.reachable(
         Engine, traffic.load("decode-closed-1x-slots"))
     assert kv == [64, 128, 256] and pages == [4, 8, 16, 32]
+
+
+def test_the_sample_is_drawn_from_the_seed_and_holds_the_longest():
+    from chipbench import harness
+
+    serve = harness.load_driver("serve")
+
+    def record(i, **kw):
+        return dict({"prompt": [0] * 8, "plen": 8, "max_new": 4,
+                     "t_submit": 10.0 + i, "times": [10.5 + i] * 4,
+                     "tokens": [1, 2, 3, 4], "done": True, "error": None},
+                    **kw)
+
+    records = [record(i) for i in range(40)]
+    records[17] = record(17, prompt=[0] * 24, plen=24)         # the longest
+    records[3] = record(3, done=False, tokens=None)            # in flight
+    records[4] = record(4, tokens=[1, 2, 3], times=[14.5] * 3)  # broken
+    records[5] = record(5, t_submit=5.0)               # before the window
+    a = serve.finished_sample(records, 9.0, 60.0, SEED, 8)
+    assert len(a) == 8 and records[17] in a
+    assert not any(r in a for r in (records[3], records[4], records[5]))
+    assert a == serve.finished_sample(records, 9.0, 60.0, SEED, 8)
+    assert a != serve.finished_sample(records, 9.0, 60.0, SEED + 1, 8)
+    assert len(serve.finished_sample(records, 9.0, 60.0, SEED, 64)) == 37
+    assert serve.finished_sample(records, 100.0, 160.0, SEED, 8) == []
 
 
 def test_the_measured_path_without_a_chip_fails():

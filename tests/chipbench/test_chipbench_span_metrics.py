@@ -4,14 +4,24 @@ cannot be placed), and on the CPU rehearsal, where there is no device
 plane and the ``idle.*`` readers return nothing."""
 import json
 import time
+import types
 
 import pytest
 
-RING_METRICS = ["queue.wait_ms_p50", "admit.repack_ms_p50",
-                "admit.pwrite_ms_p50", "tick.build_ms_p50",
+RING_METRICS = ["queue.wait_ms_p50", "tick.build_ms_p50",
                 "tick.pull_ms_p50", "tick.sample_ms_p50",
                 "loop.overhead_ms_p50", "loop.unspanned_share"]
 IDLE_METRICS = ["idle.admit_share", "idle.tick_share", "idle.unnamed_share"]
+# The ring and the trace below are of an engine with THREE programs (an
+# admission of two dispatches, as the program had until PR 26): the
+# join takes the programs' names from the family, so a stand-in names
+# them; `test_the_gpt_family_names_two_programs` holds the real one.
+THREE = types.SimpleNamespace(
+    PROGRAMS={"paged_step": ("exec:decode.pstep", "s"),
+              "prefill": ("exec:decode.prefill", "p"),
+              "write_kv_pages": ("exec:decode.pwrite", "w")})
+LETTER = dict(THREE.PROGRAMS.values())
+NAMED = {prog: event for prog, (event, _) in THREE.PROGRAMS.items()}
 ENGINE, CLIENT = 7, 8           # thread ids
 T0 = 100.0                      # the window opens, host clock
 DEVICE_EPOCH_NS = 5e12          # the profiler's clock is another
@@ -86,7 +96,8 @@ CONVERT = [("jit_convert_element_type(4)", 3.5, 0.001)]
 
 def ctx_for(ring_events, traced=None, seconds=0.3, trace_seconds=0.2):
     return {"ring": ring_events, "trace": traced, "t_open": T0,
-            "t_close": T0 + seconds, "mix": {"trace_seconds": trace_seconds}}
+            "t_close": T0 + seconds, "family": THREE,
+            "mix": {"trace_seconds": trace_seconds}}
 
 
 def read(names, ctx):
@@ -99,8 +110,6 @@ def test_ring_metrics_known_answers():
     got = read(RING_METRICS, ctx_for(ring()))
     assert got == pytest.approx({
         "queue.wait_ms_p50": 4.0,
-        "admit.repack_ms_p50": 12.0,        # 4 pull + 6 repack + 2 upload
-        "admit.pwrite_ms_p50": 14.0,
         "tick.build_ms_p50": 2.0,           # of 1.5, 2.5, 2.0
         "tick.pull_ms_p50": 2.0,
         "tick.sample_ms_p50": 3.0,
@@ -119,8 +128,7 @@ def test_a_program_without_the_spans_reads_what_it_has():
            for e in old]
     got = read(RING_METRICS + IDLE_METRICS,
                ctx_for(old, trace(PROGRAMS, CONVERT)))
-    assert set(got) == {"admit.pwrite_ms_p50", "tick.sample_ms_p50"} \
-        | set(IDLE_METRICS)
+    assert set(got) == {"tick.sample_ms_p50"} | set(IDLE_METRICS)
     assert sum(got[n] for n in IDLE_METRICS) == pytest.approx(100.0)
     for empty in (None, []):
         assert read(RING_METRICS + IDLE_METRICS, ctx_for(empty)) == {}
@@ -156,15 +164,32 @@ def test_idle_gaps_are_shared_out_over_the_spans(capsys):
 def test_placement_goes_by_kind_and_duration():
     from chipbench import gapjoin
 
-    calls = gapjoin.ring_programs(ring())
-    assert "".join(gapjoin.LETTER[c[2]] for c in calls) == "pwsss"
-    _, progs = gapjoin.device_programs(trace(PROGRAMS[3:]))   # 49, 88 ms
+    calls = gapjoin.ring_programs(ring(), LETTER)
+    assert "".join(LETTER[c[2]] for c in calls) == "pwsss"
+    _, progs = gapjoin.device_programs(trace(PROGRAMS[3:]),   # 49, 88 ms
+                                       NAMED)
     # by kind alone two steps fit at 2 and at 3; the 88 ms one only
     # under the 90 ms call
     assert gapjoin.place(progs, calls, 0, 1e9) == [3]
     assert gapjoin.place(progs, calls, 0, T0 + 0.05) == []    # too early
-    _, progs = gapjoin.device_programs(trace(PROGRAMS[2:4]))  # 49, 49 ms
+    _, progs = gapjoin.device_programs(trace(PROGRAMS[2:4]),  # 49, 49 ms
+                                       NAMED)
     assert gapjoin.place(progs, calls, 0, 1e9) == [2, 3]
+
+
+def test_the_gpt_family_names_two_programs():
+    """Since PR 26 an admission is one dispatch: the page write has no
+    program and no ring event of its own, and the join looks for none."""
+    from chipbench import gapjoin, harness
+
+    gpt = harness.load_family("gpt")
+    assert gpt.PROGRAMS == {"paged_step": ("exec:decode.pstep", "s"),
+                            "prefill": ("exec:decode.prefill", "p")}
+    got = read(IDLE_METRICS, dict(ctx_for(ring(), trace(PROGRAMS, CONVERT)),
+                                  family=gpt))
+    # one placement of "psss"; the page write's 13 ms now read as idle
+    assert sum(got.values()) == pytest.approx(100.0)
+    assert got["idle.admit_share"] > 100 * (0.499 + 15 + 9) / 86.499
 
 
 def test_a_ring_that_cannot_be_placed_yields_nothing(capsys):
@@ -224,5 +249,3 @@ def test_rehearsal_prints_the_ring_metrics_and_no_idle_share(rehearsal):
     tick_host = sum(got[n]["value"] for n in (
         "tick.build_ms_p50", "tick.pull_ms_p50", "tick.sample_ms_p50"))
     assert tick_host <= 1.5 * got["engine.host_ms_per_tick"]["value"]
-    assert got["admit.repack_ms_p50"]["value"] \
-        <= got["admit.host_ms_p50"]["value"]
