@@ -6,19 +6,22 @@ steps, and requests arrive and finish mid-flight. This engine is the
 token-level analog of the batcher's shape-bucket design, over a PAGED
 KV cache instead of per-slot contiguous panels:
 
-  * the KV store is one device-resident page pool
-    (`[layers, pages, page_tokens, heads, head_dim]` for K and V) plus
-    a per-sequence int32 block table; `memory.page_allocator` hands out
-    refcounted page ids. Admission allocates pages, eviction releases
+  * the KV store is one device-resident page pool plus a
+    per-sequence int32 block table; `memory.page_allocator` hands out
+    refcounted page ids. What a page holds is the model kind's to say
+    (`inference.model_kinds`: K and V `[layers, pages, page_tokens,
+    heads, head_dim]` for a GPT, one latent row a token a layer for
+    `axk1`); the engine threads the pools as one pytree. Admission allocates pages, eviction releases
     them — capacity growth is a wider block table, never a cache copy
     (the contiguous engine re-packed the whole pool on every rung
     change);
-  * the compute core is `models.gpt.gpt_paged_prefill_fns` — one
-    dispatch builds a request's K/V panel and writes it into the
-    request's pool pages, so no K or V crosses to the host — and
-    `gpt_paged_decode_fns`' `paged_step`, which advances EVERY active
-    request one token, writing through the block table and attending
-    via `ops.pallas.decode_attention.paged_decode_attention`;
+  * the compute core is the model kind's prefill-into-pages — one
+    dispatch builds a request's cache rows and writes them into the
+    request's pool pages, so nothing of the cache crosses to the host
+    — and its paged step, which advances EVERY active request one
+    token, writing through the block table and attending over the
+    pages (for a GPT `models.gpt.gpt_paged_prefill_fns` /
+    `gpt_paged_decode_fns`);
   * all device entry points run through an `AotCache` — the fused
     prefill per prompt rung, the step per (batch-rung x page-rung)
     bucket, plus one traced-scalar copy-on-write executable — so after
@@ -86,8 +89,8 @@ PADDLE_TPU_DECODE_SPECULATE / PADDLE_TPU_DECODE_DRAFT_MODEL or serve's
 """
 from __future__ import annotations
 
-import dataclasses
 import hashlib
+import heapq
 import json
 import queue
 import threading
@@ -108,24 +111,26 @@ from ..memory.migration import (HostPageStore, MigrationEngine,
                                 TieredPageAllocator, deserialize_pages,
                                 serialize_pages, tier_metrics)
 from ..memory.page_allocator import (PageAllocator, PageExhausted,
-                                     copy_page, gather_pages, write_pages)
-from ..models.gpt import (GPTConfig, gpt_paged_decode_fns,
-                          gpt_paged_prefill_fns, gpt_paged_rollout_fns,
+                                     gather_pages, write_pages)
+from ..models.gpt import (GPTConfig, gpt_paged_rollout_fns,
                           gpt_paged_verify_fns)
 from ..observability import counter, gauge, histogram
 from ..observability import memz as _memz
 from ..observability.spans import SpanRecorder, next_request_id
 from ..observability.tracez import RING as _RING
-from ..quant.kv import kv_pool_sds, kv_pool_zeros, validate_kv_dtype
+from ..quant.kv import kv_pool_sds, kv_pool_zeros
 from ..quant.ptq import is_quantized as _params_quantized
 from ..quant.ptq import quantize_params
 from ..testing import chaos
 from .batching import (_WARMUP_SIG_CAP, bucket_ladder, next_bucket,
                        tenant_quotas as _tenant_quotas,
                        tenant_weights as _tenant_weights)
+from . import model_kinds
 from .errors import (ERR_FAILED_PRECONDITION, ERR_INVALID_ARGUMENT,
                      ERR_RESOURCE_EXHAUSTED, ERR_UNAVAILABLE,
                      TypedServeError)
+from .model_kinds import (GPTKind, _copy_kv_page,  # noqa: F401
+                          kv_fingerprint, kv_page_bytes, kv_slot_bytes)
 
 DEFAULT_MAX_SLOTS = 8          # CPU fallback when HBM stats are absent
 DEFAULT_MAX_NEW_TOKENS = 64
@@ -275,6 +280,17 @@ def _decode_metrics():
             "kv_quantized": gauge(
                 "paddle_tpu_decode_kv_quantized",
                 "1 when the engine's KV page pool is int8, 0 for fp32"),
+            # routed expert layers (model kinds that have them)
+            "routed_assignments": counter(
+                "paddle_tpu_decode_routed_assignments_total",
+                "Routed (token, expert) assignments computed here, by "
+                "expert layer and held expert; accumulated on the "
+                "device, read when stats() is",
+                labelnames=("layer", "expert")),
+            "routed_tokens": counter(
+                "paddle_tpu_decode_routed_tokens_total",
+                "Live tokens that went through the routers (prefill "
+                "positions and decode rows), read when stats() is"),
         }
     return _METRICS
 
@@ -319,24 +335,6 @@ def _handoff_metrics():
     return _HANDOFF_METRICS
 
 
-def kv_fingerprint(cfg: GPTConfig, eps: float, params: Dict) -> str:
-    """16-hex-char identity of (config, eps, parameter names/shapes/
-    dtypes). Two engines with equal fingerprints run the same forward
-    over the same weights *layout*, so their KV pages are
-    interchangeable — the model-identity leg of the KV-handoff compat
-    contract. Weight VALUES are deliberately not hashed (hashing GBs of
-    params per engine start is not worth catching an operator loading
-    two different checkpoints of the same architecture under one
-    fingerprint — the serve artifact prefix already pins the weights)."""
-    spec = json.dumps(
-        {"config": dataclasses.asdict(cfg), "eps": float(eps),
-         "params": sorted((str(k), list(v.shape),
-                           str(np.dtype(v.dtype)))
-                          for k, v in params.items())},
-        sort_keys=True)
-    return hashlib.sha1(spec.encode()).hexdigest()[:16]
-
-
 class _HandoffJob:
     """Pseudo-request for allocator accounting inside a KV handoff —
     `_alloc_pages` only reads `.id` (chaos detail, error messages) and
@@ -363,25 +361,6 @@ def _next_pool_label() -> str:
 def _trie_owner(digest: bytes) -> tuple:
     """Allocator owner tag for a prefix-trie node (short digest hex)."""
     return ("trie", digest.hex()[:12])
-
-
-def kv_slot_bytes(cfg: GPTConfig, capacity: Optional[int] = None) -> int:
-    """HBM bytes one sequence's full K+V panel occupies at `capacity`
-    (the contiguous-pool cost model; the paged analog is
-    `kv_page_bytes` x pages actually mapped)."""
-    cap = capacity or cfg.max_seq_len
-    return cfg.layers * 2 * cap * cfg.heads * cfg.head_dim * 4
-
-
-def kv_page_bytes(cfg: GPTConfig, page_tokens: int,
-                  kv_dtype: str = "float32") -> int:
-    """HBM bytes one K+V page occupies at the pool dtype. The int8 pool
-    (quant/kv.py) pays 1 byte per element plus one fp32 scale per
-    (token row, head) — 1 + 4/head_dim bytes/element vs 4 for fp32."""
-    rows = cfg.layers * 2 * int(page_tokens) * cfg.heads
-    if validate_kv_dtype(kv_dtype) == "int8":
-        return rows * cfg.head_dim + rows * 4
-    return rows * cfg.head_dim * 4
 
 
 def fit_slot_count(step_bytes, budget: int, upper: int,
@@ -414,7 +393,7 @@ def fit_slot_count(step_bytes, budget: int, upper: int,
     return 1        # compiles, above the budget: one slot is the floor
 
 
-def default_slot_count(step_jit, params, cfg: GPTConfig, page_tokens: int,
+def default_slot_count(step_jit, params, kind, page_tokens: int,
                        kv_dtype: str = "float32",
                        hbm_fraction: float = 0.5,
                        fallback: int = DEFAULT_MAX_SLOTS) -> int:
@@ -423,24 +402,23 @@ def default_slot_count(step_jit, params, cfg: GPTConfig, page_tokens: int,
     full block-table width) needs no more than what is in use now plus
     `hbm_fraction` of the free bytes. The compiler's own
     `memory_analysis()` is the cost model — it sees what arithmetic on
-    logical shapes cannot: tile padding of the [.., heads, head_dim]
-    minor dims and the step's gather temporaries, together several
-    times the pools' logical bytes. A device without memory stats (CPU)
+    logical shapes cannot: tile padding of the pools' minor dims and the
+    step's gather temporaries, together several times the pools'
+    logical bytes. `kind` is the model kind (`model_kinds`): it
+    describes the pools of an n-slot engine. A device without memory stats (CPU)
     gets the fixed fallback so tests and benches behave identically."""
     used, limit = monitor.hbm_usage()
     if limit <= 0:
         return fallback
     budget = used + int(max(limit - used, 0) * hbm_fraction)
-    L, nh, D = cfg.layers, cfg.heads, cfg.head_dim
-    pages_per_seq = -(-cfg.max_seq_len // page_tokens)
+    pages_per_seq = -(-kind.max_seq_len // page_tokens)
     i32 = jnp.int32
 
     def step_bytes(n):
-        pool = kv_pool_sds((L, n * pages_per_seq + 1, page_tokens, nh, D),
-                           kv_dtype)
+        pools = kind.pools_sds(n * pages_per_seq + 1, page_tokens, kv_dtype)
         try:
             exe, _ = aot_compile(
-                step_jit, params, pool, pool,
+                step_jit, params, pools,
                 jax.ShapeDtypeStruct((n, pages_per_seq), i32),
                 jax.ShapeDtypeStruct((n,), i32),
                 jax.ShapeDtypeStruct((n,), i32),
@@ -453,9 +431,11 @@ def default_slot_count(step_jit, params, cfg: GPTConfig, page_tokens: int,
         return (m.argument_size_in_bytes + m.output_size_in_bytes
                 - m.alias_size_in_bytes + m.temp_size_in_bytes)
 
-    # logical K+V bytes per slot bound the count from above
-    upper = max(1, min(int((limit - used) // kv_slot_bytes(cfg)), 256))
-    return fit_slot_count(step_bytes, budget, upper)
+    # logical cache bytes per slot bound the count from above
+    upper = max(1, min(int((limit - used) // kind.slot_bytes()), 256))
+    return fit_slot_count(step_bytes, budget, upper,
+                          kind.sizing_start(budget - used)
+                          or DEFAULT_MAX_SLOTS)
 
 
 def kv_capacity_ladder(max_seq_len: int,
@@ -552,6 +532,14 @@ class DecodeStream:
         thread per stream."""
         if self._pending:
             return self._pending.popleft()
+        # An empty stream is the common answer and has to be cheap: a
+        # collector that sweeps a stream a slot once a millisecond holds
+        # the interpreter lock while it asks, and the scheduler thread
+        # waits for it. Peeking the queue's deque takes no lock and
+        # raises nothing (0.04 us against 1.7 for `queue.Empty`); an
+        # event that lands right after the peek is read by the next poll.
+        if not self._q.queue:
+            return None
         try:
             ev = self._q.get_nowait()
         except queue.Empty:
@@ -768,14 +756,26 @@ class _PrefixCache:
         whole chain walks it tip-to-root instead of orphaning it)."""
         removed = 0
         with self._lock:
-            while removed < max(n, 0):
-                cands = [(d, e) for d, e in self._entries.items()
-                         if e[0] >= 0]
-                if not cands:
-                    break
-                d, e = min(cands, key=lambda x: self._leaf_key(*x))
+            # one heap a call, not one scan of the trie a page: an
+            # admission of a 6k prompt evicts 48 pages out of thousands
+            # (52 ms of `decode.admit.alloc` at 4,289 pages, PR 28). A
+            # parent whose last child goes is pushed again under its new
+            # key; the stale copy is skipped when it surfaces.
+            heap = [(self._leaf_key(d, e), d)
+                    for d, e in self._entries.items() if e[0] >= 0]
+            heapq.heapify(heap)
+            while removed < max(n, 0) and heap:
+                key, d = heapq.heappop(heap)
+                e = self._entries.get(d)
+                if e is None or e[0] < 0 or key != self._leaf_key(d, e):
+                    continue
+                parent = e[2]
                 self._remove(d, e)
                 removed += 1
+                up = self._entries.get(parent) if parent is not None \
+                    else None
+                if up is not None and up[0] >= 0:
+                    heapq.heappush(heap, (self._leaf_key(parent, up), parent))
             self._evictions += removed
         return removed
 
@@ -864,21 +864,18 @@ class _PrefixCache:
                     "orphaned": self._orphaned}
 
 
-# Pure pool entry point (jit + AotCache'd by the engine): K and V move
-# together so one executable covers both copies.
-
-def _copy_kv_page(k_pool, v_pool, src, dst):
-    return (copy_page(k_pool, src, dst), copy_page(v_pool, src, dst))
-
-
 class DecodeEngine:
-    """Slot-pool continuous batcher over the paged incremental GPT
-    forward: fixed device page pool + per-slot block tables, prefix
-    sharing with copy-on-write, typed backpressure on exhaustion."""
+    """Slot-pool continuous batcher over a model kind's paged
+    incremental forward (`model_kinds`: a GPT, or `axk1`): fixed device
+    page pool + per-slot block tables, prefix sharing with
+    copy-on-write, typed backpressure on exhaustion. `model` (a layer)
+    or `cfg` + `params` say which model; the kind follows from their
+    type."""
 
     _req_cls = _Req       # SpecDecodeEngine swaps in _SpecReq
+    _speculative = False
 
-    def __init__(self, model=None, *, cfg: Optional[GPTConfig] = None,
+    def __init__(self, model=None, *, cfg=None,
                  params: Optional[Dict] = None, eps: Optional[float] = None,
                  max_slots: Optional[int] = None,
                  max_new_tokens: int = DEFAULT_MAX_NEW_TOKENS,
@@ -895,69 +892,70 @@ class DecodeEngine:
                  handoff: Optional[bool] = None):
         if model is not None:
             from .. import framework
-            cfg = model.cfg
+            kind = model_kinds.for_model(model, eps)
             params = framework.param_arrays(model)
-            eps = model.ln_f._epsilon if eps is None else eps
-        if cfg is None or params is None:
+        elif cfg is None or params is None:
             raise ValueError("DecodeEngine needs a model or (cfg, params)")
-        self.cfg = cfg
-        self.eps = 1e-5 if eps is None else float(eps)
+        else:
+            kind = model_kinds.for_config(cfg, eps)
+        self._kind = kind
+        self.cfg = kind.cfg
+        self.eps = kind.eps
         self.params = {k: jnp.asarray(v) for k, v in params.items()}
         self.max_new_tokens = int(max_new_tokens)
         self.eos_id = eos_id
-        self.page_tokens = int(
-            page_tokens or _flags.env_value("PADDLE_TPU_DECODE_PAGE_TOKENS"))
+        self.page_tokens = int(page_tokens or kind.default_page_tokens())
         if self.page_tokens < 1:
             raise ValueError(f"page_tokens must be >= 1, "
                              f"got {self.page_tokens}")
-        self.kv_dtype = validate_kv_dtype(
-            kv_dtype if kv_dtype is not None
-            else _flags.env_value("PADDLE_TPU_DECODE_KV_DTYPE"))
-        _, step_fn = gpt_paged_decode_fns(
-            cfg, eps=self.eps, page_tokens=self.page_tokens)
-        # `jit_prefill` in a device trace, beside its ring label
-        # `exec:decode.prefill` (the draft's is `jit_paged_prefill` /
-        # `exec:decode.dprefill`): a reader of both pairs them by name
-        prefill_fn = gpt_paged_prefill_fns(
-            cfg, eps=self.eps, page_tokens=self.page_tokens, name="prefill")
-        # Pool args are donated: every call site rebinds the pools from
-        # the result, so XLA updates the multi-MB pool buffers in place
-        # instead of copying them per dispatch (the copy dominated
-        # step/verify cost on CPU).
-        step_jit = jax.jit(step_fn, donate_argnums=(1, 2))
-        self.max_slots = int(max_slots) if max_slots \
-            else default_slot_count(step_jit, self.params, cfg,
-                                    self.page_tokens, self.kv_dtype,
-                                    hbm_fraction)
-        self.max_pending = int(max_pending) if max_pending is not None \
-            else 4 * self.max_slots
-        self.batch_ladder = bucket_ladder(
-            self.max_slots, env=_flags.env_value("PADDLE_TPU_DECODE_BUCKETS"))
-        self.kv_ladder = kv_capacity_ladder(cfg.max_seq_len,
-                                            floor=self.page_tokens)
-        # block-table width rungs: pages needed to hold each kv rung
-        self.page_ladder = sorted(
-            {-(-r // self.page_tokens) for r in self.kv_ladder})
-        self.pages_per_seq = -(-cfg.max_seq_len // self.page_tokens)
-        # +1: page 0 is the reserved null/scratch page (table padding
-        # and padded-batch writes land there, never on live data)
-        self.num_pages = int(num_pages) if num_pages \
-            else self.max_slots * self.pages_per_seq + 1
         hp = int(host_pages) if host_pages is not None \
             else int(_flags.env_value("PADDLE_TPU_DECODE_HOST_PAGES"))
         self.host_pages = max(hp, 0)
-        pool_label = _next_pool_label()
-        self._alloc = TieredPageAllocator(
-            self.num_pages, host_pages=self.host_pages,
-            label=pool_label) \
-            if self.host_pages \
-            else PageAllocator(self.num_pages, label=pool_label)
         # disaggregated prefill/decode KV handoff (docs/serving.md):
         # export gathers a prompt's full pages through `pgather`, import
         # lands them through `ptier` + a prefix-trie insert so the
         # follow-up stream admits as a prefix hit
         self.handoff = bool(_flags.env_value("PADDLE_TPU_DECODE_HANDOFF")) \
             if handoff is None else bool(handoff)
+        # the kind says which pool dtype it runs, and refuses (typed)
+        # the optional features it does not have
+        self.kv_dtype = kind.pool_dtype(
+            kv_dtype, host_pages=self.host_pages, handoff=self.handoff,
+            speculative=self._speculative)
+        step_fn = kind.step_fn(self.page_tokens)
+        # `jit_prefill` in a device trace, beside its ring label
+        # `exec:decode.prefill` (the draft's is `jit_paged_prefill` /
+        # `exec:decode.dprefill`): a reader of both pairs them by name
+        prefill_fn = kind.prefill_fn(self.page_tokens, name="prefill")
+        # The pools are donated: every call site rebinds them from the
+        # result, so XLA updates the multi-MB pool buffers in place
+        # instead of copying them per dispatch (the copy dominated
+        # step/verify cost on CPU).
+        step_jit = jax.jit(step_fn, donate_argnums=(1,))
+        self.max_slots = int(max_slots) if max_slots \
+            else default_slot_count(step_jit, self.params, kind,
+                                    self.page_tokens, self.kv_dtype,
+                                    hbm_fraction)
+        self.max_pending = int(max_pending) if max_pending is not None \
+            else 4 * self.max_slots
+        self.batch_ladder = bucket_ladder(
+            self.max_slots, env=_flags.env_value("PADDLE_TPU_DECODE_BUCKETS"))
+        self.kv_ladder = kv_capacity_ladder(kind.max_seq_len,
+                                            floor=self.page_tokens)
+        # block-table width rungs: pages needed to hold each kv rung
+        self.page_ladder = sorted(
+            {-(-r // self.page_tokens) for r in self.kv_ladder})
+        self.pages_per_seq = -(-kind.max_seq_len // self.page_tokens)
+        # +1: page 0 is the reserved null/scratch page (table padding
+        # and padded-batch writes land there, never on live data)
+        self.num_pages = int(num_pages) if num_pages \
+            else self.max_slots * self.pages_per_seq + 1
+        pool_label = _next_pool_label()
+        self._alloc = TieredPageAllocator(
+            self.num_pages, host_pages=self.host_pages,
+            label=pool_label) \
+            if self.host_pages \
+            else PageAllocator(self.num_pages, label=pool_label)
         use_prefix = prefix_cache if prefix_cache is not None \
             else bool(_flags.env_value("PADDLE_TPU_DECODE_PREFIX_CACHE"))
         # tiering spills and refetches *through* the trie — its entries
@@ -970,13 +968,13 @@ class DecodeEngine:
             if use_prefix else None
 
         self._prefill_aot = AotCache(
-            jax.jit(prefill_fn, donate_argnums=(1, 2)), "decode.prefill",
-            donate_argnums=(1, 2))
+            jax.jit(prefill_fn, donate_argnums=(1,)), "decode.prefill",
+            donate_argnums=(1,))
         self._step_aot = AotCache(step_jit, "decode.pstep",
-                                  donate_argnums=(1, 2))
+                                  donate_argnums=(1,))
         self._copy_aot = AotCache(
-            jax.jit(_copy_kv_page, donate_argnums=(0, 1)), "decode.pcow",
-            donate_argnums=(0, 1))
+            jax.jit(kind.copy_page, donate_argnums=(0,)), "decode.pcow",
+            donate_argnums=(0,))
         # host-tier / handoff executables: `pgather` snapshots pages
         # into an independent buffer (pools NOT donated — the engine
         # keeps stepping on them), `ptier` scatters rows back in. The
@@ -991,13 +989,13 @@ class DecodeEngine:
                 jax.jit(write_pages, donate_argnums=(0,)), "decode.ptier",
                 donate_argnums=(0,))
 
-        self.fingerprint = kv_fingerprint(cfg, self.eps, self.params)
+        self.fingerprint = kind.fingerprint(self.params)
         self._hm = _handoff_metrics() if self.handoff else None
         self._handoff_counts = {"exports": 0, "imports": 0, "rejects": 0}
 
         self._m = _decode_metrics()
         self._m["kv_page_bytes"].set(
-            kv_page_bytes(cfg, self.page_tokens, self.kv_dtype))
+            kind.page_bytes(self.page_tokens, self.kv_dtype))
         self._m["kv_quantized"].set(1 if self.kv_dtype == "int8" else 0)
         self._spans = SpanRecorder(
             component="decode", metric="paddle_tpu_decode_span_seconds",
@@ -1017,8 +1015,8 @@ class DecodeEngine:
         self._preempt_on = bool(
             _flags.env_value("PADDLE_TPU_DECODE_PREEMPT")) \
             if preempt is None else bool(preempt)
-        self._kpool = None           # [L, P, page_tokens, nh, D], lazy
-        self._vpool = None
+        self._pool_tree = None       # the kind's pools pytree, lazy
+        self._routed_seen = None     # routed counters last exported
         # host tier (lazy with the pools): arena store + migration
         # worker + requests parked on an in-flight refetch
         self._store = None
@@ -1057,15 +1055,15 @@ class DecodeEngine:
         toks = [int(t) for t in np.asarray(prompt, dtype=np.int64).reshape(-1)]
         if not toks:
             raise TypedServeError(ERR_INVALID_ARGUMENT, "empty prompt")
-        if any(t < 0 or t >= self.cfg.vocab_size for t in toks):
+        if any(t < 0 or t >= self._kind.vocab_size for t in toks):
             raise TypedServeError(
                 ERR_INVALID_ARGUMENT,
-                f"prompt token out of range [0, {self.cfg.vocab_size})")
-        if len(toks) >= self.cfg.max_seq_len:
+                f"prompt token out of range [0, {self._kind.vocab_size})")
+        if len(toks) >= self._kind.max_seq_len:
             raise TypedServeError(
                 ERR_INVALID_ARGUMENT,
                 f"prompt length {len(toks)} leaves no room to generate "
-                f"(max_seq_len={self.cfg.max_seq_len})")
+                f"(max_seq_len={self._kind.max_seq_len})")
         tenant = str(tenant).strip() if tenant else DEFAULT_TENANT
         req = self._req_cls(toks,
                             int(max_new_tokens or self.max_new_tokens),
@@ -1114,32 +1112,31 @@ class DecodeEngine:
     def _quota_rate(self, tenant: str) -> float:
         return self._quota.get(tenant, self._quota["*"])
 
-    def _pool_shape(self):
-        L, nh, D = self.cfg.layers, self.cfg.heads, self.cfg.head_dim
-        return (L, self.num_pages, self.page_tokens, nh, D)
-
-    def _pool_sds(self):
-        return kv_pool_sds(self._pool_shape(), self.kv_dtype)
+    def _model_pools_sds(self):
+        """The model kind's pools, described: what step, prefill and
+        copy-on-write take."""
+        return self._kind.pools_sds(self.num_pages, self.page_tokens,
+                                    self.kv_dtype)
 
     # The tier moves every pool an engine owns as ONE pytree — the base
-    # engine's (k, v), the speculative engine's (k, v, dk, dv) — so one
-    # gather/scatter executable per page rung migrates a page's full
-    # footprint. Subclasses that add pools override these three hooks.
+    # engine's model pools, the speculative engine's plus its draft's —
+    # so one gather/scatter executable per page rung migrates a page's
+    # full footprint. Subclasses that add pools override these three
+    # hooks.
 
     def _pools(self):
-        return (self._kpool, self._vpool)
+        return self._pool_tree
 
     def _set_pools(self, pools):
-        self._kpool, self._vpool = pools
+        self._pool_tree = pools
 
     def _pools_sds(self):
-        p = self._pool_sds()
-        return (p, p)
+        return self._model_pools_sds()
 
     def _ensure_pool(self):
-        if self._kpool is None:
-            self._kpool = kv_pool_zeros(self._pool_shape(), self.kv_dtype)
-            self._vpool = kv_pool_zeros(self._pool_shape(), self.kv_dtype)
+        if self._pool_tree is None:
+            self._pool_tree = self._kind.pools_zeros(
+                self.num_pages, self.page_tokens, self.kv_dtype)
         if self.host_pages and self._migrate is None:
             self._store = HostPageStore(self._pools_sds(), self.host_pages)
             self._migrate = MigrationEngine(
@@ -1152,34 +1149,34 @@ class DecodeEngine:
         with self._cond:
             self._cond.notify_all()
 
-    # One prefill path (`gpt_paged_prefill_fns`): the target model's
-    # admission, the KV-handoff export and the speculative engine's
-    # draft all dispatch it through these two.
+    # One prefill path (the kind's prefill-into-pages): the target
+    # model's admission, the KV-handoff export and the speculative
+    # engine's draft all dispatch it through these two.
 
-    def _prefill_exe(self, aot, params, k_pool, v_pool, rung):
+    def _prefill_exe(self, aot, params, pools, rung):
         """`aot`'s fused prefill-into-pages executable for one kv rung
         (the pools may be arrays or their ShapeDtypeStructs)."""
         i32 = jnp.int32
         return aot.get_or_compile(
-            params, k_pool, v_pool,
+            params, pools,
             jax.ShapeDtypeStruct((1, rung), i32),
             jax.ShapeDtypeStruct((1, -(-rung // self.page_tokens)), i32),
             jax.ShapeDtypeStruct((1,), i32),
             key=("prefill", 1, rung))
 
-    def _prefill_into_pages(self, aot, params, k_pool, v_pool, toks, pages):
-        """One dispatch: `toks` prefilled at their kv rung and their K/V
-        written into `pages` of the (donated) pools; table padding aims
-        at the null page. Returns (logits [1, V], k_pool, v_pool), all
-        on the device."""
+    def _prefill_into_pages(self, aot, params, pools, toks, pages):
+        """One dispatch: `toks` prefilled at their kv rung and their
+        cache rows written into `pages` of the (donated) pools; table
+        padding aims at the null page. Returns (logits [1, V], pools),
+        all on the device."""
         plen = len(toks)
         rung = next_bucket(plen, self.kv_ladder)
         inp = np.zeros((1, rung), np.int32)
         inp[0, :plen] = toks
         table = np.zeros((1, -(-rung // self.page_tokens)), np.int32)
         table[0, :len(pages)] = pages
-        exe = self._prefill_exe(aot, params, k_pool, v_pool, rung)
-        return exe(params, k_pool, v_pool, jnp.asarray(inp),
+        exe = self._prefill_exe(aot, params, pools, rung)
+        return exe(params, pools, jnp.asarray(inp),
                    jnp.asarray(table), jnp.asarray([plen], np.int32))
 
     def warmup(self, verbose: bool = False) -> int:
@@ -1189,11 +1186,11 @@ class DecodeEngine:
         first dropped last). Returns the number of fresh compiles."""
         before = len(profiler.compile_events())
         i32 = jnp.int32
-        pool = self._pool_sds()
+        pool = self._model_pools_sds()
         for r in self.kv_ladder:
-            self._prefill_exe(self._prefill_aot, self.params, pool, pool, r)
+            self._prefill_exe(self._prefill_aot, self.params, pool, r)
         self._copy_aot.get_or_compile(
-            pool, pool,
+            pool,
             jax.ShapeDtypeStruct((), i32), jax.ShapeDtypeStruct((), i32),
             key=("pcow",))
         if self.host_pages or self.handoff:
@@ -1217,7 +1214,7 @@ class DecodeEngine:
             sigs = sigs[:_WARMUP_SIG_CAP]
         for b, w in sigs:
             self._step_aot.get_or_compile(
-                self.params, pool, pool,
+                self.params, pool,
                 jax.ShapeDtypeStruct((b, w), i32),
                 jax.ShapeDtypeStruct((b,), i32),
                 jax.ShapeDtypeStruct((b,), i32),
@@ -1247,12 +1244,14 @@ class DecodeEngine:
             "page_tokens": self.page_tokens,
             "kv_dtype": self.kv_dtype,
             "fingerprint": self.fingerprint,
-            "kv_page_bytes": kv_page_bytes(self.cfg, self.page_tokens,
-                                           self.kv_dtype),
+            "kv_page_bytes": self._kind.page_bytes(self.page_tokens,
+                                                   self.kv_dtype),
+            "model_kind": self._kind.name,
             "pages": self._alloc.stats(),
             "tenants": {t: round(v, 4)
                         for t, v in sorted(dict(self._vtokens).items())},
         }
+        st.update(self._routed_stats())
         if self._prefix is not None:
             st["prefix_cache"] = self._prefix.stats()
         if self.handoff:
@@ -1271,6 +1270,39 @@ class DecodeEngine:
                 tier.update(self._migrate.stats())
             st["kv_tier"] = tier
         return st
+
+    def _routed_stats(self) -> Dict:
+        """The kind's device-side counters (routed assignments by expert
+        layer and held expert, tokens routed), read here and nowhere on
+        the tick; what is new since the last read goes to the
+        `paddle_tpu_decode_routed_*` counters. The scheduler thread
+        donates the pools to every dispatch and rebinds them when it
+        returns (a prefill of 8k tokens holds them for 0.4 s), so a read
+        that finds them gone waits for the next tree."""
+        deadline = time.monotonic() + 5.0
+        while True:
+            try:
+                now = self._kind.counters(self._pool_tree)
+                break
+            except RuntimeError:        # donated under us
+                if time.monotonic() > deadline:
+                    return {}
+                time.sleep(0.001)
+        if not now:
+            return {}
+        seen = self._routed_seen or {"routed": [[0] * len(r)
+                                                for r in now["routed"]],
+                                     "routed_tokens": 0}
+        for li, (row, old) in enumerate(zip(now["routed"], seen["routed"])):
+            for e, (n, o) in enumerate(zip(row, old)):
+                if n > o:
+                    self._m["routed_assignments"].labels(
+                        layer=str(li), expert=str(e)).inc(n - o)
+        if now["routed_tokens"] > seen["routed_tokens"]:
+            self._m["routed_tokens"].inc(
+                now["routed_tokens"] - seen["routed_tokens"])
+        self._routed_seen = now
+        return now
 
     def stop(self):
         """Stop the scheduler; open streams get typed UNAVAILABLE."""
@@ -1619,12 +1651,12 @@ class DecodeEngine:
         old = req.pages[slot]
         (new,) = self._alloc_pages(1, req)
         exe = self._copy_aot.get_or_compile(
-            self._kpool, self._vpool,
+            self._pool_tree,
             jax.ShapeDtypeStruct((), jnp.int32),
             jax.ShapeDtypeStruct((), jnp.int32),
             key=("pcow",))
-        self._kpool, self._vpool = exe(
-            self._kpool, self._vpool,
+        self._pool_tree = exe(
+            self._pool_tree,
             jnp.asarray(old, jnp.int32), jnp.asarray(new, jnp.int32))
         req.pages[slot] = new
         self._alloc.release(old, owner=self._owner_for(req))
@@ -1838,15 +1870,15 @@ class DecodeEngine:
                 for t in np.asarray(prompt, np.int64).reshape(-1)]
         if not toks:
             raise TypedServeError(ERR_INVALID_ARGUMENT, "empty prompt")
-        if any(t < 0 or t >= self.cfg.vocab_size for t in toks):
+        if any(t < 0 or t >= self._kind.vocab_size for t in toks):
             raise TypedServeError(
                 ERR_INVALID_ARGUMENT,
-                f"prompt token out of range [0, {self.cfg.vocab_size})")
-        if len(toks) >= self.cfg.max_seq_len:
+                f"prompt token out of range [0, {self._kind.vocab_size})")
+        if len(toks) >= self._kind.max_seq_len:
             raise TypedServeError(
                 ERR_INVALID_ARGUMENT,
                 f"prompt length {len(toks)} exceeds "
-                f"max_seq_len={self.cfg.max_seq_len}")
+                f"max_seq_len={self._kind.max_seq_len}")
         return self._handoff_call(lambda: self._export_kv(toks), timeout)
 
     def import_kv(self, payload: Dict, timeout: float = 30.0) -> int:
@@ -1926,9 +1958,8 @@ class DecodeEngine:
         # the partial last page's rows fall on the null page: only full
         # pages travel. (An AotCache call returns once its outputs are
         # ready, so the latency below is prefill + page write.)
-        _, self._kpool, self._vpool = self._prefill_into_pages(
-            self._prefill_aot, self.params, self._kpool, self._vpool,
-            toks, pages)
+        _, self._pool_tree = self._prefill_into_pages(
+            self._prefill_aot, self.params, self._pool_tree, toks, pages)
         self._m["prefills"].inc()
         self._m["prefill_latency"].observe(time.perf_counter() - t0)
         self._prefix.insert(toks[:n_full * pt], pages)
@@ -2135,9 +2166,9 @@ class DecodeEngine:
                 self._m["evictions"].labels(reason="exhausted").inc()
                 return False
         t0 = time.perf_counter()
-        logits, self._kpool, self._vpool = self._prefill_into_pages(
-            self._prefill_aot, self.params, self._kpool, self._vpool,
-            toks, req.pages)
+        logits, self._pool_tree = self._prefill_into_pages(
+            self._prefill_aot, self.params, self._pool_tree, toks,
+            req.pages)
         with _RING.span("decode.admit.logits_pull"):
             row = np.asarray(logits)[0]
         req.prefill_s = time.perf_counter() - t0
@@ -2175,7 +2206,7 @@ class DecodeEngine:
         req.stream._push_token(tok, eos)
         _RING.instant("decode.emit", {"req": req.id})
         if eos or len(req.generated) >= req.max_new \
-                or req.cache_len >= self.cfg.max_seq_len:
+                or req.cache_len >= self._kind.max_seq_len:
             self._finish(req, "eos" if eos else "length")
             self._release_pages(req)
             return False
@@ -2208,7 +2239,7 @@ class DecodeEngine:
                     ltok[j] = req.last_tok
                     clen[j] = req.cache_len
                 exe = self._step_aot.get_or_compile(
-                    self.params, self._kpool, self._vpool,
+                    self.params, self._pool_tree,
                     jax.ShapeDtypeStruct((b_rung, w_rung), jnp.int32),
                     jax.ShapeDtypeStruct((b_rung,), jnp.int32),
                     jax.ShapeDtypeStruct((b_rung,), jnp.int32),
@@ -2216,8 +2247,8 @@ class DecodeEngine:
                 tables, ltok, clen = (jnp.asarray(tables),
                                       jnp.asarray(ltok), jnp.asarray(clen))
             t0 = time.perf_counter()
-            logits, self._kpool, self._vpool = exe(
-                self.params, self._kpool, self._vpool, tables, ltok, clen)
+            logits, self._pool_tree = exe(
+                self.params, self._pool_tree, tables, ltok, clen)
             with _RING.span("decode.step.pull", {}) as pull:
                 lognp = np.asarray(logits)
                 pull.args["bytes"] = lognp.nbytes
@@ -2303,7 +2334,7 @@ class DecodeEngine:
             req.stream._push_token(tok, eos)
             _RING.instant("decode.emit", {"req": req.id})
             if eos or len(req.generated) >= req.max_new \
-                    or req.cache_len >= self.cfg.max_seq_len:
+                    or req.cache_len >= self._kind.max_seq_len:
                 self._finish(req, "eos" if eos else "length")
                 self._release_pages(req)
                 finished.append(req)
@@ -2427,12 +2458,20 @@ class SpecDecodeEngine(DecodeEngine):
     """
 
     _req_cls = _SpecReq
+    _speculative = True
 
     def __init__(self, model=None, *, draft_model=None,
                  draft_cfg: Optional[GPTConfig] = None,
                  draft_params: Optional[Dict] = None,
                  draft_eps: Optional[float] = None,
                  speculate_k: Optional[int] = None, **kw):
+        tcfg = model.cfg if model is not None else kw.get("cfg")
+        for c in (tcfg, draft_cfg if draft_model is None
+                  else draft_model.cfg):
+            if c is not None and not isinstance(c, GPTConfig):
+                # the typed refusal of a kind that has no speculation yet
+                model_kinds.for_config(c).pool_dtype(
+                    kw.get("kv_dtype"), speculative=True)
         if draft_model is not None:
             from .. import framework
             draft_cfg = draft_model.cfg
@@ -2448,7 +2487,6 @@ class SpecDecodeEngine(DecodeEngine):
         if k < 1:
             raise ValueError(f"speculate_k must be >= 1, got {k}")
         # validate against the target BEFORE the scheduler thread starts
-        tcfg = model.cfg if model is not None else kw.get("cfg")
         if tcfg is not None:
             if draft_cfg.vocab_size != tcfg.vocab_size:
                 raise ValueError(
@@ -2464,8 +2502,8 @@ class SpecDecodeEngine(DecodeEngine):
         self._draft_params = {n: jnp.asarray(v)
                               for n, v in draft_params.items()}
         self.k_ladder = spec_k_ladder(k)
-        dprefill = gpt_paged_prefill_fns(
-            draft_cfg, eps=self.draft_eps, page_tokens=self.page_tokens)
+        dprefill = GPTKind(draft_cfg, self.draft_eps).prefill_fn(
+            self.page_tokens, name="paged_prefill")
         rollout = gpt_paged_rollout_fns(
             draft_cfg, eps=self.draft_eps, page_tokens=self.page_tokens)
         verify = gpt_paged_verify_fns(
@@ -2473,14 +2511,14 @@ class SpecDecodeEngine(DecodeEngine):
         # Draft/target pools donated for the same in-place-update
         # reason as the base engine's executables.
         self._dprefill_aot = AotCache(
-            jax.jit(dprefill, donate_argnums=(1, 2)), "decode.dprefill",
-            donate_argnums=(1, 2))
+            jax.jit(dprefill, donate_argnums=(1,)), "decode.dprefill",
+            donate_argnums=(1,))
         self._droll_aot = AotCache(
             jax.jit(rollout, donate_argnums=(1, 2)), "decode.droll",
             donate_argnums=(1, 2))
         self._dcopy_aot = AotCache(
-            jax.jit(_copy_kv_page, donate_argnums=(0, 1)), "decode.dcow",
-            donate_argnums=(0, 1))
+            jax.jit(_copy_kv_page, donate_argnums=(0,)), "decode.dcow",
+            donate_argnums=(0,))
         self._verify_aot = AotCache(
             jax.jit(verify, donate_argnums=(1, 2)), "decode.verify",
             donate_argnums=(1, 2))
@@ -2499,6 +2537,28 @@ class SpecDecodeEngine(DecodeEngine):
         if isinstance(req, _HandoffJob):
             return super()._owner_for(req)
         return ("draft", req.id)
+
+    # The target's pools are a GPT's (k_pool, v_pool): this engine
+    # names them, behind its typed refusal of every other kind.
+
+    @property
+    def _kpool(self):
+        return None if self._pool_tree is None else self._pool_tree[0]
+
+    @_kpool.setter
+    def _kpool(self, pool):
+        self._pool_tree = (pool, self._vpool)
+
+    @property
+    def _vpool(self):
+        return None if self._pool_tree is None else self._pool_tree[1]
+
+    @_vpool.setter
+    def _vpool(self, pool):
+        self._pool_tree = (self._kpool, pool)
+
+    def _pool_sds(self):
+        return self._model_pools_sds()[0]
 
     def _dpool_shape(self):
         c = self.draft_cfg
@@ -2519,8 +2579,8 @@ class SpecDecodeEngine(DecodeEngine):
         return (self._kpool, self._vpool, self._dkpool, self._dvpool)
 
     def _set_pools(self, pools):
-        (self._kpool, self._vpool,
-         self._dkpool, self._dvpool) = pools
+        self._pool_tree = tuple(pools[:2])
+        self._dkpool, self._dvpool = pools[2:]
 
     def _pools_sds(self):
         p, d = self._pool_sds(), self._dpool_sds()
@@ -2536,25 +2596,17 @@ class SpecDecodeEngine(DecodeEngine):
         """Copy-on-write for speculation copies the page in BOTH pools —
         one page id names a target page and a draft page."""
         old = req.pages[slot]
-        (new,) = self._alloc_pages(1, req)
+        super()._cow(req, slot)     # the target's copy; repoints the slot
         i32 = jnp.int32
-        exe = self._copy_aot.get_or_compile(
-            self._kpool, self._vpool,
-            jax.ShapeDtypeStruct((), i32), jax.ShapeDtypeStruct((), i32),
-            key=("pcow",))
-        self._kpool, self._vpool = exe(
-            self._kpool, self._vpool,
-            jnp.asarray(old, i32), jnp.asarray(new, i32))
+        dpools = (self._dkpool, self._dvpool)
         dexe = self._dcopy_aot.get_or_compile(
-            self._dkpool, self._dvpool,
+            dpools,
             jax.ShapeDtypeStruct((), i32), jax.ShapeDtypeStruct((), i32),
             key=("dcow",))
+        # the other owners still hold `old`, so its draft rows stand
         self._dkpool, self._dvpool = dexe(
-            self._dkpool, self._dvpool,
-            jnp.asarray(old, i32), jnp.asarray(new, i32))
-        req.pages[slot] = new
-        self._alloc.release(old, owner=self._owner_for(req))
-        self._m["cow"].inc()
+            dpools, jnp.asarray(old, i32),
+            jnp.asarray(req.pages[slot], i32))
 
     # ---------------------------------------------------------- warmup
 
@@ -2570,9 +2622,9 @@ class SpecDecodeEngine(DecodeEngine):
         pool, dpool = self._pool_sds(), self._dpool_sds()
         for r in self.kv_ladder:
             self._prefill_exe(self._dprefill_aot, self._draft_params,
-                              dpool, dpool, r)
+                              (dpool, dpool), r)
         self._dcopy_aot.get_or_compile(
-            dpool, dpool,
+            (dpool, dpool),
             jax.ShapeDtypeStruct((), i32), jax.ShapeDtypeStruct((), i32),
             key=("dcow",))
         # When the full (batch x page x k) cross product overflows the
@@ -2637,9 +2689,9 @@ class SpecDecodeEngine(DecodeEngine):
         the rows hold committed K/V — the one thing every mapper of a
         shared prefix page agrees on."""
         seq = (req.prompt + req.generated)[:req.cache_len]
-        _, self._dkpool, self._dvpool = self._prefill_into_pages(
+        _, (self._dkpool, self._dvpool) = self._prefill_into_pages(
             self._dprefill_aot, self._draft_params,
-            self._dkpool, self._dvpool, seq, req.pages)
+            (self._dkpool, self._dvpool), seq, req.pages)
 
     def _preempt_stash(self, req: _Req):
         """Stash only PROMPT-region pages at preemption. Generated-region
@@ -2902,8 +2954,15 @@ class SpecDecodeEngine(DecodeEngine):
 # ------------------------------------------------------------ artifact
 
 def save_for_decode(model, prefix: str, quant: Optional[str] = None):
-    """Persist a GPT for the decode daemon: config JSON + params npz
+    """Persist a model for the decode daemon: config JSON + params npz
     (the jit.save one-shot artifact has no incremental entry points).
+
+    The manifest names the model kind (`model_kinds`) under
+    `"model_kind"` for every kind but a GPT: a GPT's artifact is
+    byte-identical to those written before the key existed, and an
+    artifact without the key loads as a GPT. bfloat16 parameters, which
+    npz cannot hold, are stored as their uint16 bit patterns and listed
+    under `"bfloat16"`.
 
     `quant="int8"` applies `quant.ptq.quantize_params` before writing —
     int8 weights under their original keys plus fp32 `::scale` siblings
@@ -2911,30 +2970,41 @@ def save_for_decode(model, prefix: str, quant: Optional[str] = None):
     artifact is byte-identical to pre-quantization versions (no extra
     manifest key, same npz keys), so old artifacts load unchanged."""
     from .. import framework
-    meta = {"config": dataclasses.asdict(model.cfg),
-            "eps": float(model.ln_f._epsilon),
-            "format": "paddle_tpu.decode.v1"}
+    kind = model_kinds.for_model(model)
+    meta = dict(kind.manifest(), format="paddle_tpu.decode.v1")
+    if kind.name != GPTKind.name:
+        meta["model_kind"] = kind.name
     params = {k: np.asarray(v)
               for k, v in framework.param_arrays(model).items()}
     if quant is not None:
-        if quant != "int8":
-            raise ValueError(f"quant={quant!r}: expected None or 'int8'")
+        if quant != "int8" or kind.name != GPTKind.name:
+            raise ValueError(f"quant={quant!r} for model kind "
+                             f"{kind.name!r}: expected None, or 'int8' "
+                             f"for a GPT")
         params = quantize_params(params)
         meta["quant"] = "int8"
+    bf16 = sorted(k for k, v in params.items() if v.dtype == jnp.bfloat16)
+    if bf16:
+        meta["bfloat16"] = bf16
+        params = {k: v.view(np.uint16) if k in bf16 else v
+                  for k, v in params.items()}
     with open(prefix + ".decode.json", "w") as f:
         json.dump(meta, f, indent=2, sort_keys=True)
     np.savez(prefix + ".decode.npz", **params)
 
 
 def _load_decode_artifact(prefix: str):
+    """(model kind, params) of a `save_for_decode` artifact."""
     with open(prefix + ".decode.json") as f:
         meta = json.load(f)
     if meta.get("format") != "paddle_tpu.decode.v1":
         raise ValueError(f"{prefix}.decode.json: not a decode artifact")
-    cfg = GPTConfig(**meta["config"])
+    kind = model_kinds.from_manifest(meta)
+    bf16 = set(meta.get("bfloat16", ()))
     with np.load(prefix + ".decode.npz") as z:
-        params = {k: z[k] for k in z.files}
-    return cfg, params, meta.get("eps")
+        params = {k: z[k].view(jnp.bfloat16) if k in bf16 else z[k]
+                  for k in z.files}
+    return kind, params
 
 
 def load_for_decode(prefix: str, draft_prefix: Optional[str] = None,
@@ -2954,7 +3024,7 @@ def load_for_decode(prefix: str, draft_prefix: Optional[str] = None,
     numerics only move the acceptance rate, never the target stream, so
     this is the cheapest quantization on-ramp. Already-quantized
     artifacts (manifest `"quant": "int8"`) pass through untouched."""
-    cfg, params, eps = _load_decode_artifact(prefix)
+    kind, params = _load_decode_artifact(prefix)
     if draft_prefix is None:
         draft_prefix = _flags.env_value(
             "PADDLE_TPU_DECODE_DRAFT_MODEL") or None
@@ -2964,11 +3034,12 @@ def load_for_decode(prefix: str, draft_prefix: Optional[str] = None,
         draft_quant = bool(
             _flags.env_value("PADDLE_TPU_DECODE_DRAFT_QUANT"))
     if draft_prefix and int(speculate_k) >= 1:
-        dcfg, dparams, deps = _load_decode_artifact(draft_prefix)
+        draft, dparams = _load_decode_artifact(draft_prefix)
         if draft_quant and not _params_quantized(dparams):
             dparams = quantize_params(dparams)
-        return SpecDecodeEngine(cfg=cfg, params=params, eps=eps,
-                                draft_cfg=dcfg, draft_params=dparams,
-                                draft_eps=deps,
+        return SpecDecodeEngine(cfg=kind.cfg, params=params, eps=kind.eps,
+                                draft_cfg=draft.cfg, draft_params=dparams,
+                                draft_eps=draft.eps,
                                 speculate_k=int(speculate_k), **engine_kw)
-    return DecodeEngine(cfg=cfg, params=params, eps=eps, **engine_kw)
+    return DecodeEngine(cfg=kind.cfg, params=params, eps=kind.eps,
+                        **engine_kw)

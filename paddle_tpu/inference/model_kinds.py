@@ -1,0 +1,320 @@
+"""What the decode engine asks of a model: one object per model kind.
+
+`DecodeEngine` keeps the scheduler, the page allocator, block tables,
+the prefix trie, the ladders, sampling and the spans; everything that
+knows an architecture is behind the object here: the step and the
+prefill-into-pages functions, the page pools as ONE pytree (made,
+described, copied a page at a time), bytes a page, the positions limit,
+the vocabulary, a fingerprint, and which of the engine's optional
+features the kind has. The engine threads `pools` through every
+dispatch, donated, and never looks inside.
+
+    step(params, pools, tables [B, W], last_tok [B], cache_len [B])
+        -> (logits [B, V], pools)
+    prefill(params, pools, toks [1, R], tables [1, W], n [1])
+        -> (logits [1, V], pools)
+
+Kinds: `gpt` (`GPTKind`: K and V pools `[layers, P, page_tokens, heads,
+head_dim]`, float32 or int8) and `axk1` (`AXK1Kind`: one latent pool a
+layer, bfloat16, plus the routed-assignment counters). The manifest of a
+`save_for_decode` artifact names its kind under `"model_kind"`; one
+without the key is a GPT.
+"""
+from __future__ import annotations
+
+import dataclasses
+import hashlib
+import json
+import os
+from typing import Dict, Optional
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from ..core import flags as _flags
+from ..memory.page_allocator import copy_page
+from ..models.axk1 import (AXK1, AXK1Config, axk1_paged_fns,
+                           latent_pools_sds)
+from ..models.gpt import (GPTConfig, gpt_paged_decode_fns,
+                          gpt_paged_prefill_fns)
+from ..quant.kv import kv_pool_sds, kv_pool_zeros, validate_kv_dtype
+from .errors import ERR_FAILED_PRECONDITION, TypedServeError
+
+_PAGE_TOKENS_ENV = "PADDLE_TPU_DECODE_PAGE_TOKENS"
+
+
+def _fingerprint(spec: Dict, params: Dict) -> str:
+    spec = dict(spec, params=sorted(
+        (str(k), list(v.shape), str(np.dtype(v.dtype)))
+        for k, v in params.items()))
+    return hashlib.sha1(
+        json.dumps(spec, sort_keys=True).encode()).hexdigest()[:16]
+
+
+def unsupported(kind: str, feature: str, roadmap: str):
+    """The typed refusal of a feature a model kind does not have yet."""
+    return TypedServeError(
+        ERR_FAILED_PRECONDITION,
+        f"model kind {kind!r} does not support {feature} yet "
+        f"(ROADMAP.md {roadmap})")
+
+
+# ------------------------------------------------------------------ gpt
+
+
+def kv_slot_bytes(cfg: GPTConfig, capacity: Optional[int] = None) -> int:
+    """HBM bytes one sequence's full K+V panel occupies at `capacity`
+    (the contiguous-pool cost model; the paged analog is
+    `kv_page_bytes` x pages actually mapped)."""
+    cap = capacity or cfg.max_seq_len
+    return cfg.layers * 2 * cap * cfg.heads * cfg.head_dim * 4
+
+
+def kv_page_bytes(cfg: GPTConfig, page_tokens: int,
+                  kv_dtype: str = "float32") -> int:
+    """HBM bytes one K+V page occupies at the pool dtype. The int8 pool
+    (quant/kv.py) pays 1 byte per element plus one fp32 scale per
+    (token row, head) — 1 + 4/head_dim bytes/element vs 4 for fp32."""
+    rows = cfg.layers * 2 * int(page_tokens) * cfg.heads
+    if validate_kv_dtype(kv_dtype) == "int8":
+        return rows * cfg.head_dim + rows * 4
+    return rows * cfg.head_dim * 4
+
+
+def kv_fingerprint(cfg: GPTConfig, eps: float, params: Dict) -> str:
+    """16-hex-char identity of (config, eps, parameter names/shapes/
+    dtypes). Two engines with equal fingerprints run the same forward
+    over the same weights *layout*, so their KV pages are
+    interchangeable — the model-identity leg of the KV-handoff compat
+    contract. Weight VALUES are deliberately not hashed (hashing GBs of
+    params per engine start is not worth catching an operator loading
+    two different checkpoints of the same architecture under one
+    fingerprint — the serve artifact prefix already pins the weights)."""
+    return _fingerprint({"config": dataclasses.asdict(cfg),
+                         "eps": float(eps)}, params)
+
+
+def _copy_kv_page(pools, src, dst):
+    """K and V move together so one executable covers both copies."""
+    return tuple(copy_page(p, src, dst) for p in pools)
+
+
+class GPTKind:
+    """`models.gpt`: pools `(k_pool, v_pool)`, each `[layers, P,
+    page_tokens, heads, head_dim]` float32, or the int8 `(data, scale)`
+    pair of `quant.kv`. Wraps the functions that were there, so the
+    step and prefill programs trace as they did."""
+
+    name = "gpt"
+
+    def __init__(self, cfg: GPTConfig, eps: Optional[float] = None):
+        self.cfg = cfg
+        self.eps = 1e-5 if eps is None else float(eps)
+        self.vocab_size = cfg.vocab_size
+        self.max_seq_len = cfg.max_seq_len
+
+    @classmethod
+    def from_model(cls, model, eps=None):
+        return cls(model.cfg, model.ln_f._epsilon if eps is None else eps)
+
+    @classmethod
+    def from_manifest(cls, meta):
+        return cls(GPTConfig(**meta["config"]), meta.get("eps"))
+
+    def manifest(self):
+        return {"config": dataclasses.asdict(self.cfg), "eps": self.eps}
+
+    def default_page_tokens(self):
+        return int(_flags.env_value(_PAGE_TOKENS_ENV))
+
+    def pool_dtype(self, kv_dtype, **features):
+        """The pool dtype this engine runs (every optional feature of
+        the engine exists for this kind)."""
+        return validate_kv_dtype(
+            kv_dtype if kv_dtype is not None
+            else _flags.env_value("PADDLE_TPU_DECODE_KV_DTYPE"))
+
+    def step_fn(self, page_tokens):
+        _, step = gpt_paged_decode_fns(self.cfg, eps=self.eps,
+                                       page_tokens=page_tokens)
+
+        def paged_step(params, pools, tables, last_tok, cache_len):
+            logits, k_pool, v_pool = step(params, *pools, tables, last_tok,
+                                          cache_len)
+            return logits, (k_pool, v_pool)
+
+        return paged_step
+
+    def prefill_fn(self, page_tokens, name="prefill"):
+        inner = gpt_paged_prefill_fns(self.cfg, eps=self.eps,
+                                      page_tokens=page_tokens, name=name)
+
+        def prefill(params, pools, toks, tables, n):
+            logits, k_pool, v_pool = inner(params, *pools, toks, tables, n)
+            return logits, (k_pool, v_pool)
+
+        prefill.__name__ = prefill.__qualname__ = name
+        return prefill
+
+    def _pool_shape(self, num_pages, page_tokens):
+        c = self.cfg
+        return (c.layers, int(num_pages), int(page_tokens), c.heads,
+                c.head_dim)
+
+    def pools_sds(self, num_pages, page_tokens, kv_dtype):
+        p = kv_pool_sds(self._pool_shape(num_pages, page_tokens), kv_dtype)
+        return (p, p)
+
+    def pools_zeros(self, num_pages, page_tokens, kv_dtype):
+        shape = self._pool_shape(num_pages, page_tokens)
+        return (kv_pool_zeros(shape, kv_dtype),
+                kv_pool_zeros(shape, kv_dtype))
+
+    copy_page = staticmethod(_copy_kv_page)
+
+    def page_bytes(self, page_tokens, kv_dtype):
+        return kv_page_bytes(self.cfg, page_tokens, kv_dtype)
+
+    def slot_bytes(self):
+        return kv_slot_bytes(self.cfg)
+
+    def sizing_start(self, free_bytes):
+        """Where the slot-sizing probe starts: the engine's default."""
+        return None
+
+    def fingerprint(self, params):
+        return kv_fingerprint(self.cfg, self.eps, params)
+
+    def counters(self, pools):
+        return {}
+
+
+# ----------------------------------------------------------------- axk1
+
+
+class AXK1Kind:
+    """`models.axk1`: one latent pool a layer plus the device-side
+    routed-assignment counters (`latent_pools_sds`), bfloat16 as the
+    config says. Not in this kind yet, each a typed refusal at
+    construction: speculative decoding, int8 pages, host tiering, KV
+    handoff (ROADMAP R3)."""
+
+    name = "axk1"
+    DEFAULT_PAGE_TOKENS = 128   # 640 bfloat16 lanes a row: 160 KB a page
+
+    def __init__(self, cfg: AXK1Config, eps: Optional[float] = None):
+        if eps is not None and float(eps) != float(cfg.rms_norm_eps):
+            raise ValueError("AXK1Kind: eps is the config's rms_norm_eps")
+        self.cfg = cfg
+        self.eps = float(cfg.rms_norm_eps)
+        self.vocab_size = cfg.vocab_size
+        self.max_seq_len = cfg.max_seq_len
+
+    @classmethod
+    def from_model(cls, model, eps=None):
+        return cls(model.cfg, eps)
+
+    @classmethod
+    def from_manifest(cls, meta):
+        return cls(AXK1Config(**meta["config"]))
+
+    def manifest(self):
+        return {"config": dataclasses.asdict(self.cfg), "eps": self.eps}
+
+    def default_page_tokens(self):
+        """The flag where it is set; else pages of 128 tokens: at the
+        flag's default of 16 a page is 18 KB and the attention kernel's
+        grid has eight times the cells."""
+        if os.environ.get(_PAGE_TOKENS_ENV, "").strip():
+            return int(_flags.env_value(_PAGE_TOKENS_ENV))
+        return self.DEFAULT_PAGE_TOKENS
+
+    def pool_dtype(self, kv_dtype, host_pages=0, handoff=False,
+                   speculative=False):
+        if speculative:
+            raise unsupported(self.name, "speculative decoding "
+                              "(SpecDecodeEngine)", "R3")
+        if kv_dtype not in (None, self.cfg.dtype):
+            raise unsupported(self.name, f"kv_dtype={kv_dtype!r} (latent "
+                              f"pages are {self.cfg.dtype})", "R3")
+        if host_pages:
+            raise unsupported(self.name, "host tiering of latent pages "
+                              "(host_pages)", "R3")
+        if handoff:
+            raise unsupported(self.name, "KV handoff of latent pages",
+                              "R3")
+        return self.cfg.dtype
+
+    def step_fn(self, page_tokens):
+        return axk1_paged_fns(self.cfg, page_tokens)[1]
+
+    def prefill_fn(self, page_tokens, name="prefill"):
+        return axk1_paged_fns(self.cfg, page_tokens, prefill_name=name)[0]
+
+    def pools_sds(self, num_pages, page_tokens, kv_dtype):
+        return latent_pools_sds(self.cfg, num_pages, page_tokens)
+
+    def pools_zeros(self, num_pages, page_tokens, kv_dtype):
+        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                            self.pools_sds(num_pages, page_tokens, kv_dtype))
+
+    @staticmethod
+    def copy_page(pools, src, dst):
+        return dict(pools, latent=tuple(
+            p.at[dst].set(p[src]) for p in pools["latent"]))
+
+    def page_bytes(self, page_tokens, kv_dtype):
+        c = self.cfg
+        return c.num_hidden_layers * int(page_tokens) * c.pool_row_width \
+            * jnp.dtype(c.dtype).itemsize
+
+    def slot_bytes(self):
+        return self.page_bytes(self.max_seq_len, None)
+
+    def sizing_start(self, free_bytes):
+        """Where the slot-sizing probe starts: what the pools' own bytes
+        allow. The step's footprint is the weights plus the pools (its
+        temporaries are a few MB), so the proportional search from the
+        engine's default of 8 would take a dozen compiles to climb."""
+        return max(int(free_bytes // self.slot_bytes()), 1)
+
+    def fingerprint(self, params):
+        return _fingerprint({"kind": self.name,
+                             "config": dataclasses.asdict(self.cfg)},
+                            params)
+
+    def counters(self, pools):
+        """{"routed": [expert layers][held] assignments, "routed_tokens":
+        tokens through the routers}: one device read, for `stats()`."""
+        if pools is None:
+            return {}
+        return {"routed": np.asarray(pools["routed"]).tolist(),
+                "routed_tokens": int(pools["routed_tokens"])}
+
+
+KINDS = {GPTKind.name: GPTKind, AXK1Kind.name: AXK1Kind}
+
+
+def for_config(cfg, eps=None):
+    if isinstance(cfg, GPTConfig):
+        return GPTKind(cfg, eps)
+    if isinstance(cfg, AXK1Config):
+        return AXK1Kind(cfg, eps)
+    raise TypeError(f"no decode model kind for config {type(cfg).__name__}")
+
+
+def for_model(model, eps=None):
+    kind = AXK1Kind if isinstance(model, AXK1) else GPTKind
+    return kind.from_model(model, eps)
+
+
+def from_manifest(meta):
+    """The kind a `save_for_decode` manifest names; one without
+    `"model_kind"` is a GPT (every artifact before the key existed)."""
+    name = meta.get("model_kind", GPTKind.name)
+    if name not in KINDS:
+        raise ValueError(f"decode artifact of unknown model kind {name!r} "
+                         f"(known: {sorted(KINDS)})")
+    return KINDS[name].from_manifest(meta)

@@ -5,7 +5,7 @@ from . import utils  # noqa: F401  (weight_norm_hook import path)
 from .layer.activation import *   # noqa: F401,F403
 from .layer.common import *      # noqa: F401,F403
 from .layer.container import *   # noqa: F401,F403
-from .layer.moe import MoELayer  # noqa: F401
+from .layer.moe import MoELayer, RoutedExperts  # noqa: F401
 from .layer.conv import *        # noqa: F401,F403
 from .layer.layers import Layer  # noqa: F401
 from .layer.loss import *        # noqa: F401,F403

@@ -27,7 +27,8 @@ from ...core.tensor import apply
 from ..initializer import Normal
 from .layers import Layer
 
-__all__ = ["MoELayer", "collect_aux_losses"]
+__all__ = ["MoELayer", "RoutedExperts", "collect_aux_losses",
+           "routed_experts", "route_sigmoid_grouped"]
 
 # trace-local collector: GPT.loss (or any training loss) opens this scope
 # so every MoE layer's load-balance loss from the CURRENT forward is
@@ -153,3 +154,182 @@ class MoELayer(Layer):
         if not isinstance(aux._data, _core.Tracer):
             self.aux_loss = aux   # eager convenience; never store tracers
         return out
+
+
+# ---------------------------------------------------------------------------
+# Dropless routed experts (the DeepSeek-V2/V3 family's expert layer)
+# ---------------------------------------------------------------------------
+#
+# Sigmoid scores over all `n_routed` experts, group-limited top-k, and a
+# layer that is TOLD which experts it holds: `held = (first, count)`. It
+# routes over all of them and computes the part of the result its own
+# experts give; assignments to experts held elsewhere are left out (on a
+# one-chip share nothing stands in for the absent chips or their
+# exchange). No capacity, no dropped token: the many-token path sorts the
+# held assignments by expert and runs one grouped matrix product per
+# projection (`jax.lax.ragged_dot`, which XLA lowers to a tiled grouped
+# kernel on a TPU), the few-token path (a decode step) applies every held
+# expert to every row under a zero/non-zero combine weight. Both are
+# exact; which one runs follows from the static token count.
+
+DENSE_MAX_TOKENS = 256      # at or under this many tokens: the dense path
+SORT_CHUNK_TOKENS = 1024    # the sorted path's working set, in tokens
+
+
+def route_sigmoid_grouped(x, router_w, *, top_k, n_group=1, topk_group=1,
+                          norm_topk_prob=True, scale=1.0):
+    """(idx [N, K] int32 over all routed experts, w [N, K] float32).
+
+    s = sigmoid(x W_r) in float32 (six-pass matmul: the router is small
+    and its picks decide everything after it). With `n_group` > 1 the
+    experts form `n_group` equal groups, a group scores the sum of its
+    two best s, only the `topk_group` best groups stay eligible; then
+    the `top_k` best s among the eligible. w = s of the picked, divided
+    by their sum under `norm_topk_prob`, times `scale`."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    N, E = s.shape
+    pick_from = s
+    if n_group > 1:
+        g = s.reshape(N, n_group, E // n_group)
+        group_score = jax.lax.top_k(g, 2)[0].sum(-1)            # [N, G]
+        _, best = jax.lax.top_k(group_score, topk_group)        # [N, g]
+        keep = jnp.any(best[:, :, None]
+                       == jnp.arange(n_group, dtype=best.dtype), axis=1)
+        pick_from = jnp.where(keep[:, :, None], g, 0.0).reshape(N, E)
+    _, idx = jax.lax.top_k(pick_from, top_k)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    if norm_topk_prob:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * jnp.float32(scale)
+
+
+def _swiglu_f32(g, u):
+    return jax.nn.silu(g) * u
+
+
+def _held_dense(x, local, held, w, wg, wu, wd):
+    """Every held expert over every row, combined under a weight that is
+    zero where the row was not routed to it."""
+    count = wg.shape[0]
+    hit = (local[..., None] == jnp.arange(count, dtype=local.dtype)) \
+        & held[..., None]                                       # [N, K, c]
+    cw = jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=1)     # [N, c]
+    f32 = jnp.float32
+    g = jnp.einsum("nh,ehf->enf", x, wg, preferred_element_type=f32)
+    u = jnp.einsum("nh,ehf->enf", x, wu, preferred_element_type=f32)
+    a = (_swiglu_f32(g, u) * cw.T[:, :, None]).astype(x.dtype)
+    return jnp.einsum("enf,efh->nh", a, wd, preferred_element_type=f32)
+
+
+def _held_sorted(x, local, held, w, wg, wu, wd):
+    """The held assignments sorted by expert, one grouped product per
+    projection, results gathered back per (token, pick)."""
+    N, K = local.shape
+    count = wg.shape[0]
+    f32 = jnp.float32
+    key = jnp.where(held, local, count).reshape(-1)             # [A]
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(count, dtype=key.dtype),
+                    axis=0, dtype=jnp.int32)                    # [count]
+    xs = x[(order // K)]                                        # [A, H]
+    g = jax.lax.ragged_dot(xs, wg, sizes, preferred_element_type=f32)
+    u = jax.lax.ragged_dot(xs, wu, sizes, preferred_element_type=f32)
+    ys = jax.lax.ragged_dot(_swiglu_f32(g, u).astype(x.dtype), wd, sizes,
+                            preferred_element_type=f32)         # [A, H]
+    where = jnp.zeros(N * K, jnp.int32).at[order].set(
+        jnp.arange(N * K, dtype=jnp.int32)).reshape(N, K)
+    # rows past the last group belong to no expert: whatever the
+    # grouped product left there is masked, never multiplied
+    yk = jnp.where(held[..., None], ys[where] * w[..., None], 0.0)
+    return yk.sum(axis=1)
+
+
+def routed_experts(x, router_w, wg, wu, wd, *, top_k, n_group=1,
+                   topk_group=1, norm_topk_prob=True, scale=1.0,
+                   held=None, live=None):
+    """The routed sum of one expert layer over the experts held here.
+
+    x [N, H]; router_w [H, n_routed]; wg, wu [count, H, F]; wd
+    [count, F, H]: the held experts' SwiGLU weights, expert `first + i`
+    at index i. `held = (first, count)`, default all. `live` [N] bool:
+    rows that are padding are neither computed nor counted.
+
+    Returns (y [N, H] float32, hits [count] int32: live assignments per
+    held expert)."""
+    n_routed = router_w.shape[1]
+    first, count = held if held is not None else (0, n_routed)
+    if wg.shape[0] != count:
+        raise ValueError(f"routed_experts: {wg.shape[0]} expert weights "
+                         f"for held={held}")
+    idx, w = route_sigmoid_grouped(
+        x, router_w, top_k=top_k, n_group=n_group, topk_group=topk_group,
+        norm_topk_prob=norm_topk_prob, scale=scale)
+    local = idx - jnp.int32(first)
+    mine = (local >= 0) & (local < count)
+    if live is not None:
+        mine = mine & live[:, None]
+    hits = jnp.sum((local[..., None] == jnp.arange(count, dtype=jnp.int32))
+                   & mine[..., None], axis=(0, 1), dtype=jnp.int32)
+    N = x.shape[0]
+    if N <= DENSE_MAX_TOKENS:
+        return _held_dense(x, local, mine, w, wg, wu, wd), hits
+    chunk = SORT_CHUNK_TOKENS
+    if N <= chunk or N % chunk:
+        return _held_sorted(x, local, mine, w, wg, wu, wd), hits
+
+    def one(args):
+        return _held_sorted(*args, wg, wu, wd)
+
+    parts = jax.lax.map(one, tuple(
+        a.reshape((N // chunk, chunk) + a.shape[1:])
+        for a in (x, local, mine, w)))
+    return parts.reshape(N, -1), hits
+
+
+class RoutedExperts(Layer):
+    """Dropless routed SwiGLU experts as a layer of the framework:
+    y = scale * sum over the top-k picks held here of w_e Expert_e(x).
+
+    `held = (first, count)` of `n_routed` says which experts this layer
+    holds (default all): the router keeps `n_routed` outputs, the expert
+    weights are stacked [count, ...]. Input [..., d_model] -> output of
+    the same shape. The shared expert of a DeepSeek-style block, which
+    every chip computes alike, is not part of this layer."""
+
+    def __init__(self, d_model, d_hidden, n_routed, top_k, n_group=1,
+                 topk_group=1, norm_topk_prob=True,
+                 routed_scaling_factor=1.0, held=None, dtype=None):
+        super().__init__()
+        first, count = held if held is not None else (0, int(n_routed))
+        if first < 0 or count < 1 or first + count > n_routed:
+            raise ValueError(f"held={held} of {n_routed} routed experts")
+        if n_routed % n_group:
+            raise ValueError(f"{n_routed} experts in {n_group} groups")
+        self.held = (int(first), int(count))
+        self.routing = dict(top_k=int(top_k), n_group=int(n_group),
+                            topk_group=int(topk_group),
+                            norm_topk_prob=bool(norm_topk_prob),
+                            scale=float(routed_scaling_factor))
+        init = Normal(0.0, 0.02)
+        self.router = self.create_parameter(
+            [d_model, n_routed], dtype=dtype, default_initializer=init)
+        self.gate_proj = self.create_parameter(
+            [count, d_model, d_hidden], dtype=dtype,
+            default_initializer=init)
+        self.up_proj = self.create_parameter(
+            [count, d_model, d_hidden], dtype=dtype,
+            default_initializer=init)
+        self.down_proj = self.create_parameter(
+            [count, d_hidden, d_model], dtype=dtype,
+            default_initializer=init)
+
+    def forward(self, x):
+        def f(xa, rw, wg, wu, wd):
+            y, _ = routed_experts(xa.reshape(-1, xa.shape[-1]), rw, wg, wu,
+                                  wd, held=self.held, **self.routing)
+            return y.reshape(xa.shape).astype(xa.dtype)
+
+        return apply(f, x, self.router, self.gate_proj, self.up_proj,
+                     self.down_proj, op_name="routed_experts")

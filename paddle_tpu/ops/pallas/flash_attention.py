@@ -152,6 +152,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
 
 def _fwd(q3, k3, v3, scale, causal):
     BH, T, D = q3.shape
+    Dv = v3.shape[2]        # the value width; training's calls have Dv == D
     bq, bk = _block_sizes(T, D)
     nq, nk = T // bq, T // bk
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -165,23 +166,23 @@ def _fwd(q3, k3, v3, scale, causal):
                          memory_space=_VMEM),
             pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, _I0),
                          memory_space=_VMEM),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, _I0),
+            pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, _I0),
                          memory_space=_VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, _I0),
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, _I0),
                          memory_space=_VMEM),
             pl.BlockSpec((1, bq, LANE), lambda b, i, j: (b, i, _I0),
                          memory_space=_VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), q3.dtype),
+            jax.ShapeDtypeStruct((BH, T, Dv), q3.dtype),
             jax.ShapeDtypeStruct((BH, T, LANE), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, LANE), jnp.float32),
             pltpu.VMEM((bq, LANE), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=_common.interpret(),
         **_compiler_params("parallel", "parallel", "arbitrary"),
@@ -436,6 +437,28 @@ def flash_attention(q, k, v, causal=False, scale=None):
 
     o3 = _flash3(to3(q), to3(k), to3(v), float(scale), bool(causal))
     return jnp.transpose(o3.reshape(B, H, T, D), (0, 2, 1, 3))
+
+
+def flash_attention_forward(q, k, v, causal=False, scale=None):
+    """Forward only, for inference: q, k [B, T, H, D] and v
+    [B, T, H, Dv] -> [B, T, H, Dv]. The value width is its own (latent
+    attention's expanded prefill has 192-wide q and k and 128-wide v);
+    no gradient is defined. `flash_attention` keeps one width, which is
+    all its backward kernels know."""
+    B, T, H, D = q.shape
+    Dv = v.shape[-1]
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
+    bq, bk = _block_sizes(T, D)
+    if T % bq or T % bk:
+        raise ValueError(f"flash_attention_forward: seq len {T} must be a "
+                         f"multiple of the block size {bq}")
+
+    def to3(x):
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(B * H, T, x.shape[-1])
+
+    o3, _ = _fwd(to3(q), to3(k), to3(v), float(scale), bool(causal))
+    return jnp.transpose(o3.reshape(B, H, T, Dv), (0, 2, 1, 3))
 
 
 # ---------------------------------------------------------------------------

@@ -45,34 +45,3 @@ def _restore_global_mesh():
     prior = mesh_mod.get_mesh()
     yield
     mesh_mod.set_mesh(prior)
-
-
-# ONE marker for ONE test until the first `benchmark` PR, and no way for
-# a later PR to switch tests off. `tests/chipbench/` is the benchmark's
-# (`paths` in BENCHMARK.json): only a `benchmark` PR may edit it. Its
-# rehearsal test, as PR 25 left it, requires `admit.repack_ms_p50` and
-# `admit.pwrite_ms_p50` to read; ISSUE 26 deleted what they read (the K/V
-# panel's trip through numpy and an admission's second dispatch). The
-# marker expires with the file: it holds only while the file is byte for
-# byte PR 25's, so the first edit to it voids the marker, and the PR
-# after that deletes this block (ROADMAP S3, PERF.md section 7). Strict:
-# should the test pass again, it fails here.
-_PR25_SPAN_METRICS_SHA256 = \
-    "c0d433a2c1106986698457c2b93238902143628127a5710f95c9aab605a11b72"
-
-
-def pytest_collection_modifyitems(items):
-    import hashlib
-
-    nodeid = ("tests/chipbench/test_chipbench_span_metrics.py::"
-              "test_rehearsal_prints_the_ring_metrics_and_no_idle_share")
-    for item in items:
-        if item.nodeid != nodeid:
-            continue
-        with open(str(item.fspath), "rb") as f:
-            digest = hashlib.sha256(f.read()).hexdigest()
-        if digest == _PR25_SPAN_METRICS_SHA256:
-            item.add_marker(pytest.mark.xfail(strict=True, reason=(
-                "PR 26: an admission is one dispatch; no decode.admit."
-                "kv_pull/.repack/.upload span and no exec:decode.pwrite "
-                "is written, so two of RING_METRICS read nothing")))

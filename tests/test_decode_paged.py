@@ -231,9 +231,8 @@ def test_prefill_program_is_named_for_the_trace(gpt_models):
     eng = DecodeEngine(gpt_models["tiny-scan"], max_slots=2,
                        max_new_tokens=4, page_tokens=4)
     try:
-        pool = eng._pool_sds()
-        exe = eng._prefill_exe(eng._prefill_aot, eng.params, pool, pool,
-                               eng.kv_ladder[0])
+        exe = eng._prefill_exe(eng._prefill_aot, eng.params,
+                               eng._model_pools_sds(), eng.kv_ladder[0])
         assert exe.as_text().startswith("HloModule jit_prefill,")
         assert eng._prefill_aot._label == "decode.prefill"
         assert eng._step_aot._jitted.__name__ == "paged_step"
@@ -555,3 +554,44 @@ def test_stats_report_rungs_and_pages_before_first_admission(gpt_models):
         assert st["pages"]["pages_used"] == 0   # prefix off: 5 < 8 page
     finally:
         eng.stop()
+
+
+@pytest.mark.parametrize("n_evict", [1, 3, 7, 100])
+def test_trie_eviction_is_leaf_first_lru_in_one_pass(n_evict):
+    """`_PrefixCache.evict` builds one heap a call; what it removes is
+    what the rule says, one removal at a time: the least recently used
+    of the entries that are leaves at that moment (a chain goes tip to
+    root, never orphaned)."""
+    from paddle_tpu.inference.decode import _PrefixCache
+    from paddle_tpu.memory.page_allocator import PageAllocator
+
+    alloc = PageAllocator(64, label="evict-test")
+    trie = _PrefixCache(alloc, page_tokens=2)
+    rng = np.random.RandomState(5)
+    head = rng.randint(0, 50, size=6).tolist()
+    prompts = [head + rng.randint(0, 50, size=4).tolist() for _ in range(3)] \
+        + [rng.randint(0, 50, size=8).tolist() for _ in range(3)]
+    for p in prompts:
+        pages = alloc.alloc(len(p) // 2, owner=("slot", 0, "t"))
+        trie.insert(p, pages)
+        for page in pages:
+            alloc.release(page, owner=("slot", 0, "t"))
+    for p in (prompts[4], prompts[1]):          # touch two chains
+        for page in trie.lookup(p, owner=("slot", 1, "t"))[0]:
+            alloc.release(page, owner=("slot", 1, "t"))
+
+    entries = {d: list(e) for d, e in trie._entries.items()}
+    kids = dict(trie._kids)
+    want = []
+    for _ in range(min(n_evict, len(entries))):     # the rule, by scans
+        d = min(entries, key=lambda d: (1 if kids.get(d) else 0,
+                                        entries[d][1]))
+        parent = entries.pop(d)[2]
+        want.append(d)
+        if parent is not None:
+            kids[parent] -= 1
+    before = set(trie._entries)
+    assert trie.evict(n_evict) == len(want)
+    assert before - set(trie._entries) == set(want)
+    assert trie.stats()["orphaned"] == 0
+    assert alloc.stats()["pages_used"] == len(trie._entries)

@@ -257,6 +257,14 @@ def test_quota_deferral_queues_never_drops(gpt_models):
     eng = DecodeEngine(model, max_slots=2, max_new_tokens=8,
                        max_pending=64, tenant_quota="capped:8")
     try:
+        # compile the shapes the scenario uses first, under a tenant
+        # that has no quota: the bucket refills by wall time (8 tokens a
+        # second), so a run that compiles its steps as it goes (seconds
+        # each on a cold cache) never falls into debt and nothing is
+        # deferred: the test then failed by the machine's speed
+        for s in [eng.submit(p, max_new_tokens=4, tenant="free")
+                  for p in prompts[:2]]:
+            s.result(timeout=120)
         m0 = _flat('paddle_tpu_tenant_quota_deferred_total'
                    '{tenant="capped"}')
         streams = [eng.submit(p, max_new_tokens=4, tenant="capped")

@@ -201,8 +201,13 @@ def test_engine_oom_dump_accounts_for_every_page():
     eng = DecodeEngine(model, max_slots=2, max_new_tokens=8,
                        page_tokens=4, num_pages=5, prefix_cache=False)
     try:
-        s1 = eng.submit(rng.randint(0, 512, size=8), max_new_tokens=6)
-        time.sleep(0.3)
+        # the first request holds its pages once it has stepped: its
+        # second token means a third page (row 8) is mapped, so the
+        # second request's two pages cannot both be had. (A sleep stood
+        # here for that, and under six workers it was too short.)
+        s1 = eng.submit(rng.randint(0, 512, size=8), max_new_tokens=8)
+        for _ in range(2):
+            assert s1.next_event(timeout=120)[0] == "token"
         s2 = eng.submit(rng.randint(0, 512, size=8), max_new_tokens=6)
         with pytest.raises(TypedServeError):
             s2.result(timeout=120)

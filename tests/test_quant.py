@@ -253,7 +253,7 @@ def test_int8_pool_write_and_copy_pytree():
     err = np.abs(np.asarray(got) - np.asarray(k_rows[:, 0]))
     assert (err <= np.asarray(kp[1][:, 2])[..., None] * 0.5 + 1e-7).all()
     assert int(jnp.abs(kp[0][:, 3].astype(jnp.int32)).sum()) == 0
-    kp, vp = _copy_kv_page(kp, vp, jnp.int32(2), jnp.int32(3))
+    kp, vp = _copy_kv_page((kp, vp), jnp.int32(2), jnp.int32(3))
     np.testing.assert_array_equal(np.asarray(kp[0][:, 3]),
                                   np.asarray(kp[0][:, 2]))
     np.testing.assert_array_equal(np.asarray(kp[1][:, 3]),
