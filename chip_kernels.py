@@ -151,13 +151,14 @@ def decode_cases(H, D, tag):
     k, v = (arr((B, cap, H, D), jnp.float32, 0.5) for _ in range(2))
     case(f"decode contiguous {tag}", da._decode_attention_pallas,
          da.decode_attention_reference, (q, k, v, lengths), tol=2e-2)
-    kp, vp = (arr((P, pt, H, D), jnp.float32, 0.5) for _ in range(2))
+    # a layer's pool: a token's row is its heads side by side
+    kp, vp = (arr((P, pt, H * D), jnp.float32, 0.5) for _ in range(2))
     tables = jnp.asarray(rng.permutation(P - 1)[:B * W].reshape(B, W) + 1,
                          jnp.int32)
     case(f"decode paged {tag}", da._paged_decode_attention_pallas,
          da.paged_decode_attention_reference,
          (q, kp, vp, tables, lengths), tol=2e-2)
-    k8, v8 = (arr((P, pt, H, D), jnp.int8, ints=(-127, 128))
+    k8, v8 = (arr((P, pt, H * D), jnp.int8, ints=(-127, 128))
               for _ in range(2))
     ks, vs = (jnp.abs(arr((P, pt, H), jnp.float32, 0.01)) + 1e-3
               for _ in range(2))

@@ -9,10 +9,12 @@ KV cache instead of per-slot contiguous panels:
   * the KV store is one device-resident page pool plus a
     per-sequence int32 block table; `memory.page_allocator` hands out
     refcounted page ids. What a page holds is the model kind's to say
-    (`inference.model_kinds`: K and V `[layers, pages, page_tokens,
-    heads, head_dim]` for a GPT, one latent row a token a layer for
-    `axk1`); the engine threads the pools as one pytree. Admission allocates pages, eviction releases
-    them — capacity growth is a wider block table, never a cache copy
+    (`inference.model_kinds`: K and V, each one array a layer `[pages,
+    page_tokens, heads * head_dim]`, for a GPT, one latent row a token
+    a layer for `axk1`; page axis 0 on every leaf, a token's row whole,
+    so a step writes rows into the arrays it was given); the engine
+    threads the pools as one pytree. Admission allocates pages,
+    eviction releases them — capacity growth is a wider block table, never a cache copy
     (the contiguous engine re-packed the whole pool on every rung
     change);
   * the compute core is the model kind's prefill-into-pages — one
@@ -111,7 +113,7 @@ from ..memory.migration import (HostPageStore, MigrationEngine,
                                 TieredPageAllocator, deserialize_pages,
                                 serialize_pages, tier_metrics)
 from ..memory.page_allocator import (PageAllocator, PageExhausted,
-                                     gather_pages, write_pages)
+                                     copy_page, gather_pages, write_pages)
 from ..models.gpt import (GPTConfig, gpt_paged_rollout_fns,
                           gpt_paged_verify_fns)
 from ..observability import counter, gauge, histogram
@@ -129,7 +131,7 @@ from . import model_kinds
 from .errors import (ERR_FAILED_PRECONDITION, ERR_INVALID_ARGUMENT,
                      ERR_RESOURCE_EXHAUSTED, ERR_UNAVAILABLE,
                      TypedServeError)
-from .model_kinds import (GPTKind, _copy_kv_page,  # noqa: F401
+from .model_kinds import (GPTKind,  # noqa: F401
                           kv_fingerprint, kv_page_bytes, kv_slot_bytes)
 
 DEFAULT_MAX_SLOTS = 8          # CPU fallback when HBM stats are absent
@@ -358,6 +360,12 @@ def _next_pool_label() -> str:
     return "kv" if n == 1 else f"kv{n}"
 
 
+def greedy_picks(logits):
+    """Every row's first best id, [B] int32: what `np.argmax` reads
+    from the pulled rows, computed where the logits are."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def _trie_owner(digest: bytes) -> tuple:
     """Allocator owner tag for a prefix-trie node (short digest hex)."""
     return ("trie", digest.hex()[:12])
@@ -431,11 +439,14 @@ def default_slot_count(step_jit, params, kind, page_tokens: int,
         return (m.argument_size_in_bytes + m.output_size_in_bytes
                 - m.alias_size_in_bytes + m.temp_size_in_bytes)
 
-    # logical cache bytes per slot bound the count from above
-    upper = max(1, min(int((limit - used) // kind.slot_bytes()), 256))
+    # logical cache bytes per slot bound the count from above, and say
+    # where the probe starts: the step's footprint is the weights plus
+    # the pools (its temporaries are a fraction of them), so from what
+    # the pools' own bytes allow it settles in two or three compiles
+    slot = kind.slot_bytes()
+    upper = max(1, min(int((limit - used) // slot), 256))
     return fit_slot_count(step_bytes, budget, upper,
-                          kind.sizing_start(budget - used)
-                          or DEFAULT_MAX_SLOTS)
+                          max(int((budget - used) // slot), 1))
 
 
 def kv_capacity_ladder(max_seq_len: int,
@@ -637,6 +648,11 @@ class _PrefixCache:
     Each entry tracks its parent digest and a live-child count to make
     leaf status O(1); forced mid-chain removals bump the `orphaned`
     stat (the children remain cached but can never be looked up again).
+    The order is kept in one heap for the trie's life: an entry is
+    pushed under its new key whenever the key changes (inserted,
+    touched, a child gained or the last one lost, back from the host)
+    and a copy whose key is no longer the entry's is skipped when it
+    surfaces, so an eviction costs the pages it frees, not the trie.
 
     With a :class:`~paddle_tpu.memory.TieredPageAllocator` behind it,
     an entry's location may also be a negative **host handle**: the
@@ -655,6 +671,9 @@ class _PrefixCache:
         # device page (one ref held), loc < 0 a host-tier handle
         self._entries: Dict[bytes, List] = {}
         self._kids: Dict[bytes, int] = {}     # digest -> live children
+        # (leaf key, digest) of every device-resident entry under its
+        # current key, among stale copies: see `_push` and `evict`
+        self._heap: List[Tuple[tuple, bytes]] = []
         self._tick = 0
         self._evictions = 0
         self._orphaned = 0
@@ -677,6 +696,7 @@ class _PrefixCache:
             self._kids[parent] -= 1
             if self._kids[parent] <= 0:
                 del self._kids[parent]
+                self._push(parent)            # a leaf now
         self._orphaned += self._kids.pop(d, 0)
         if ent[0] >= 0:
             self._alloc.release(ent[0], owner=_trie_owner(d))
@@ -698,6 +718,7 @@ class _PrefixCache:
                     break
                 self._alloc.retain(ent[0], owner=owner)
                 ent[1] = self._tick
+                self._push(d)
                 pages.append(ent[0])
         return pages, len(pages) * self._pt
 
@@ -737,18 +758,30 @@ class _PrefixCache:
                 if ent is None:
                     self._alloc.retain(p, owner=_trie_owner(d))
                     self._entries[d] = [int(p), self._tick, prev]
+                    self._push(d)
                     if prev is not None and prev in self._entries:
                         self._kids[prev] = self._kids.get(prev, 0) + 1
+                        if self._kids[prev] == 1:
+                            self._push(prev)  # mid-chain now
                 elif ent[0] < 0 and \
                         self._alloc.residency(ent[0]) == Residency.HOST:
                     self._alloc.retain(p, owner=_trie_owner(d))
                     self._alloc.host_drop(ent[0])
                     ent[0] = int(p)
                     ent[1] = self._tick
+                    self._push(d)
                 prev = d
 
     def _leaf_key(self, d: bytes, ent: List):
         return (1 if self._kids.get(d) else 0, ent[1])
+
+    def _push(self, d: bytes):
+        """Enter `d` under its current key (lock held). Called wherever
+        a device-resident entry's key changes; whatever copies it left
+        behind are skipped by `evict`."""
+        ent = self._entries.get(d)
+        if ent is not None and ent[0] >= 0:
+            heapq.heappush(self._heap, (self._leaf_key(d, ent), d))
 
     def evict(self, n: int) -> int:
         """Release up to `n` device-resident entries' pages, leaf-first
@@ -756,26 +789,23 @@ class _PrefixCache:
         whole chain walks it tip-to-root instead of orphaning it)."""
         removed = 0
         with self._lock:
-            # one heap a call, not one scan of the trie a page: an
-            # admission of a 6k prompt evicts 48 pages out of thousands
-            # (52 ms of `decode.admit.alloc` at 4,289 pages, PR 28). A
-            # parent whose last child goes is pushed again under its new
-            # key; the stale copy is skipped when it surfaces.
-            heap = [(self._leaf_key(d, e), d)
-                    for d, e in self._entries.items() if e[0] >= 0]
-            heapq.heapify(heap)
+            # the heap outlives the call: a tick at a hundred slots
+            # evicts at half a dozen page boundaries and two admissions,
+            # a page or a few each, out of thousands of entries (a heap
+            # built per call was 14 ms of such a tick, PR 29; a scan of
+            # the trie per page 52 ms of a long admission, PR 28).
+            heap = self._heap
+            if len(heap) > 4 * len(self._entries) + 64:
+                heap[:] = [(self._leaf_key(d, e), d)
+                           for d, e in self._entries.items() if e[0] >= 0]
+                heapq.heapify(heap)
             while removed < max(n, 0) and heap:
                 key, d = heapq.heappop(heap)
                 e = self._entries.get(d)
                 if e is None or e[0] < 0 or key != self._leaf_key(d, e):
-                    continue
-                parent = e[2]
-                self._remove(d, e)
+                    continue              # a copy the entry moved on from
+                self._remove(d, e)        # pushes a parent that became a leaf
                 removed += 1
-                up = self._entries.get(parent) if parent is not None \
-                    else None
-                if up is not None and up[0] >= 0:
-                    heapq.heappush(heap, (self._leaf_key(parent, up), parent))
             self._evictions += removed
         return removed
 
@@ -812,6 +842,7 @@ class _PrefixCache:
                 return False
             ent[0] = int(page)
             ent[1] = self._tick
+            self._push(d)
             # the caller's allocator ref changes hands: attribution
             # follows it from the tier to this trie node
             self._alloc.retag(page, ("tier", handle), _trie_owner(d))
@@ -854,6 +885,12 @@ class _PrefixCache:
                     self._alloc.host_drop(ent[0])
             self._entries.clear()
             self._kids.clear()
+            self._heap.clear()
+
+    def cached_pages(self) -> int:
+        """Entries held, device and host: one length, for the gauges."""
+        with self._lock:
+            return len(self._entries)
 
     def stats(self) -> Dict:
         with self._lock:
@@ -975,6 +1012,9 @@ class DecodeEngine:
         self._copy_aot = AotCache(
             jax.jit(kind.copy_page, donate_argnums=(0,)), "decode.pcow",
             donate_argnums=(0,))
+        # a tick whose rows are all greedy pulls [B] ids, not [B, V]
+        # logits (17 MB at 84 rows of a 50k vocabulary, every tick)
+        self._pick_aot = AotCache(jax.jit(greedy_picks), "decode.ppick")
         # host-tier / handoff executables: `pgather` snapshots pages
         # into an independent buffer (pools NOT donated — the engine
         # keeps stepping on them), `ptier` scatters rows back in. The
@@ -1179,9 +1219,14 @@ class DecodeEngine:
         return exe(params, pools, jnp.asarray(inp),
                    jnp.asarray(table), jnp.asarray([plen], np.int32))
 
+    def _pick_exe(self, b_rung):
+        return self._pick_aot.get_or_compile(
+            jax.ShapeDtypeStruct((b_rung, self._kind.vocab_size),
+                                 jnp.float32), key=("ppick", b_rung))
+
     def warmup(self, verbose: bool = False) -> int:
         """AOT-compile the fused prefill-into-pages prompt rungs, the
-        copy-on-write executable, and the decode
+        copy-on-write executable, the greedy pick, and the decode
         (batch-rung x page-rung) cross product (capped, largest rungs
         first dropped last). Returns the number of fresh compiles."""
         before = len(profiler.compile_events())
@@ -1204,7 +1249,7 @@ class DecodeEngine:
                 ids = jax.ShapeDtypeStruct((w,), i32)
                 rows = jax.tree.map(
                     lambda s, _w=w: jax.ShapeDtypeStruct(
-                        (s.shape[0], _w) + s.shape[2:], s.dtype), pools)
+                        (_w,) + s.shape[1:], s.dtype), pools)
                 self._gather_aot.get_or_compile(
                     pools, ids, key=("pgather", w))
                 self._tier_write_aot.get_or_compile(
@@ -1219,6 +1264,8 @@ class DecodeEngine:
                 jax.ShapeDtypeStruct((b,), i32),
                 jax.ShapeDtypeStruct((b,), i32),
                 key=("pstep", b, w))
+        for b in self.batch_ladder:
+            self._pick_exe(b)
         n = len(profiler.compile_events()) - before
         if verbose:
             print(f"DECODE WARMUP compiles={n} "
@@ -2013,8 +2060,9 @@ class DecodeEngine:
                 "checksum" if "checksum" in str(e) else "structure",
                 str(e))
         # the payload's leaf structure must be THIS engine's pool
-        # structure — a speculative engine's 4-pool footprint can never
-        # land in a plain engine's 2-pool one, nor across draft shapes
+        # structure — a speculative engine's footprint (target and
+        # draft pools) can never land in a plain engine's (the
+        # target's alone), nor across draft shapes
         sds = jax.tree_util.tree_flatten(self._pools_sds())[0]
         if len(leaves) != len(sds):
             self._handoff_reject(
@@ -2022,7 +2070,7 @@ class DecodeEngine:
                 f"pool structure mismatch ({len(leaves)} payload "
                 f"leaves, engine has {len(sds)})")
         for i, (a, s) in enumerate(zip(leaves, sds)):
-            want = (s.shape[0], n) + tuple(s.shape[2:])
+            want = (n,) + tuple(s.shape[1:])
             if tuple(a.shape) != want \
                     or np.dtype(a.dtype) != np.dtype(s.dtype):
                 self._handoff_reject(
@@ -2051,8 +2099,8 @@ class DecodeEngine:
         w = next_bucket(n, self.page_ladder)
         padded = []
         for a in leaves:
-            out = np.zeros((a.shape[0], w) + a.shape[2:], a.dtype)
-            out[:, :n] = a
+            out = np.zeros((w,) + a.shape[1:], a.dtype)
+            out[:n] = a
             padded.append(out)
         rows = jax.tree_util.tree_unflatten(
             jax.tree_util.tree_structure(self._pools_sds()), padded)
@@ -2250,14 +2298,20 @@ class DecodeEngine:
             logits, self._pool_tree = exe(
                 self.params, self._pool_tree, tables, ltok, clen)
             with _RING.span("decode.step.pull", {}) as pull:
-                lognp = np.asarray(logits)
-                pull.args["bytes"] = lognp.nbytes
+                # greedy rows need their best id and nothing else of
+                # the logits: the device picks it and [B] ids cross;
+                # one sampling row and the tick pulls the logits
+                if all(r.temperature <= 0.0 for r in reqs):
+                    rows = np.asarray(self._pick_exe(b_rung)(logits))
+                else:
+                    rows = np.asarray(logits)
+                pull.args["bytes"] = rows.nbytes
             self._m["step_latency"].observe(time.perf_counter() - t0)
             self._last_b_rung, self._last_w_rung = b_rung, w_rung
             self._steps += 1
             self._m["steps"].inc()
             with _RING.span("decode.sample", {"reqs": len(reqs)}):
-                finished = self._sample_rows(reqs, lognp)
+                finished = self._sample_rows(reqs, rows)
             tick.args.update(batch=len(reqs), b_rung=b_rung, w_rung=w_rung)
         if finished:
             done = {r.id for r in finished}
@@ -2293,10 +2347,13 @@ class DecodeEngine:
             self._update_gauges()
         return taken
 
-    def _sample_rows(self, reqs: List[_Req], lognp: np.ndarray):
+    def _sample_rows(self, reqs: List[_Req], rows: np.ndarray):
         """Advance every slot past the step just run: feed the next
         prompt-tail token, or sample, push and account one new token.
-        Returns the requests that ended."""
+        `rows` is the step's logits [B, V], or the device's greedy
+        picks [B] when every row is greedy. Returns the requests that
+        ended."""
+        picked = rows.ndim == 1
         pt = self.page_tokens
         finished = []
         for j, req in enumerate(reqs):
@@ -2315,7 +2372,8 @@ class DecodeEngine:
             first = not req.generated
             try:
                 chaos.maybe_fail("decode.stream", detail=req.id)
-                tok = self._sample(lognp[j], req)
+                tok = int(rows[j]) if picked \
+                    else self._sample(rows[j], req)
             except Exception as exc:
                 req.stream._push_error(TypedServeError(
                     ERR_UNAVAILABLE, f"decode stream killed: {exc}"))
@@ -2390,14 +2448,17 @@ class DecodeEngine:
             self._m["active"].set(n)
             self._m["occupancy"].set(n / max(self.max_slots, 1))
             self._m["preempted_waiting"].set(len(self._paused))
-            ps = self._alloc.stats()
+            # counts the allocator and the trie keep as pages change
+            # hands: nothing here walks the pool (a refresh follows
+            # every admission and every finishing tick)
+            ps = self._alloc.occupancy()
             self._m["page_pool_size"].set(ps["pages_total"])
             self._m["page_in_use"].set(ps["pages_used"])
             self._m["page_shared"].set(ps["pages_shared"])
             self._m["page_fragmentation"].set(ps["fragmentation"])
             if self._prefix is not None:
                 self._m["prefix_cached_pages"].set(
-                    self._prefix.stats()["cached_pages"])
+                    self._prefix.cached_pages())
             if self._tm is not None:
                 self._tm["resident"].labels(tier="device").set(
                     ps["pages_used"])
@@ -2517,7 +2578,7 @@ class SpecDecodeEngine(DecodeEngine):
             jax.jit(rollout, donate_argnums=(1, 2)), "decode.droll",
             donate_argnums=(1, 2))
         self._dcopy_aot = AotCache(
-            jax.jit(_copy_kv_page, donate_argnums=(0,)), "decode.dcow",
+            jax.jit(copy_page, donate_argnums=(0,)), "decode.dcow",
             donate_argnums=(0,))
         self._verify_aot = AotCache(
             jax.jit(verify, donate_argnums=(1, 2)), "decode.verify",

@@ -10,13 +10,20 @@ features the kind has. The engine threads `pools` through every
 dispatch, donated, and never looks inside.
 
     step(params, pools, tables [B, W], last_tok [B], cache_len [B])
-        -> (logits [B, V], pools)
+        -> (logits [B, V] float32, pools)
     prefill(params, pools, toks [1, R], tables [1, W], n [1])
-        -> (logits [1, V], pools)
+        -> (logits [1, V] float32, pools)
 
-Kinds: `gpt` (`GPTKind`: K and V pools `[layers, P, page_tokens, heads,
-head_dim]`, float32 or int8) and `axk1` (`AXK1Kind`: one latent pool a
-layer, bfloat16, plus the routed-assignment counters). The manifest of a
+The logits stay on the device unless a row samples: a tick of greedy
+rows pulls the engine's `greedy_picks` of them, [B] ids.
+
+Kinds: `gpt` (`GPTKind`: a K and a V pool, each one array a layer `[P,
+page_tokens, heads * head_dim]`, float32 or int8) and `axk1`
+(`AXK1Kind`: one latent pool a layer, bfloat16, plus the
+routed-assignment counters). Both keep the page axis at 0 on every
+page-holding leaf and a token's row whole and lane-dense, so the
+compiled step writes rows into the arrays it was given and
+`memory.page_allocator`'s page ops serve either. The manifest of a
 `save_for_decode` artifact names its kind under `"model_kind"`; one
 without the key is a GPT.
 """
@@ -83,29 +90,38 @@ def kv_page_bytes(cfg: GPTConfig, page_tokens: int,
     return rows * cfg.head_dim * 4
 
 
+# How a `gpt` page lies in a handoff payload, folded into the
+# fingerprint: an engine from before the pools were one array a layer
+# (leaves `[layers, n, page_tokens, heads, head_dim]`, page axis 1) has
+# another fingerprint, so its payload is refused as any other model's
+# would be, with the typed FAILED_PRECONDITION of the compat check.
+KV_PAGE_LAYOUT = "layer x [page, token, head*dim]"
+
+
 def kv_fingerprint(cfg: GPTConfig, eps: float, params: Dict) -> str:
-    """16-hex-char identity of (config, eps, parameter names/shapes/
-    dtypes). Two engines with equal fingerprints run the same forward
-    over the same weights *layout*, so their KV pages are
-    interchangeable — the model-identity leg of the KV-handoff compat
-    contract. Weight VALUES are deliberately not hashed (hashing GBs of
+    """16-hex-char identity of (config, eps, page layout, parameter
+    names/shapes/dtypes). Two engines with equal fingerprints run the
+    same forward over the same weights *layout* into pages of the same
+    layout, so their KV pages are interchangeable — the model-identity
+    leg of the KV-handoff compat contract. Weight VALUES are deliberately not hashed (hashing GBs of
     params per engine start is not worth catching an operator loading
     two different checkpoints of the same architecture under one
     fingerprint — the serve artifact prefix already pins the weights)."""
     return _fingerprint({"config": dataclasses.asdict(cfg),
-                         "eps": float(eps)}, params)
-
-
-def _copy_kv_page(pools, src, dst):
-    """K and V move together so one executable covers both copies."""
-    return tuple(copy_page(p, src, dst) for p in pools)
+                         "eps": float(eps),
+                         "page_layout": KV_PAGE_LAYOUT}, params)
 
 
 class GPTKind:
-    """`models.gpt`: pools `(k_pool, v_pool)`, each `[layers, P,
-    page_tokens, heads, head_dim]` float32, or the int8 `(data, scale)`
-    pair of `quant.kv`. Wraps the functions that were there, so the
-    step and prefill programs trace as they did."""
+    """`models.gpt`: pools `(k_pool, v_pool)`, each a tuple of `layers`
+    layer pools `[P, page_tokens, heads * head_dim]` float32, or the
+    int8 `(data, scale)` pair of `quant.kv` a layer. One layout for
+    every `GPTConfig`: a row of `heads * head_dim` lanes fills whole
+    tiles at any head size, and one array a layer lets the step's row
+    scatter and the admission's page scatter update their operand in
+    place (a stacked `[layers, ..]` pool made the compiler slice a
+    layer out, and `[.., heads, head_dim]` rows made it copy every
+    pool into another layout and back, each step)."""
 
     name = "gpt"
 
@@ -172,17 +188,14 @@ class GPTKind:
         return (kv_pool_zeros(shape, kv_dtype),
                 kv_pool_zeros(shape, kv_dtype))
 
-    copy_page = staticmethod(_copy_kv_page)
+    # K and V, every layer: one executable covers all the copies
+    copy_page = staticmethod(copy_page)
 
     def page_bytes(self, page_tokens, kv_dtype):
         return kv_page_bytes(self.cfg, page_tokens, kv_dtype)
 
     def slot_bytes(self):
         return kv_slot_bytes(self.cfg)
-
-    def sizing_start(self, free_bytes):
-        """Where the slot-sizing probe starts: the engine's default."""
-        return None
 
     def fingerprint(self, params):
         return kv_fingerprint(self.cfg, self.eps, params)
@@ -262,8 +275,7 @@ class AXK1Kind:
 
     @staticmethod
     def copy_page(pools, src, dst):
-        return dict(pools, latent=tuple(
-            p.at[dst].set(p[src]) for p in pools["latent"]))
+        return dict(pools, latent=copy_page(pools["latent"], src, dst))
 
     def page_bytes(self, page_tokens, kv_dtype):
         c = self.cfg
@@ -272,13 +284,6 @@ class AXK1Kind:
 
     def slot_bytes(self):
         return self.page_bytes(self.max_seq_len, None)
-
-    def sizing_start(self, free_bytes):
-        """Where the slot-sizing probe starts: what the pools' own bytes
-        allow. The step's footprint is the weights plus the pools (its
-        temporaries are a few MB), so the proportional search from the
-        engine's default of 8 would take a dozen compiles to climb."""
-        return max(int(free_bytes // self.slot_bytes()), 1)
 
     def fingerprint(self, params):
         return _fingerprint({"kind": self.name,

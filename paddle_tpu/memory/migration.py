@@ -203,11 +203,15 @@ class TieredPageAllocator(PageAllocator):
         with self._lock:
             return self.host_pages - len(self._host_free)
 
+    def occupancy(self) -> Dict:
+        occ = super().occupancy()
+        occ["host_pages_used"] = self.host_used()
+        return occ
+
     def stats(self) -> Dict:
         st = super().stats()
         with self._lock:
             st["host_pages_total"] = self.host_pages
-            st["host_pages_used"] = self.host_pages - len(self._host_free)
             st["host_inflight"] = sum(
                 1 for r in self._residency.values()
                 if r == Residency.IN_FLIGHT)
@@ -220,7 +224,7 @@ class HostPageStore:
     """Preallocated host arenas for spilled page payloads.
 
     ``template`` is a pytree whose leaves carry the *pool* shape
-    ``[..., P, page_tokens, ...]`` (page axis 1) — concrete arrays or
+    ``[P, page_tokens, ...]`` (page axis 0) — concrete arrays or
     ShapeDtypeStructs both work; only ``.shape``/``.dtype`` are read.
     One numpy arena of shape ``(capacity, *leaf_shape_without_P)`` is
     allocated per leaf up front, so a spill is a bounded copy into a
@@ -235,10 +239,9 @@ class HostPageStore:
         self._treedef = jax.tree_util.tree_structure(template)
         self._arenas = []
         for leaf in leaves:
-            shape = tuple(leaf.shape)
-            page_shape = shape[:1] + shape[2:]   # drop the page axis
-            self._arenas.append(np.zeros((self.capacity,) + page_shape,
-                                         dtype=np.dtype(leaf.dtype)))
+            self._arenas.append(np.zeros(
+                (self.capacity,) + tuple(leaf.shape)[1:],   # page axis 0
+                dtype=np.dtype(leaf.dtype)))
 
     def nbytes(self) -> int:
         return sum(a.nbytes for a in self._arenas)
@@ -246,9 +249,9 @@ class HostPageStore:
     def put(self, slot: int, chunk_leaves: Sequence[np.ndarray],
             index: int) -> None:
         """Store page `index` of a gathered chunk (leaf list, each
-        ``[..., W, page_tokens, ...]``) into arena slot `slot`."""
+        ``[W, page_tokens, ...]``) into arena slot `slot`."""
         for arena, leaf in zip(self._arenas, chunk_leaves):
-            arena[slot] = leaf[:, index]
+            arena[slot] = leaf[index]
 
     def assemble(self, slots: Sequence[int], rung: int):
         """Build page rows for `slots`, zero-padded to `rung` pages —
@@ -259,10 +262,8 @@ class HostPageStore:
 
         rows = []
         for arena in self._arenas:
-            out = np.zeros((arena.shape[1], int(rung)) + arena.shape[2:],
-                           dtype=arena.dtype)
-            for j, slot in enumerate(slots):
-                out[:, j] = arena[slot]
+            out = np.zeros((int(rung),) + arena.shape[1:], dtype=arena.dtype)
+            out[:len(slots)] = arena[list(slots)]
             rows.append(out)
         return jax.tree_util.tree_unflatten(self._treedef, rows)
 
@@ -271,8 +272,8 @@ class HostPageStore:
 #
 # The prefill/decode KV handoff ships gathered page chunks between
 # processes over the serve wire protocol. Same leaf discipline as
-# `HostPageStore`: a chunk is a pytree of ``[..., W, page_tokens, ...]``
-# leaves (page axis 1), possibly rung-padded past the real page count.
+# `HostPageStore`: a chunk is a pytree of ``[W, page_tokens, ...]``
+# leaves (page axis 0), possibly rung-padded past the real page count.
 # Serialization slices each leaf to the real count, records per-leaf
 # dtype/shape metadata plus a per-page crc32 chained across leaves, and
 # re-views int8 leaves as uint8 (the wire tensor codec carries no int8
@@ -283,7 +284,7 @@ class HostPageStore:
 def _page_crc(leaves: Sequence[np.ndarray], index: int) -> int:
     c = 0
     for a in leaves:
-        c = zlib.crc32(np.ascontiguousarray(a[:, index]).tobytes(), c)
+        c = zlib.crc32(np.ascontiguousarray(a[index]).tobytes(), c)
     return c
 
 
@@ -297,7 +298,7 @@ def serialize_pages(chunk, count: int) -> Tuple[List[np.ndarray], Dict]:
     import jax
 
     count = int(count)
-    leaves = [np.ascontiguousarray(np.asarray(x)[:, :count])
+    leaves = [np.ascontiguousarray(np.asarray(x)[:count])
               for x in jax.tree_util.tree_flatten(chunk)[0]]
     arrays, leaf_meta = [], []
     for a in leaves:
@@ -313,7 +314,7 @@ def deserialize_pages(arrays: Sequence[np.ndarray],
                       meta: Dict) -> List[np.ndarray]:
     """Inverse of :func:`serialize_pages`: restore leaf dtypes from the
     metadata and validate every page's crc32 chain. Returns the per-leaf
-    arrays (``[..., n_pages, ...]``, page axis 1). Raises ``ValueError``
+    arrays (``[n_pages, page_tokens, ...]``, page axis 0). Raises ``ValueError``
     on any structural or checksum mismatch."""
     leaf_meta = meta.get("leaves") or []
     crcs = list(meta.get("crcs") or [])
@@ -338,10 +339,10 @@ def deserialize_pages(arrays: Sequence[np.ndarray],
                 f"kv payload structure mismatch: leaf {i} is "
                 f"{a.dtype}{list(a.shape)}, descriptor says "
                 f"{dt}{list(shape)}")
-        if len(shape) < 2 or shape[1] != n:
+        if not shape or shape[0] != n:
             raise ValueError(
                 f"kv payload structure mismatch: leaf {i} holds "
-                f"{shape[1] if len(shape) > 1 else 0} pages, "
+                f"{shape[0] if shape else 0} pages, "
                 f"metadata says {n}")
         leaves.append(a)
     for j in range(n):

@@ -4,8 +4,8 @@ The framework's first real device-memory manager (PAPER.md L1:
 `paddle/fluid/memory/` keeps a strategy-selectable allocator stack for
 exactly this job). The allocator itself never touches device memory —
 it hands out integer *page ids* into a pool whose storage the caller
-owns (for decode: `[layers, pages, page_tokens, heads, head_dim]` K/V
-arrays). That keeps it decode-agnostic: any subsystem that wants paged
+owns (for decode: one `[pages, page_tokens, row]` array a layer, the
+page axis first). That keeps it decode-agnostic: any subsystem that wants paged
 device buffers (KV caches today, remat/offload spill later) can reuse
 the same alloc/retain/release/refcount discipline.
 
@@ -47,13 +47,15 @@ pages granted, not O(free · log free).
 
 `write_pages` / `copy_page` are the pure-jax pool ops that pair with
 the bookkeeping: both are shape-stable (jit/AOT-cacheable) updates over
-a pool whose axis 1 is the page axis.
+a pool whose axis 0 is the page axis.
 """
 from __future__ import annotations
 
 import threading
 from bisect import insort
 from typing import Dict, List, Optional, Tuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -120,6 +122,7 @@ class PageAllocator:
         self._allocs = 0
         self._failures = 0
         self._high_water = 0
+        self._shared = 0           # pages at refcount > 1, kept as refs move
 
     # ------------------------------------------------- owner side table
 
@@ -184,6 +187,7 @@ class PageAllocator:
                 raise ValueError(f"retain of unallocated page {page}")
             self._refs[page] += 1
             refs = self._refs[page]
+            self._shared += refs == 2
             self._owner_add(page, tag)
             free = len(self._free)
         _ring_record("retain", self.label, tag, 1, free)
@@ -199,6 +203,7 @@ class PageAllocator:
                 raise ValueError(f"release of unallocated page {page}")
             if refs > 1:
                 self._refs[page] = refs - 1
+                self._shared -= refs == 2
                 self._owner_drop(page, tag)
                 left = refs - 1
             else:
@@ -228,6 +233,7 @@ class PageAllocator:
                 refs = self._refs[p]
                 if refs > 1:
                     self._refs[p] = refs - 1
+                    self._shared -= refs == 2
                     self._owner_drop(p, tag)
                 else:
                     del self._refs[p]
@@ -286,6 +292,29 @@ class PageAllocator:
             return [(p, next(iter(self._owners.get(p) or [UNTAGGED])),
                      r) for p, r in self._refs.items()]
 
+    def occupancy(self) -> Dict:
+        """What a gauge refresh reads, several times a scheduler tick:
+        the page counts (kept as pages change hands) and the free
+        space's fragmentation (one pass over the free list, which is
+        short while a prefix cache holds what requests left behind).
+        Nothing here walks the allocated pages or their owners."""
+        with self._lock:
+            free = np.asarray(self._free)      # already sorted ascending
+            used, shared = len(self._refs), self._shared
+        frag = 0.0
+        if len(free):
+            # run ends: where the next free page is not the next page
+            ends = np.flatnonzero(np.diff(free) != 1)
+            runs = np.diff(np.concatenate(([-1], ends, [len(free) - 1])))
+            frag = 1.0 - int(runs.max()) / len(free)
+        return {
+            "pages_total": self.num_pages - (1 if self.null_page == 0 else 0),
+            "pages_free": len(free),
+            "pages_used": used,
+            "pages_shared": shared,
+            "fragmentation": round(frag, 4),
+        }
+
     def stats(self) -> Dict:
         """Occupancy + fragmentation snapshot (all counts exclude the
         reserved null page). Fragmentation is 1 − largest contiguous
@@ -294,25 +323,13 @@ class PageAllocator:
         ``owner_kinds`` / ``tenants`` are the primary-owner page
         rollups (each sums to ``pages_used``)."""
         with self._lock:
-            free = list(self._free)        # already sorted ascending
-            used = len(self._refs)
-            shared = sum(1 for r in self._refs.values() if r > 1)
             refs_total = sum(self._refs.values())
             allocs, failures = self._allocs, self._failures
             high = self._high_water
-        longest = run = 0
-        for i, p in enumerate(free):
-            run = run + 1 if i and p == free[i - 1] + 1 else 1
-            longest = max(longest, run)
-        frag = 0.0 if not free else 1.0 - longest / len(free)
         by_owner, by_kind, by_tenant = self.owner_rollups()
         return {
-            "pages_total": self.num_pages - (1 if self.null_page == 0 else 0),
-            "pages_free": len(free),
-            "pages_used": used,
-            "pages_shared": shared,
+            **self.occupancy(),
             "refs_total": refs_total,
-            "fragmentation": round(frag, 4),
             "allocs_total": allocs,
             "alloc_failures_total": failures,
             "high_watermark": high,
@@ -342,40 +359,40 @@ class PageAllocator:
 def write_pages(pool, rows, page_ids):
     """Scatter whole pages into the pool.
 
-    pool      [..., P, page_tokens, ...]  (page axis = 1 on every leaf)
-    rows      [..., W, page_tokens, ...]  page-shaped rows to write
-    page_ids  [W] int32                   destination pages (traced ok)
+    pool      [P, page_tokens, ...]  (page axis = 0 on every leaf)
+    rows      [W, page_tokens, ...]  page-shaped rows to write
+    page_ids  [W] int32              destination pages (traced ok)
 
-    `pool` may be a bare array or a pytree (e.g. the int8 pool's
-    ``(data, scale)`` pair from `quant.kv`); `rows` must mirror its
-    structure. Duplicate destinations (e.g. several padding rows aimed
+    `pool` may be a bare array or a pytree (one array a layer, the int8
+    pool's ``(data, scale)`` pair from `quant.kv`, a model kind's whole
+    pools); `rows` must mirror its structure. Duplicate destinations (e.g. several padding rows aimed
     at the null page) resolve arbitrarily — by convention only
     don't-care data is ever aimed at a duplicated id.
     """
-    return jax.tree.map(lambda p, r: p.at[:, page_ids].set(r), pool, rows)
+    return jax.tree.map(lambda p, r: p.at[page_ids].set(r), pool, rows)
 
 
 def copy_page(pool, src, dst):
-    """Copy one page (copy-on-write): pool[:, dst] = pool[:, src] on
-    every pool leaf. `src`/`dst` may be traced scalars, so one
+    """Copy one page (copy-on-write): pool[dst] = pool[src] on every
+    pool leaf. `src`/`dst` may be traced scalars, so one
     executable serves every (src, dst) pair."""
-    return jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]), pool)
+    return jax.tree.map(lambda p: p.at[dst].set(p[src]), pool)
 
 
 def gather_pages(pool, page_ids):
     """Gather whole pages out of the pool into a fresh buffer — the
     shape-stable read twin of `write_pages`.
 
-    pool      [..., P, page_tokens, ...]  (page axis = 1 on every leaf)
-    page_ids  [W] int32                   source pages (traced ok)
+    pool      [P, page_tokens, ...]  (page axis = 0 on every leaf)
+    page_ids  [W] int32              source pages (traced ok)
 
-    The result is an *independent* `[..., W, page_tokens, ...]` buffer
+    The result is an *independent* `[W, page_tokens, ...]` buffer
     per leaf, so the caller may release (and even donate) the pool right
     after dispatch — jax orders the in-flight read before any later
     donation. This is the spill-side primitive of host tiering: gather
     cold pages, hand the chunk to the migration engine, free the pages.
     """
-    return jax.tree.map(lambda p: p[:, page_ids], pool)
+    return jax.tree.map(lambda p: p[page_ids], pool)
 
 
 __all__ = ["PageAllocator", "PageExhausted", "UNTAGGED", "owner_str",

@@ -985,44 +985,32 @@ def _qmm(bp, name, x):
     return int8_weight_matmul(x, bp[name], s)
 
 
-# An fp32 KV pool is a bare [layers, P, page_tokens, heads, head_dim]
-# array; the int8 pool (quant/kv.py) is the (data int8, scale f32)
-# pytree with one scale per (layer, page, row, head). The helpers below
-# branch on that structure at trace time, so every paged decode fn
-# serves both pool dtypes from one code path and the fp32 trace is
-# byte-identical to the pre-quantization implementation.
+# A K/V pool is a tuple of `layers` layer pools, one array a layer:
+# fp32 `[P, page_tokens, heads * head_dim]` (a token's row is its heads
+# side by side: whole 128-lane tiles at any head size, so the compiled
+# step scatters into the array it was given and gathers from it as it
+# lies), or the int8 pair (quant/kv.py) `(data int8 [P, page_tokens,
+# heads * head_dim], scale f32 [P, page_tokens, heads])` with one scale
+# per (page, row, head). The page axis is 0 on every leaf. The helpers
+# below branch on a layer pool's structure at trace time, so every
+# paged decode fn serves both pool dtypes from one code path.
 
-def _kv_pool_write(pool, li, page_idx, offset, rows):
-    """Scatter fresh fp32 K/V rows at [li, page_idx, offset] (`li` may
-    be `slice(None)` for all-layer scatters); int8 pools quantize the
-    rows per (row, head) inside the same executable."""
+def _kv_pool_write(pool, page_idx, offset, rows):
+    """Scatter fresh fp32 K/V rows [..., heads, head_dim] into one
+    layer's pool at [page_idx, offset]; an int8 pool quantizes the rows
+    per (row, head) inside the same executable."""
+    flat = rows.shape[:-2] + (-1,)
     if isinstance(pool, tuple):
         from ..quant.kv import quantize_kv
         data, scale = pool
         q, s = quantize_kv(rows)
-        return (data.at[li, page_idx, offset].set(q),
-                scale.at[li, page_idx, offset].set(s))
-    return pool.at[li, page_idx, offset].set(rows)
-
-
-def _kv_pool_layer(pool, li):
-    """Layer `li`'s pool view: bare array slice, or (data, scale)."""
-    if isinstance(pool, tuple):
-        return pool[0][li], pool[1][li]
-    return pool[li]
-
-
-def _kv_pool_take(pool, tables, axis):
-    """Block-table gather of pool pages as fp32 rows (dequantizing an
-    int8 pool's gathered panel in the same expression)."""
-    if isinstance(pool, tuple):
-        return (jnp.take(pool[0], tables, axis=axis).astype(jnp.float32)
-                * jnp.take(pool[1], tables, axis=axis)[..., None])
-    return jnp.take(pool, tables, axis=axis)
+        return (data.at[page_idx, offset].set(q.reshape(flat)),
+                scale.at[page_idx, offset].set(s))
+    return pool.at[page_idx, offset].set(rows.reshape(flat))
 
 
 def _paged_attend(q, k_layer, v_layer, tables, lengths):
-    """Paged decode attention over one layer's pool view, fused-dequant
+    """Paged decode attention over one layer's pool, fused-dequant
     variant when the pool is int8."""
     if isinstance(k_layer, tuple):
         from ..ops.pallas.decode_attention import paged_decode_attention_quant
@@ -1139,16 +1127,16 @@ def gpt_paged_decode_fns(cfg: GPTConfig, eps: float = 1e-5,
     block tables:
 
     paged_step(params,
-               k_pool, v_pool [layers, P, page_tokens, heads, head_dim],
+               k_pool, v_pool  layers x [P, page_tokens, heads * head_dim],
                tables   [B, W] int32 (unused entries -> null page 0),
                last_tok [B] int32,
                cache_len [B] int32)
         -> (logits [B,V], k_pool, v_pool)
 
     The new token's K/V lands at page tables[b, cache_len//pt], row
-    cache_len%pt, via one advanced-index scatter per layer (padded batch
-    rows carry all-null tables, so their garbage writes fall into the
-    reserved scratch page); attention walks the block table through
+    cache_len%pt, via one row scatter per layer pool, in place (padded
+    batch rows carry all-null tables, so their garbage writes fall into
+    the reserved scratch page); attention walks the block table through
     `ops.pallas.decode_attention.paged_decode_attention`. One executable
     serves every occupancy of a (batch-rung x page-rung) bucket, and —
     unlike the contiguous pool — capacity growth is just a wider block
@@ -1167,9 +1155,34 @@ def gpt_paged_decode_fns(cfg: GPTConfig, eps: float = 1e-5,
                         approximate=False)
         return x + _qmm(bp, "fc2.weight", m) + bp["fc2.bias"]
 
+    @jax.jit
+    def block(bp, x, k_layer, v_layer, tables, page_idx, offset, lengths):
+        """One block over its layer's pools. A jit of its own, so the
+        step's trace holds the block once and calls it a layer: every
+        layer has the same shapes, and an engine traces the step once
+        a (batch rung, page rung) — forty times at a hundred slots,
+        most of what its warm-up costs. The compiler inlines the calls;
+        the pools are still written in place."""
+        B = x.shape[0]
+        h1 = _pp_ln(x, bp["ln1.weight"], bp["ln1.bias"], eps)
+        qkv = _qmm(bp, "attn.qkv.weight", h1) + bp["attn.qkv.bias"]
+        q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
+        q = q.reshape(B, nh, D)
+        k_new = k_new.reshape(B, nh, D)
+        v_new = v_new.reshape(B, nh, D)
+        with jax.named_scope("pool_write"):
+            k_layer = _kv_pool_write(k_layer, page_idx, offset, k_new)
+            v_layer = _kv_pool_write(v_layer, page_idx, offset, v_new)
+        with jax.named_scope("attention"):  # "page_gather" inside it
+            o = _paged_attend(q, k_layer, v_layer, tables,
+                              lengths).reshape(B, -1)
+        x = x + _qmm(bp, "attn.proj.weight", o) + bp["attn.proj.bias"]
+        with jax.named_scope("mlp"):
+            x = _ffn(bp, x)
+        return x, k_layer, v_layer
+
     def paged_step(params, k_pool, v_pool, tables, last_tok, cache_len):
         embed, blocks, head = split_decode_params(params, cfg)
-        B = last_tok.shape[0]
         W = tables.shape[1]
         pos = jnp.clip(cache_len.astype(jnp.int32), 0,
                        cfg.max_seq_len - 1)
@@ -1178,28 +1191,15 @@ def gpt_paged_decode_fns(cfg: GPTConfig, eps: float = 1e-5,
             tables, jnp.minimum(pos // pt, W - 1)[:, None], axis=1)[:, 0]
         offset = pos % pt
         lengths = pos + 1                 # the row just written is live
+        k_pool, v_pool = list(k_pool), list(v_pool)
         for i, bp in enumerate(blocks):
-            h1 = _pp_ln(x, bp["ln1.weight"], bp["ln1.bias"], eps)
-            qkv = _qmm(bp, "attn.qkv.weight", h1) + bp["attn.qkv.bias"]
-            q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(B, nh, D)
-            k_new = k_new.reshape(B, nh, D)
-            v_new = v_new.reshape(B, nh, D)
-            with jax.named_scope("pool_write"):
-                k_pool = _kv_pool_write(k_pool, i, page_idx, offset, k_new)
-                v_pool = _kv_pool_write(v_pool, i, page_idx, offset, v_new)
-            with jax.named_scope("attention"):  # "page_gather" inside it
-                o = _paged_attend(
-                    q, _kv_pool_layer(k_pool, i),
-                    _kv_pool_layer(v_pool, i), tables,
-                    lengths).reshape(B, -1)
-            x = x + _qmm(bp, "attn.proj.weight", o) + bp["attn.proj.bias"]
-            with jax.named_scope("mlp"):
-                x = _ffn(bp, x)
+            x, k_pool[i], v_pool[i] = block(
+                bp, x, k_pool[i], v_pool[i], tables, page_idx, offset,
+                lengths)
         with jax.named_scope("head"):
             xf = _pp_ln(x, head["ln_f.weight"], head["ln_f.bias"], eps)
             logits = xf @ embed["wte.weight"].T
-        return logits, k_pool, v_pool
+        return logits, tuple(k_pool), tuple(v_pool)
 
     prefill, _ = gpt_decode_fns(cfg, eps=eps)
     return prefill, paged_step
@@ -1211,7 +1211,7 @@ def gpt_paged_verify_fns(cfg: GPTConfig, eps: float = 1e-5,
     side of speculative decoding.
 
     paged_verify(params,
-                 k_pool, v_pool [layers, P, page_tokens, heads, head_dim],
+                 k_pool, v_pool  layers x [P, page_tokens, heads * head_dim],
                  tables    [B, W]  int32 (unused entries -> null page 0),
                  toks      [B, K1] int32 (token at position cache_len+i),
                  cache_len [B]     int32)
@@ -1219,8 +1219,8 @@ def gpt_paged_verify_fns(cfg: GPTConfig, eps: float = 1e-5,
 
     Row i of `toks` is the token at absolute position `cache_len + i`;
     its K/V lands at page tables[b, pos//pt], row pos%pt — the exact
-    addressing `paged_step` uses, via one [B, K1] advanced-index scatter
-    per layer. `logits[b, i]` is the target's next-token distribution
+    addressing `paged_step` uses, via one [B, K1] row scatter per layer
+    pool. `logits[b, i]` is the target's next-token distribution
     AFTER consuming toks[b, :i+1], so one call scores every drafted
     position at once. Attention gathers the block table like the XLA
     reference kernel and masks per query: position p attends keys
@@ -1238,7 +1238,6 @@ def gpt_paged_verify_fns(cfg: GPTConfig, eps: float = 1e-5,
     D = cfg.head_dim
     nh = cfg.heads
     pt = int(page_tokens)
-    scale = 1.0 / math.sqrt(D)
 
     def _ffn(bp, x):
         h2 = _pp_ln(x, bp["ln2.weight"], bp["ln2.bias"], eps)
@@ -1247,6 +1246,8 @@ def gpt_paged_verify_fns(cfg: GPTConfig, eps: float = 1e-5,
         return x + _qmm(bp, "fc2.weight", m) + bp["fc2.bias"]
 
     def paged_verify(params, k_pool, v_pool, tables, toks, cache_len):
+        from ..ops.pallas.decode_attention import (gathered_panel, head_mix,
+                                                   head_scores)
         embed, blocks, head = split_decode_params(params, cfg)
         B, K1 = toks.shape
         W = tables.shape[1]
@@ -1260,50 +1261,47 @@ def gpt_paged_verify_fns(cfg: GPTConfig, eps: float = 1e-5,
         page_idx = jnp.where(valid, page_idx, 0)  # overruns -> null page
         offset = pos_c % pt
         kcap = W * pt
-        # Attention is split prefix/window so the pool gather hoists out
-        # of the layer loop: the committed prefix (rows < cache_len) is
-        # gathered ONCE for all layers, while the K1 in-flight tokens
-        # attend each other directly from this dispatch's fresh K/V
-        # under an in-window causal triangle. Score layout per query is
-        # [prefix rows | window rows]; one softmax over the concat keeps
-        # the math identical to the single-gather formulation.
-        keys_all = _kv_pool_take(k_pool, tables, axis=1) \
-            .reshape(len(blocks), B, kcap, nh, D)
-        vals_all = _kv_pool_take(v_pool, tables, axis=1) \
-            .reshape(len(blocks), B, kcap, nh, D)
+        # Attention is split prefix/window so the pool gathers stand
+        # before every write: the committed prefix (rows < cache_len) is
+        # gathered from each layer's pool as the call found it, while
+        # the K1 in-flight tokens attend each other directly from this
+        # dispatch's fresh K/V under an in-window causal triangle. Score
+        # layout per query is [prefix rows | window rows]; one softmax
+        # over the concat keeps the math identical to the single-gather
+        # formulation. Rows stay whole ([.., heads * head_dim]) through
+        # scores and sums, as in the step.
+        keys_all = [gathered_panel(p, tables) for p in k_pool]
+        vals_all = [gathered_panel(p, tables) for p in v_pool]
         prefix_live = jnp.arange(kcap, dtype=jnp.int32)[None, :] \
             < cache_len.astype(jnp.int32)[:, None]            # [B, kcap]
         prefix_live = prefix_live[:, None, None, :]           # [B,1,1,kcap]
         win = jnp.arange(K1, dtype=jnp.int32)
-        win_causal = (win[None, :] <= win[:, None])[None, None]  # [1,1,K1,K1]
+        win_causal = (win[None, :] <= win[:, None])[None, :, None]  # [1,K1,1,K1]
         k_news, v_news = [], []
         for i, bp in enumerate(blocks):
             h1 = _pp_ln(x, bp["ln1.weight"], bp["ln1.bias"], eps)
             qkv = _qmm(bp, "attn.qkv.weight", h1) + bp["attn.qkv.bias"]
-            q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(B, K1, nh, D)
-            k_new = k_new.reshape(B, K1, nh, D)
-            v_new = v_new.reshape(B, K1, nh, D)
+            q, k_new, v_new = jnp.split(qkv, 3, axis=-1)      # [B, K1, C]
             k_news.append(k_new)
             v_news.append(v_new)
-            sp = jnp.einsum("bqhd,bkhd->bhqk", q, keys_all[i]) * scale
-            sp = jnp.where(prefix_live, sp.astype(jnp.float32), -1e30)
-            sw = jnp.einsum("bqhd,bkhd->bhqk", q, k_new) * scale
-            sw = jnp.where(win_causal, sw.astype(jnp.float32), -1e30)
+            sp = jnp.where(prefix_live, head_scores(q, keys_all[i], nh),
+                           -1e30)                             # [B,K1,nh,kcap]
+            sw = jnp.where(win_causal, head_scores(q, k_new, nh), -1e30)
             s = jnp.concatenate([sp, sw], axis=-1)
             p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-            o = jnp.einsum("bhqk,bkhd->bqhd", p[..., :kcap], vals_all[i]) \
-                + jnp.einsum("bhqk,bkhd->bqhd", p[..., kcap:], v_new)
-            o = o.reshape(B, K1, -1)
+            o = head_mix(p[..., :kcap], vals_all[i]) \
+                + head_mix(p[..., kcap:], v_new)              # [B, K1, C]
             x = x + _qmm(bp, "attn.proj.weight", o) + bp["attn.proj.bias"]
             x = _ffn(bp, x)
-        # one all-layer scatter of the fresh K/V (page_idx/offset are
+        # the fresh K/V of every layer (page_idx/offset are
         # layer-invariant); accepted rows persist, rejected rows become
         # garbage above the rolled-back cache_len, overruns hit page 0
-        k_pool = _kv_pool_write(k_pool, slice(None), page_idx, offset,
-                                jnp.stack(k_news))
-        v_pool = _kv_pool_write(v_pool, slice(None), page_idx, offset,
-                                jnp.stack(v_news))
+        k_pool = tuple(
+            _kv_pool_write(p, page_idx, offset, r.reshape(B, K1, nh, D))
+            for p, r in zip(k_pool, k_news))
+        v_pool = tuple(
+            _kv_pool_write(p, page_idx, offset, r.reshape(B, K1, nh, D))
+            for p, r in zip(v_pool, v_news))
         xf = _pp_ln(x, head["ln_f.weight"], head["ln_f.bias"], eps)
         logits = xf @ embed["wte.weight"].T
         amax = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1323,14 +1321,15 @@ def gpt_paged_prefill_fns(cfg: GPTConfig, eps: float = 1e-5,
     run it.
 
     paged_prefill(params,
-                  k_pool, v_pool [layers, P, page_tokens, heads, head_dim],
+                  k_pool, v_pool  layers x [P, page_tokens, heads * head_dim],
                   toks   [1, R] int32 (prompt padded to the rung),
                   tables [1, W] int32 (W >= ceil(R / page_tokens)),
                   n      [1]    int32 (true prompt length)
         -> (logits [1, V], k_pool, v_pool)
 
-    The panel is cut into whole pages and page j lands on tables[0, j]
-    (`write_pages`, one index per page): rows at or past `n` are written
+    Each layer's panel is cut into whole pages and page j lands on
+    tables[0, j] of that layer's pool (`write_pages`, one index per
+    page, in place): rows at or past `n` are written
     as zeros, so rung garbage never enters the pool and a page's tail
     holds nothing stale; table padding aims at the null page, which
     takes whatever falls there. An int8 pool quantizes the pages per
@@ -1349,19 +1348,22 @@ def gpt_paged_prefill_fns(cfg: GPTConfig, eps: float = 1e-5,
         R = toks.shape[1]
         W = tables.shape[1]
         logits, k, v = prefill(params, toks, n)
-        live = (jnp.arange(R, dtype=jnp.int32) < n[0])[None, :, None, None]
+        live = (jnp.arange(R, dtype=jnp.int32) < n[0])[:, None, None]
 
-        def pages(panel):              # [L, 1, R, nh, D] -> [L, W, pt, nh, D]
-            rows = jnp.where(live, panel[:, 0], 0.0)
-            rows = jnp.pad(rows, ((0, 0), (0, W * pt - R), (0, 0), (0, 0)))
-            rows = rows.reshape(rows.shape[0], W, pt, *rows.shape[2:])
-            if isinstance(k_pool, tuple):
+        def pages(panel):              # [1, R, nh, D] -> [W, pt, nh * D]
+            rows = jnp.where(live, panel[0], 0.0)
+            rows = jnp.pad(rows, ((0, W * pt - R), (0, 0), (0, 0)))
+            if isinstance(k_pool[0], tuple):
                 from ..quant.kv import quantize_kv
-                return quantize_kv(rows)
-            return rows
+                q, s = quantize_kv(rows)
+                return q.reshape(W, pt, -1), s.reshape(W, pt, -1)
+            return rows.reshape(W, pt, -1)
 
-        return (logits, write_pages(k_pool, pages(k), tables[0]),
-                write_pages(v_pool, pages(v), tables[0]))
+        def land(pool, panels):
+            return tuple(write_pages(p, pages(panels[i]), tables[0])
+                         for i, p in enumerate(pool))
+
+        return logits, land(k_pool, k), land(v_pool, v)
 
     paged_prefill.__name__ = paged_prefill.__qualname__ = name
     return paged_prefill
@@ -1375,7 +1377,7 @@ def gpt_paged_rollout_fns(cfg: GPTConfig, eps: float = 1e-5,
     k + 1.
 
     paged_rollout(params,
-                  k_pool, v_pool [layers, P, page_tokens, heads, head_dim],
+                  k_pool, v_pool  layers x [P, page_tokens, heads * head_dim],
                   tables [B, W] int32 (unused entries -> null page 0),
                   forced [B, K] int32 (>= 0: the committed token to
                           consume at step i — catch-up; -1: chain the
@@ -1400,7 +1402,6 @@ def gpt_paged_rollout_fns(cfg: GPTConfig, eps: float = 1e-5,
     D = cfg.head_dim
     nh = cfg.heads
     pt = int(page_tokens)
-    scale = 1.0 / math.sqrt(D)
 
     def _ffn(bp, x):
         h2 = _pp_ln(x, bp["ln2.weight"], bp["ln2.bias"], eps)
@@ -1409,6 +1410,8 @@ def gpt_paged_rollout_fns(cfg: GPTConfig, eps: float = 1e-5,
         return x + _qmm(bp, "fc2.weight", m) + bp["fc2.bias"]
 
     def paged_rollout(params, k_pool, v_pool, tables, forced, cache_len):
+        from ..ops.pallas.decode_attention import (gathered_panel, head_mix,
+                                                   head_scores)
         embed, blocks, head = split_decode_params(params, cfg)
         B, K = forced.shape
         W = tables.shape[1]
@@ -1430,26 +1433,21 @@ def gpt_paged_rollout_fns(cfg: GPTConfig, eps: float = 1e-5,
             offset = pos_c % pt
             live = jnp.arange(kcap, dtype=jnp.int32)[None, :] \
                 < (pos_c + 1)[:, None]                       # [B, kcap]
+            k_pool, v_pool = list(k_pool), list(v_pool)
             for li, bp in enumerate(blocks):
                 h1 = _pp_ln(x, bp["ln1.weight"], bp["ln1.bias"], eps)
                 qkv = _qmm(bp, "attn.qkv.weight", h1) + bp["attn.qkv.bias"]
-                q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-                q = q.reshape(B, nh, D)
-                k_new = k_new.reshape(B, nh, D)
-                v_new = v_new.reshape(B, nh, D)
-                k_pool = _kv_pool_write(k_pool, li, page_idx, offset, k_new)
-                v_pool = _kv_pool_write(v_pool, li, page_idx, offset, v_new)
-                keys = _kv_pool_take(_kv_pool_layer(k_pool, li),
-                                     tables, axis=0) \
-                    .reshape(B, kcap, nh, D)
-                vals = _kv_pool_take(_kv_pool_layer(v_pool, li),
-                                     tables, axis=0) \
-                    .reshape(B, kcap, nh, D)
-                s = jnp.einsum("bhd,bkhd->bhk", q, keys) * scale
-                s = s.astype(jnp.float32)
-                s = jnp.where(live[:, None], s, -1e30)
+                q, k_new, v_new = jnp.split(qkv, 3, axis=-1)  # [B, C]
+                k_pool[li] = _kv_pool_write(k_pool[li], page_idx, offset,
+                                            k_new.reshape(B, nh, D))
+                v_pool[li] = _kv_pool_write(v_pool[li], page_idx, offset,
+                                            v_new.reshape(B, nh, D))
+                keys = gathered_panel(k_pool[li], tables)     # [B, kcap, C]
+                vals = gathered_panel(v_pool[li], tables)
+                s = head_scores(q[:, None], keys, nh)         # [B,1,nh,kcap]
+                s = jnp.where(live[:, None, None], s, -1e30)
                 p = jax.nn.softmax(s, axis=-1).astype(vals.dtype)
-                o = jnp.einsum("bhk,bkhd->bhd", p, vals).reshape(B, -1)
+                o = head_mix(p, vals)[:, 0]
                 x = x + _qmm(bp, "attn.proj.weight", o) + bp["attn.proj.bias"]
                 x = _ffn(bp, x)
             xf = _pp_ln(x, head["ln_f.weight"], head["ln_f.bias"], eps)
@@ -1457,12 +1455,12 @@ def gpt_paged_rollout_fns(cfg: GPTConfig, eps: float = 1e-5,
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             drafts = jax.lax.dynamic_update_slice_in_dim(
                 drafts, nxt[:, None], i, axis=1)
-            return nxt, drafts, k_pool, v_pool
+            return nxt, drafts, tuple(k_pool), tuple(v_pool)
 
         prev0 = forced[:, 0]
         drafts0 = jnp.zeros((B, K), jnp.int32)
         _, drafts, k_pool, v_pool = jax.lax.fori_loop(
-            0, K, step, (prev0, drafts0, k_pool, v_pool))
+            0, K, step, (prev0, drafts0, tuple(k_pool), tuple(v_pool)))
         return drafts, k_pool, v_pool
 
     return paged_rollout

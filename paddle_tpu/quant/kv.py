@@ -1,14 +1,20 @@
-"""Int8 KV page pools for the paged decode engine.
+"""K/V page pools for the paged decode engine, float32 and int8.
 
-An fp32 pool is a bare ``[layers, pages, page_tokens, heads, head_dim]``
-array; the int8 pool is the pytree ``(data int8, scale f32)`` where the
-scale drops the trailing ``head_dim`` axis — one symmetric scale per
-(layer, page, token row, head). Per-row scales mean a freshly written
-token never forces requantization of its page, and a COW page copy is a
-plain two-leaf copy. Every pool consumer (`memory.page_allocator` pool
-ops, the decode fns in `models.gpt`, the engine's AOT signatures)
-branches on the pytree structure at trace time, so the fp32 path traces
-byte-identically to the pre-quantization code.
+A pool (K or V) is a tuple of `layers` layer pools, one array a layer,
+page axis 0. A float32 layer pool is a bare ``[pages, page_tokens,
+heads * head_dim]`` array: a token's row is its heads side by side, so
+the minor dimension fills whole 128-lane tiles at any head size and
+the compiled step writes into, and gathers from, the array it was
+given (with ``[.., heads, head_dim]`` minor dimensions the compiler
+copied every pool into a layout it could gather from, and back, every
+step). The int8 layer pool is the pair ``(data int8 [pages,
+page_tokens, heads * head_dim], scale f32 [pages, page_tokens,
+heads])`` — one symmetric scale per (page, token row, head). Per-row
+scales mean a freshly written token never forces requantization of its
+page, and a COW page copy is a plain copy of every leaf. Every pool
+consumer (`memory.page_allocator` pool ops, the decode fns in
+`models.gpt`, the engine's AOT signatures) branches on a layer pool's
+structure at trace time.
 
 Byte math per element: 1 (int8 payload) + 4 / head_dim (amortized
 scale) versus 4 fp32 — a 3.76x reduction at head_dim 64.
@@ -46,27 +52,31 @@ def quantize_kv(rows: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 
 def dequantize_kv(data: jax.Array, scale: jax.Array) -> jax.Array:
-    """Inverse of :func:`quantize_kv`: ``q * scale`` broadcast over D."""
+    """Inverse of :func:`quantize_kv`: ``q * scale`` broadcast over D
+    (rows as ``[..., H, D]``; a pool's ``[..., H * D]`` rows go through
+    `ops.pallas.decode_attention.dequantize_rows`)."""
     return data.astype(jnp.float32) * scale[..., None]
 
 
-PoolLike = Union[jax.Array, Tuple[jax.Array, jax.Array]]
-
-
-def kv_pool_zeros(shape: Sequence[int], kv_dtype: str = "float32") -> PoolLike:
-    """Zero-initialized pool pytree for ``shape`` = [L, P, pt, nh, D]."""
-    shape = tuple(int(s) for s in shape)
-    if validate_kv_dtype(kv_dtype) == "int8":
-        return (jnp.zeros(shape, jnp.int8), jnp.zeros(shape[:-1], jnp.float32))
-    return jnp.zeros(shape, jnp.float32)
+LayerPool = Union[jax.Array, Tuple[jax.Array, jax.Array]]
+PoolLike = Tuple[LayerPool, ...]
 
 
 def kv_pool_sds(shape: Sequence[int], kv_dtype: str = "float32") -> PoolLike:
-    """ShapeDtypeStruct pytree matching :func:`kv_pool_zeros` (warmup/AOT)."""
-    shape = tuple(int(s) for s in shape)
+    """ShapeDtypeStruct pytree (warmup/AOT) of the pool that holds
+    ``shape`` = (layers, pages, page_tokens, heads, head_dim): `layers`
+    layer pools, each ``[pages, page_tokens, heads * head_dim]``."""
+    layers, pages, pt, heads, dim = (int(s) for s in shape)
     if validate_kv_dtype(kv_dtype) == "int8":
-        return (
-            jax.ShapeDtypeStruct(shape, jnp.int8),
-            jax.ShapeDtypeStruct(shape[:-1], jnp.float32),
-        )
-    return jax.ShapeDtypeStruct(shape, jnp.float32)
+        layer = (jax.ShapeDtypeStruct((pages, pt, heads * dim), jnp.int8),
+                 jax.ShapeDtypeStruct((pages, pt, heads), jnp.float32))
+    else:
+        layer = jax.ShapeDtypeStruct((pages, pt, heads * dim), jnp.float32)
+    return tuple(layer for _ in range(layers))
+
+
+def kv_pool_zeros(shape: Sequence[int], kv_dtype: str = "float32") -> PoolLike:
+    """Zero-initialized pool matching :func:`kv_pool_sds`, every leaf a
+    buffer of its own (the step donates them one by one)."""
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                        kv_pool_sds(shape, kv_dtype))

@@ -304,7 +304,9 @@ def test_routed_layer_is_a_layer_of_the_framework():
 
 def _engine_logits(model, prompts, max_new, **engine_kw):
     """Each request's tokens and the logits rows the engine sampled
-    them from (prefill's, then every step's)."""
+    them from (prefill's, then every step's). The requests sample
+    with `top_k=1`, which leaves the best id alone: a tick of greedy
+    rows would pull the device's picks and no logits."""
     eng = DecodeEngine(model, max_slots=3, page_tokens=8,
                        max_new_tokens=max_new, **engine_kw)
     rows = {}
@@ -316,7 +318,8 @@ def _engine_logits(model, prompts, max_new, **engine_kw):
 
     eng._sample = tap
     try:
-        streams = [eng.submit(p, max_new_tokens=max_new) for p in prompts]
+        streams = [eng.submit(p, max_new_tokens=max_new, temperature=1.0,
+                              top_k=1) for p in prompts]
         tokens = [s.result(timeout=300) for s in streams]
         stats = eng.stats()
     finally:

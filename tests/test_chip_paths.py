@@ -227,3 +227,97 @@ def test_router_process_never_initialises_a_backend():
     p = _run([sys.executable, "-c", code], JAX_PLATFORMS="cpu")
     assert p.returncode == 0, p.stderr[-2000:]
     assert "BACKEND False" in p.stdout
+
+
+# -- the gpt pools are used in place by the compiled step --------------------
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a v5e this machine does not have, for the chipless
+    compiler. Described inside the fixture (never at import: one
+    process at a time may load the TPU's library, and every xdist
+    worker imports this file)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def pool_pass_probe(v5e_chip):
+    """The gpt step and prefill-into-pages compiled for that chip (2
+    layers, 768 wide, 12 heads of 64; 16 slots of 64 pages of 16), and
+    what each makes at a layer pool's size."""
+    import re
+
+    import jax.numpy as jnp
+
+    from paddle_tpu import framework
+    from paddle_tpu.inference import model_kinds
+    from paddle_tpu.models.gpt import GPT, GPTConfig
+
+    cfg = GPTConfig(vocab_size=512, max_seq_len=1024, hidden=768, layers=2,
+                    heads=12)
+    kind = model_kinds.for_config(cfg, 1e-5)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=v5e_chip), tree)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=v5e_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: framework.param_arrays(GPT(cfg))))
+    n, pt, W = 16, 16, 64
+    P = n * W + 1
+    pools = on_chip(kind.pools_sds(P, pt, "float32"))
+    out = {"pool_bytes": sum(x.size * x.dtype.itemsize
+                             for x in jax.tree.leaves(pools))}
+    pool_shape = "f32[%d,%d,%d]" % (P, pt, cfg.heads * cfg.head_dim)
+    # as served: matmuls at XLA's default precision (conftest.py asks
+    # for "highest", for the CPU's sake)
+    with jax.default_matmul_precision("default"):
+        programs = {
+            "step": jax.jit(kind.step_fn(pt), donate_argnums=(1,)).lower(
+                params, pools, ints(n, W), ints(n), ints(n)),
+            "prefill": jax.jit(kind.prefill_fn(pt),
+                               donate_argnums=(1,)).lower(
+                params, pools, ints(1, 256), ints(1, 16), ints(1))}
+    for name, lowered in programs.items():
+        exe = lowered.compile()
+        text = exe.as_text()
+        made = []        # what the program itself writes at a pool's size
+        for line in text[text.index("ENTRY "):].splitlines():
+            m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+\[[\d,]*\])\S* "
+                         r"([\w\-]+)\(", line)
+            if m and m.group(2) == pool_shape and m.group(3) not in (
+                    "parameter", "bitcast", "get-tuple-element"):
+                made.append((m.group(3), "scatter" in line))
+        mem = exe.memory_analysis()
+        out[name] = {"temp": mem.temp_size_in_bytes,
+                     "alias": mem.alias_size_in_bytes, "made": made}
+    return out
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_gpt_pools_are_written_in_place_on_v5e(pool_pass_probe, program):
+    """What S5 was: with `[.., heads, head_dim]` minor dimensions the
+    compiler copied each pool into a layout it could gather from and
+    back, every step and every admission, and a stacked pool cost a
+    slice a layer. One lane-dense array a layer: the only thing the
+    program makes at a layer pool's size is the scatter into the very
+    buffer it was given (two a layer, K and V), every pool byte is
+    aliased to its output, and the temporaries are a fraction of the
+    pools (they were four times the pools)."""
+    got = pool_pass_probe[program]
+    layers = 2
+    assert len(got["made"]) == 2 * layers, got["made"]
+    assert all(op == "fusion" and is_scatter
+               for op, is_scatter in got["made"]), got["made"]
+    assert got["alias"] >= pool_pass_probe["pool_bytes"]
+    assert got["temp"] < pool_pass_probe["pool_bytes"] / 4, got

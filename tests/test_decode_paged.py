@@ -86,19 +86,70 @@ def test_page_allocator_refcounts_and_stats():
     assert st["allocs_total"] == 4 and st["alloc_failures_total"] == 0
 
 
-def test_pool_ops_write_and_copy():
+def _kind_pools(which, pages, pt):
+    """Zeroed page-holding pools of a model kind at a tiny size."""
+    from paddle_tpu.inference import model_kinds
+    from paddle_tpu.models.axk1 import axk1_tiny
+
+    if which == "axk1":
+        kind = model_kinds.for_config(axk1_tiny())
+        return kind.pools_zeros(pages, pt, None)["latent"]
+    kind = model_kinds.for_config(
+        GPTConfig(vocab_size=64, max_seq_len=16, hidden=24, layers=2,
+                  heads=3))
+    return kind.pools_zeros(pages, pt, which)
+
+
+@pytest.mark.parametrize("which", ["float32", "int8", "axk1"])
+def test_page_ops_round_trip_on_a_kinds_pools(which):
+    """`write_pages`, `gather_pages`, `copy_page`, the wire codec and
+    the host arena move whole pages of either kind's pools: one
+    convention, page axis 0 on every leaf, a leaf a layer."""
+    import jax
     import jax.numpy as jnp
-    pool = jnp.zeros((2, 4, 3, 2), jnp.float32)      # [L, P, pt, D]
-    rows = jnp.arange(2 * 2 * 3 * 2, dtype=jnp.float32).reshape(2, 2, 3, 2)
-    pool = write_pages(pool, rows, jnp.asarray([2, 1], jnp.int32))
-    np.testing.assert_array_equal(np.asarray(pool[:, 2]),
-                                  np.asarray(rows[:, 0]))
-    np.testing.assert_array_equal(np.asarray(pool[:, 1]),
-                                  np.asarray(rows[:, 1]))
-    assert float(jnp.abs(pool[:, 3]).sum()) == 0.0
-    pool = copy_page(pool, jnp.int32(2), jnp.int32(3))
-    np.testing.assert_array_equal(np.asarray(pool[:, 3]),
-                                  np.asarray(pool[:, 2]))
+
+    from paddle_tpu.memory.migration import (HostPageStore,
+                                             deserialize_pages,
+                                             serialize_pages)
+    from paddle_tpu.memory.page_allocator import gather_pages
+
+    P, pt, W = 6, 4, 3
+    pools = _kind_pools(which, P, pt)
+    leaves, treedef = jax.tree.flatten(pools)
+    assert all(x.shape[:2] == (P, pt) for x in leaves)
+    assert len(leaves) == {"float32": 4, "int8": 8, "axk1": 3}[which]
+    rs = np.random.RandomState(3)
+    rows = jax.tree.unflatten(treedef, [
+        jnp.asarray(rs.randint(-9, 9, size=(W,) + x.shape[1:]), x.dtype)
+        for x in leaves])
+    ids = jnp.asarray([4, 1, 2], jnp.int32)
+    pools = write_pages(pools, rows, ids)
+    for got, want in zip(jax.tree.leaves(pools), jax.tree.leaves(rows)):
+        np.testing.assert_array_equal(np.asarray(got[ids]), np.asarray(want))
+        assert not np.asarray(got[jnp.asarray([0, 3, 5])]).any()
+    chunk = gather_pages(pools, ids)
+    for got, want in zip(jax.tree.leaves(chunk), jax.tree.leaves(rows)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    pools = copy_page(pools, jnp.int32(4), jnp.int32(5))
+    for x in jax.tree.leaves(pools):
+        np.testing.assert_array_equal(np.asarray(x[5]), np.asarray(x[4]))
+    # the wire: two real pages of a rung-padded chunk of three
+    arrays, meta = serialize_pages(chunk, 2)
+    back = deserialize_pages(arrays, meta)
+    for got, want in zip(back, jax.tree.leaves(rows)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, np.asarray(want)[:2])
+    # the host arena: put pages 2 and 0 of the chunk, assemble at rung 4
+    store = HostPageStore(pools, capacity=2)
+    host = [np.asarray(x) for x in jax.tree.leaves(chunk)]
+    store.put(0, host, 2)
+    store.put(1, host, 0)
+    out = store.assemble([1, 0], rung=4)
+    assert jax.tree.structure(out) == treedef
+    for got, want in zip(jax.tree.leaves(out), host):
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(got[1], want[2])
+        assert got.shape[0] == 4 and not got[2:].any()
 
 
 def test_kv_capacity_ladder_floor_follows_page_size():
@@ -150,14 +201,19 @@ def test_fused_prefill_writes_what_the_three_hops_wrote(plen, kv_dtype):
     pages = list(rs.permutation(np.arange(1, P))[:n_pages])
     table = np.zeros((1, w), np.int32)
     table[0, :n_pages] = pages
-    shape = (cfg.layers, P, pt, cfg.heads, cfg.head_dim)
+    row = cfg.heads * cfg.head_dim
 
-    def dirty_pool(seed):
+    def dirty_pool(seed):              # one array a layer, rows whole
         r = np.random.RandomState(seed)
-        if kv_dtype == "int8":
-            return (jnp.asarray(r.randint(-127, 128, size=shape), jnp.int8),
-                    jnp.asarray(r.rand(*shape[:-1]), jnp.float32))
-        return jnp.asarray(r.randn(*shape), jnp.float32)
+
+        def layer():
+            if kv_dtype == "int8":
+                return (jnp.asarray(r.randint(-127, 128, size=(P, pt, row)),
+                                    jnp.int8),
+                        jnp.asarray(r.rand(P, pt, cfg.heads), jnp.float32))
+            return jnp.asarray(r.randn(P, pt, row), jnp.float32)
+
+        return tuple(layer() for _ in range(cfg.layers))
 
     k0, v0 = dirty_pool(1), dirty_pool(2)
     n = jnp.asarray([plen], jnp.int32)
@@ -171,24 +227,31 @@ def test_fused_prefill_writes_what_the_three_hops_wrote(plen, kv_dtype):
                                   np.asarray(want_logits))
     others = [p for p in range(1, P) if p not in pages]
     for got, before, panel in ((k1, k0, k), (v1, v0, v)):
+        assert jax.tree.structure(got) == jax.tree.structure(before)
         rows = np.zeros((cfg.layers, w * pt, cfg.heads, cfg.head_dim),
                         np.float32)
         rows[:, :plen] = np.asarray(panel)[:, 0, :plen]
-        rows = jnp.asarray(rows.reshape(cfg.layers, w, pt, cfg.heads,
-                                        cfg.head_dim))
-        if kv_dtype == "int8":
-            rows = jax.jit(quantize_kv)(rows)
-        want = jax.jit(write_pages)(before, rows, jnp.asarray(table[0]))
-        for g, wnt, b in zip(jax.tree.leaves(got), jax.tree.leaves(want),
-                             jax.tree.leaves(before)):
-            g, wnt, b = np.asarray(g), np.asarray(wnt), np.asarray(b)
-            assert g.dtype == b.dtype and g.shape == b.shape
-            np.testing.assert_array_equal(g[:, pages], wnt[:, pages])
-            np.testing.assert_array_equal(g[:, others], b[:, others])
-        # the tail of a partial page is zero, not what the page held
-        data = np.asarray(jax.tree.leaves(got)[0])
-        tail = data[:, pages[-1], plen - (n_pages - 1) * pt:]
-        assert not tail.any()
+        for li in range(cfg.layers):
+            page_rows = jnp.asarray(rows[li])
+            if kv_dtype == "int8":
+                q, sc = jax.jit(quantize_kv)(page_rows)
+                page_rows = (q.reshape(w, pt, row),
+                             sc.reshape(w, pt, cfg.heads))
+            else:
+                page_rows = page_rows.reshape(w, pt, row)
+            want = jax.jit(write_pages)(before[li], page_rows,
+                                        jnp.asarray(table[0]))
+            for g, wnt, b in zip(jax.tree.leaves(got[li]),
+                                 jax.tree.leaves(want),
+                                 jax.tree.leaves(before[li])):
+                g, wnt, b = np.asarray(g), np.asarray(wnt), np.asarray(b)
+                assert g.dtype == b.dtype and g.shape == b.shape
+                np.testing.assert_array_equal(g[pages], wnt[pages])
+                np.testing.assert_array_equal(g[others], b[others])
+            # the tail of a partial page is zero, not what the page held
+            data = np.asarray(jax.tree.leaves(got[li])[0])
+            tail = data[pages[-1], plen - (n_pages - 1) * pt:]
+            assert not tail.any()
 
 
 def test_admission_is_one_dispatch_and_no_host_trip(gpt_models):
@@ -595,3 +658,153 @@ def test_trie_eviction_is_leaf_first_lru_in_one_pass(n_evict):
     assert before - set(trie._entries) == set(want)
     assert trie.stats()["orphaned"] == 0
     assert alloc.stats()["pages_used"] == len(trie._entries)
+
+
+# --------------------- attention over rows that hold every head whole
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+@pytest.mark.parametrize("heads,dim", [(3, 8), (2, 64), (12, 64)],
+                         ids=["3x8", "2x64", "12x64"])
+def test_paged_attention_matches_per_head_einsum(kernel, heads, dim):
+    """A layer's pool keeps a token's heads side by side in one row
+    `[P, pt, heads * head_dim]`; both readers (the gather that keeps the
+    panel in that layout, and the opt-in Pallas kernel) give what the
+    per-head einsum over `[.., heads, head_dim]` gives."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.decode_attention import paged_decode_attention
+
+    rs = np.random.RandomState(heads * dim)
+    P, pt, B, W = 9, 4, 3, 3
+    k = rs.randn(P, pt, heads, dim).astype(np.float32)
+    v = rs.randn(P, pt, heads, dim).astype(np.float32)
+    q = rs.randn(B, heads, dim).astype(np.float32)
+    tables = rs.randint(0, P, size=(B, W)).astype(np.int32)
+    lengths = np.asarray([1, 7, 12], np.int32)
+    got = paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(k.reshape(P, pt, heads * dim)),
+        jnp.asarray(v.reshape(P, pt, heads * dim)), jnp.asarray(tables),
+        jnp.asarray(lengths), kernel=kernel)
+    kk = k[tables].reshape(B, W * pt, heads, dim)
+    vv = v[tables].reshape(B, W * pt, heads, dim)
+    s = np.einsum("bhd,bkhd->bhk", q, kk) / np.sqrt(dim)
+    s = np.where(np.arange(W * pt)[None, None] < lengths[:, None, None],
+                 s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    want = np.einsum("bhk,bkhd->bhd", p, vv)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+
+
+# ------------------------------------- what a tick pulls from the device
+
+def test_greedy_tick_pulls_ids_and_a_sampling_row_pulls_logits(gpt_models):
+    """A tick whose rows are all greedy pulls the device's picks, [B]
+    int32, and one sampling row makes it pull the [B, V] logits; the
+    tokens are the same either way (`top_k=1` leaves the best id)."""
+    from paddle_tpu.observability.tracez import RING
+
+    model = gpt_models["tiny-scan"]
+    prompts = [np.arange(1, 6), np.arange(3, 12)]
+
+    def run(**sampling):
+        eng = DecodeEngine(model, max_slots=2, max_new_tokens=6,
+                           page_tokens=4)
+        try:
+            eng.warmup()
+            tid = eng._thread.ident
+            RING.clear()
+            streams = [eng.submit(prompts[0], max_new_tokens=6),
+                       eng.submit(prompts[1], max_new_tokens=6, **sampling)]
+            toks = [s.result(timeout=120) for s in streams]
+        finally:
+            eng.stop()
+        pulls = {args["bytes"] for ph, name, _, _, etid, args in
+                 RING.snapshot()[0]
+                 if name == "decode.step.pull" and etid == tid}
+        return toks, pulls
+
+    greedy, pulled_ids = run()
+    mixed, pulled_rows = run(temperature=1.0, top_k=1)
+    assert greedy == mixed
+    v = model.cfg.vocab_size
+    assert pulled_ids and all(b % 4 == 0 and b < v for b in pulled_ids)
+    assert any(b % (4 * v) == 0 for b in pulled_rows)
+
+
+# ------------------------- counts kept where pages change hands (S8)
+
+def test_allocator_occupancy_keeps_its_counts_under_churn():
+    """`occupancy()` (what a gauge refresh reads) walks no allocated
+    page: the shared count moves with retain / release /
+    release_range, and agrees with a recount after every operation."""
+    rs = np.random.RandomState(5)
+    a = PageAllocator(40)
+    held = []
+    for _ in range(600):
+        op = rs.randint(4)
+        if op == 0 and a.free_count() >= 3:
+            held += a.alloc(int(rs.randint(1, 4)))
+        elif op == 1 and held:
+            p = held[rs.randint(len(held))]
+            a.retain(p)
+            held.append(p)
+        elif op == 2 and held:
+            a.release(held.pop(rs.randint(len(held))))
+        elif op == 3 and len(held) > 2:
+            tail = [held.pop() for _ in range(2)]
+            a.release_range(tail, 0)
+        occ, st = a.occupancy(), a.stats()
+        refs = {}
+        for p in held:
+            refs[p] = refs.get(p, 0) + 1
+        assert occ["pages_used"] == len(refs)
+        assert occ["pages_shared"] == sum(r > 1 for r in refs.values())
+        assert occ["pages_free"] == 39 - len(refs)
+        assert {k: st[k] for k in occ} == occ
+        free = sorted(set(range(1, 40)) - set(refs))
+        runs, run = [0], 0
+        for i, p in enumerate(free):
+            run = run + 1 if i and p == free[i - 1] + 1 else 1
+            runs.append(run)
+        want = 1.0 - max(runs) / len(free) if free else 0.0
+        assert occ["fragmentation"] == round(want, 4)
+
+
+def test_prefix_eviction_order_is_the_scan_s_under_churn():
+    """The trie keeps one heap for its life; what it evicts is what a
+    scan of every entry would pick (leaf-first, then least recently
+    touched, then by digest), through inserts, hits and evictions."""
+    from paddle_tpu.inference.decode import _PrefixCache
+
+    rs = np.random.RandomState(9)
+    alloc = PageAllocator(400)
+    pc = _PrefixCache(alloc, page_tokens=2)
+    prompts = []
+    for step in range(300):
+        op = rs.randint(3)
+        if op == 0 or not prompts:
+            base = prompts[rs.randint(len(prompts))][:2 * rs.randint(0, 4)] \
+                if prompts and rs.randint(2) else []
+            prompt = list(base) + rs.randint(0, 50, size=2 * rs.randint(
+                1, 5)).tolist()
+            pages = alloc.alloc(len(prompt) // 2)
+            pc.insert(prompt, pages)
+            for p in pages:
+                alloc.release(p)
+            prompts.append(prompt)
+        elif op == 1:
+            hit, _ = pc.lookup(prompts[rs.randint(len(prompts))])
+            for p in hit:
+                alloc.release(p)
+        else:
+            with pc._lock:
+                order = sorted((pc._leaf_key(d, e), d)
+                               for d, e in pc._entries.items())
+            before = set(pc._entries)
+            if order and pc.evict(1):
+                (gone,) = before - set(pc._entries)
+                assert gone == order[0][1], step
+    assert pc.stats()["evictions"] > 20
+    assert len(pc._heap) <= 4 * len(pc._entries) + 64 + 300

@@ -143,8 +143,8 @@ def test_tiered_allocator_spill_begin_bounded():
 def test_host_store_round_trip_and_padding():
     import jax
 
-    template = (jax.ShapeDtypeStruct((2, 5, 3), np.float32),
-                jax.ShapeDtypeStruct((2, 5, 3), np.float32))
+    template = (jax.ShapeDtypeStruct((5, 2, 3), np.float32),   # [P, pt, row]
+                jax.ShapeDtypeStruct((5, 2, 3), np.float32))
     store = HostPageStore(template, capacity=3)
     assert store.nbytes() == 2 * (3 * 2 * 3 * 4)
 
@@ -154,10 +154,10 @@ def test_host_store_round_trip_and_padding():
     store.put(2, chunk, 1)
     rows = store.assemble([2, 0], rung=4)
     for leaf, src in zip(rows, chunk):
-        assert leaf.shape == (2, 4, 3)
-        np.testing.assert_array_equal(leaf[:, 0], src[:, 1])
-        np.testing.assert_array_equal(leaf[:, 1], src[:, 0])
-        assert not leaf[:, 2:].any()     # rung padding stays zero
+        assert leaf.shape == (4, 2, 3)
+        np.testing.assert_array_equal(leaf[0], src[1])
+        np.testing.assert_array_equal(leaf[1], src[0])
+        assert not leaf[2:].any()        # rung padding stays zero
 
 
 # -- MigrationEngine: async spill -> refetch round trip -------------------
@@ -167,7 +167,7 @@ def test_migration_engine_round_trip_content_exact():
     import jax.numpy as jnp
 
     alloc = TieredPageAllocator(4, host_pages=4)
-    store = HostPageStore((jax.ShapeDtypeStruct((2, 4, 3), np.float32),),
+    store = HostPageStore((jax.ShapeDtypeStruct((4, 2, 3), np.float32),),
                           capacity=4)
     eng = MigrationEngine(store, window=2)
     try:
@@ -189,8 +189,8 @@ def test_migration_engine_round_trip_content_exact():
         assert t2.wait(timeout=30) == "ok"
         (rows,) = t2.rows
         got = np.asarray(rows)
-        np.testing.assert_array_equal(got[:, :2], np.asarray(src))
-        assert not got[:, 2:].any()
+        np.testing.assert_array_equal(got[:2], np.asarray(src))
+        assert not got[2:].any()
 
         st = eng.stats()
         assert st["window"] == 2 and st["inflight"] == 0
@@ -207,7 +207,7 @@ def test_migration_engine_chaos_fails_batch_only():
     import jax.numpy as jnp
 
     alloc = TieredPageAllocator(4, host_pages=4)
-    store = HostPageStore((jax.ShapeDtypeStruct((1, 4, 2), np.float32),),
+    store = HostPageStore((jax.ShapeDtypeStruct((4, 1, 2), np.float32),),
                           capacity=4)
     eng = MigrationEngine(store, window=2)
     try:

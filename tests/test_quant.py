@@ -11,6 +11,7 @@ import json
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
@@ -19,9 +20,9 @@ import paddle_tpu.nn.functional as F
 import paddle_tpu.optimizer as opt
 from paddle_tpu import framework, profiler
 from paddle_tpu.inference.decode import (DecodeEngine, SpecDecodeEngine,
-                                         _copy_kv_page, kv_page_bytes,
-                                         load_for_decode, save_for_decode)
-from paddle_tpu.memory.page_allocator import write_pages
+                                         kv_page_bytes, load_for_decode,
+                                         save_for_decode)
+from paddle_tpu.memory.page_allocator import copy_page, write_pages
 from paddle_tpu.models.gpt import GPT, GPTConfig, gpt_tiny
 from paddle_tpu.quant import (Int8Linear, PTQ, QAT, QATLinear, SCALE_SUFFIX,
                               dequantize_kv, dequantize_params,
@@ -236,34 +237,43 @@ def test_int8_pool_write_and_copy_pytree():
     test_decode_paged compares the two bit for bit) and the engine's
     COW entry point moves both leaves together, leaving untouched pages
     zero in both."""
-    shape = (2, 4, 3, 2, 8)                    # [L, P, pt, H, D]
+    shape = (2, 4, 3, 2, 8)                    # L, P, pt, H, D
     kp = kv_pool_zeros(shape, "int8")
     vp = kv_pool_zeros(shape, "int8")
-    assert isinstance(kp, tuple) and kp[0].dtype == jnp.int8
-    assert kp[1].shape == shape[:-1] and kp[1].dtype == jnp.float32
+    # one (data, scale) pair a layer; a row is the heads side by side
+    assert isinstance(kp, tuple) and len(kp) == 2
+    assert kp[0][0].shape == (4, 3, 16) and kp[0][0].dtype == jnp.int8
+    assert kp[0][1].shape == (4, 3, 2) and kp[0][1].dtype == jnp.float32
     rng2 = np.random.default_rng(2)
     k_rows = jnp.asarray(
         rng2.normal(size=(2, 2, 3, 2, 8)).astype(np.float32))
     v_rows = jnp.asarray(
         rng2.normal(size=(2, 2, 3, 2, 8)).astype(np.float32))
     ids = jnp.asarray([2, 1], jnp.int32)
-    kp = write_pages(kp, quantize_kv(k_rows), ids)
-    vp = write_pages(vp, quantize_kv(v_rows), ids)
-    got = dequantize_kv(kp[0][:, 2], kp[1][:, 2])
-    err = np.abs(np.asarray(got) - np.asarray(k_rows[:, 0]))
-    assert (err <= np.asarray(kp[1][:, 2])[..., None] * 0.5 + 1e-7).all()
-    assert int(jnp.abs(kp[0][:, 3].astype(jnp.int32)).sum()) == 0
-    kp, vp = _copy_kv_page((kp, vp), jnp.int32(2), jnp.int32(3))
-    np.testing.assert_array_equal(np.asarray(kp[0][:, 3]),
-                                  np.asarray(kp[0][:, 2]))
-    np.testing.assert_array_equal(np.asarray(kp[1][:, 3]),
-                                  np.asarray(kp[1][:, 2]))
+
+    def pages(rows):                 # [L, W, pt, H, D] -> a pair a layer
+        q, s = quantize_kv(rows)
+        return tuple((q[i].reshape(2, 3, 16), s[i]) for i in range(2))
+
+    kp = write_pages(kp, pages(k_rows), ids)
+    vp = write_pages(vp, pages(v_rows), ids)
+    for li in range(2):
+        data, scale = kp[li]
+        got = dequantize_kv(data[2].reshape(3, 2, 8), scale[2])
+        err = np.abs(np.asarray(got) - np.asarray(k_rows[li, 0]))
+        assert (err <= np.asarray(scale[2])[..., None] * 0.5 + 1e-7).all()
+        assert int(jnp.abs(data[3].astype(jnp.int32)).sum()) == 0
+    kp, vp = copy_page((kp, vp), jnp.int32(2), jnp.int32(3))
+    for leaf in jax.tree.leaves((kp, vp)):
+        np.testing.assert_array_equal(np.asarray(leaf[3]),
+                                      np.asarray(leaf[2]))
     # the SDS mirror (AOT warmup signatures) matches shape AND dtype
-    sds = kv_pool_sds(shape, "int8")
-    assert sds[0].shape == shape and sds[0].dtype == jnp.int8
-    assert sds[1].shape == shape[:-1] and sds[1].dtype == jnp.float32
+    assert jax.tree.map(lambda x: (x.shape, x.dtype),
+                        kv_pool_sds(shape, "int8")) \
+        == jax.tree.map(lambda x: (x.shape, x.dtype), kp)
     fsds = kv_pool_sds(shape, "float32")
-    assert fsds.shape == shape and fsds.dtype == jnp.float32
+    assert len(fsds) == 2 and fsds[1].shape == (4, 3, 16) \
+        and fsds[1].dtype == jnp.float32
 
 
 def test_quant_kernels_match_reference():
@@ -284,6 +294,8 @@ def test_quant_kernels_match_reference():
     lengths = jnp.asarray([5, 16, 11], jnp.int32)
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
+    # as they lie in a layer's pool: a row is the heads side by side
+    k, v, kq, vq = (x.reshape(P, pt, H * D) for x in (k, v, kq, vq))
     truth = paged_decode_attention_reference(q, k, v, tables, lengths)
     ref = paged_decode_attention_quant_reference(
         q, kq, ks, vq, vs, tables, lengths)

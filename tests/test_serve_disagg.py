@@ -79,8 +79,8 @@ def test_serialize_roundtrip_and_checksum():
     rides int8 leaves as uint8 views (the wire dtype table has no
     int8)."""
     rng = np.random.RandomState(0)
-    chunk = (rng.randn(2, 3, 4).astype(np.float32),
-             rng.randint(-128, 127, size=(2, 3, 4), dtype=np.int8))
+    chunk = (rng.randn(3, 2, 4).astype(np.float32),     # page axis 0
+             rng.randint(-128, 127, size=(3, 2, 4), dtype=np.int8))
     arrays, meta = serialize_pages(chunk, 3)
     assert meta["n_pages"] == 3 and len(meta["crcs"]) == 3
     assert arrays[1].dtype == np.uint8          # int8 rides as a view
