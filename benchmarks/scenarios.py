@@ -25,8 +25,6 @@ stream) open-loop on the arrival clock and records one
 :class:`Outcome` per request; :func:`score` folds outcomes into
 per-tenant p50/p99 latency and goodput. Everything here is numpy +
 stdlib so tests can import the generators without touching jax.
-
-    python benchmarks/serve_bench.py --scenario adversarial_flood
 """
 from __future__ import annotations
 

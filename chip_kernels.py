@@ -3,9 +3,11 @@
     python chip_kernels.py          # on the chip: compile + run + compare
     python chip_kernels.py --aot    # in the sandbox: chipless v5e compile only
 
-Every kernel of ops/pallas/ at the widths the main paths use (GPT-2 124M:
-H=12 D=64, T=1024/4096; GPT-3 1.3B geometry: H=16 D=128, hidden 2048),
-against its XLA composition computed at `highest` matmul precision. One
+The training path's kernels of ops/pallas/ (flash attention forward and
+both backward schemes, fused linear + cross-entropy) at the widths the
+main paths use (GPT-2 124M: H=12 D=64, T=1024/4096; GPT-3 1.3B geometry:
+H=16 D=128, hidden 2048), against their XLA compositions computed at
+`highest` matmul precision. One
 JSON line per case ({kernel, compiles, matches, rel_err, msg} — for a
 kernel that does not compile, `msg` is the compiler's message), a SUMMARY
 line, and chiprun_out/kernels.json when that directory exists. Exits
@@ -29,9 +31,7 @@ import numpy as np
 
 import paddle_tpu  # noqa: F401
 from paddle_tpu.nn.functional.attention import _sdpa_xla
-from paddle_tpu.ops.pallas import (_common, decode_attention as da,
-                                   flash_attention as fa, fused_ce,
-                                   quant_matmul as qm)
+from paddle_tpu.ops.pallas import _common, flash_attention as fa, fused_ce
 
 if AOT:
     from jax.experimental import topologies
@@ -140,50 +140,6 @@ def ce_case(name, N, H, V):
 ce_case("fused-CE fwd+bwd N=8192 H=768 V=50304", 8192, 768, 50304)
 ce_case("fused-CE fwd+bwd N=4096 H=2048 V=50304", 4096, 2048, 50304)
 
-
-# ---- decode attention (opt-in: PADDLE_TPU_DECODE_KERNEL=pallas) ------------
-def decode_cases(H, D, tag):
-    B, cap, pt = 8, 1024, 16
-    W = cap // pt
-    P = B * W + 1
-    q = arr((B, H, D), jnp.float32, 0.5)
-    lengths = jnp.asarray([1, 17, 100, 512, 1000, 1024, 33, 16], jnp.int32)
-    k, v = (arr((B, cap, H, D), jnp.float32, 0.5) for _ in range(2))
-    case(f"decode contiguous {tag}", da._decode_attention_pallas,
-         da.decode_attention_reference, (q, k, v, lengths), tol=2e-2)
-    # a layer's pool: a token's row is its heads side by side
-    kp, vp = (arr((P, pt, H * D), jnp.float32, 0.5) for _ in range(2))
-    tables = jnp.asarray(rng.permutation(P - 1)[:B * W].reshape(B, W) + 1,
-                         jnp.int32)
-    case(f"decode paged {tag}", da._paged_decode_attention_pallas,
-         da.paged_decode_attention_reference,
-         (q, kp, vp, tables, lengths), tol=2e-2)
-    k8, v8 = (arr((P, pt, H * D), jnp.int8, ints=(-127, 128))
-              for _ in range(2))
-    ks, vs = (jnp.abs(arr((P, pt, H), jnp.float32, 0.01)) + 1e-3
-              for _ in range(2))
-    case(f"decode paged int8 {tag}",
-         da._paged_decode_attention_quant_pallas,
-         da.paged_decode_attention_quant_reference,
-         (q, k8, ks, v8, vs, tables, lengths), tol=2e-2)
-
-
-decode_cases(12, 64, "H=12 D=64")
-decode_cases(16, 128, "H=16 D=128")
-
-
-# ---- int8 weight matmul (opt-in) ------------------------------------------
-def mm_case(M, K, N):
-    x = arr((M, K), jnp.float32, 0.5)
-    wq = arr((K, N), jnp.int8, ints=(-127, 128))
-    sc = jnp.abs(arr((N,), jnp.float32, 0.01)) + 1e-3
-    case(f"int8 matmul {M}x{K}x{N}", qm._int8_weight_matmul_pallas,
-         qm.int8_weight_matmul_reference, (x, wq, sc), tol=2e-2)
-
-
-for M, K, N in [(8, 768, 3072), (8, 2048, 8192), (8, 8192, 2048),
-                (1024, 2048, 8192)]:
-    mm_case(M, K, N)
 
 if os.path.isdir("chiprun_out") and not AOT:
     with open("chiprun_out/kernels.json", "w") as f:

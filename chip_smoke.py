@@ -7,7 +7,7 @@ GPT-2 124M width (``GPTConfig()``: vocab 50,304, hidden 768, 12 layers,
 12 heads, T=1024), random weights from a seed:
 
 1. trainer  — ``Model.prepare(adam, strategy)`` + ``Model.fit`` (what
-   bench.py times), a few steps on a repeated batch;
+   chipbench's train cell times), a few steps on a repeated batch;
 2. artifact — ``decode.save_for_decode`` plus a float32 full-forward
    greedy oracle for a handful of fixed prompts;
 3. server   — ``python -m paddle_tpu.inference.serve <prefix> --decode
@@ -103,8 +103,8 @@ def _flash_calls(hlo_text):
 
 
 def fit_gpt2_124m(B, steps, devices=None, dp=1, tp=1):
-    """What bench.py times, minus the timing: GPT-2 124M (``GPTConfig()``)
-    through ``Model.prepare(adam, strategy)`` (AMP O2, Adam) and
+    """What chipbench's train cell times, minus the timing: GPT-2 124M
+    (``GPTConfig()``) through ``Model.prepare(adam, strategy)`` (AMP O2, Adam) and
     ``Model.fit`` for `steps` steps at T tokens on ONE batch of B
     sequences repeated — so the loss must come down. `devices`/`dp`/`tp`
     pick the mesh (default: every device, data parallel). Returns

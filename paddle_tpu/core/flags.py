@@ -261,12 +261,6 @@ define_env_flag(
     "a cache over a much larger KV store. 0 disables tiering; enabling "
     "it implies the prefix cache (spills route through the trie).")
 define_env_flag(
-    "PADDLE_TPU_DECODE_KERNEL", "xla",
-    "Decode attention kernel: 'xla' (reference einsum) or 'pallas' (fused "
-    "GEMV kernel, ops/pallas/decode_attention.py). Also selects the "
-    "int8 variants (fused dequant paged attention and the dequant "
-    "weight matmul, ops/pallas/quant_matmul.py).")
-define_env_flag(
     "PADDLE_TPU_DECODE_KV_DTYPE", "float32",
     "Decode KV page-pool dtype: 'float32', or 'int8' for quantized "
     "pages (quant/kv.py) — int8 payload plus one fp32 scale per "

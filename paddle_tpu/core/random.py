@@ -31,9 +31,11 @@ class Generator:
 
     def _ensure_key(self):
         if self._key is None:
-            self._key = jax.random.key(self._seed)
-            for _ in range(getattr(self, "_replay", 0)):
-                self._key, _ = jax.random.split(self._key)
+            # concrete even when first asked under someone's trace
+            with jax.ensure_compile_time_eval():
+                self._key = jax.random.key(self._seed)
+                for _ in range(getattr(self, "_replay", 0)):
+                    self._key, _ = jax.random.split(self._key)
             self._replay = 0
 
     @property
@@ -41,10 +43,19 @@ class Generator:
         return self._seed
 
     def next_key(self):
-        """Return a fresh key; advances internal state (eager use only)."""
+        """Return a fresh key; advances internal state (eager use only).
+        Under someone's trace with no `key_scope` (a layer constructed
+        inside `jax.eval_shape`, say) the split is evaluated at trace
+        time, so the chain stays concrete: a traced key kept here would
+        outlive its trace and fail the next eager draw with an
+        escaped-tracer error."""
         with self._lock:
             self._ensure_key()
-            self._key, sub = jax.random.split(self._key)
+            if jax.core.trace_ctx.is_top_level():
+                self._key, sub = jax.random.split(self._key)
+            else:
+                with jax.ensure_compile_time_eval():
+                    self._key, sub = jax.random.split(self._key)
             self._offset += 1
             return sub
 

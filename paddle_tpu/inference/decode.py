@@ -22,8 +22,8 @@ KV cache instead of per-slot contiguous panels:
     request's pool pages, so nothing of the cache crosses to the host
     — and its paged step, which advances EVERY active request one
     token, writing through the block table and attending over the
-    pages (for a GPT `models.gpt.gpt_paged_prefill_fns` /
-    `gpt_paged_decode_fns`);
+    pages (for a GPT both come from the one builder,
+    `models.gpt.gpt_paged_fns`);
   * all device entry points run through an `AotCache` — the fused
     prefill per prompt rung, the step per (batch-rung x page-rung)
     bucket, plus one traced-scalar copy-on-write executable — so after
@@ -83,7 +83,7 @@ gapless.
 `SpecDecodeEngine` layers draft-and-verify speculative decoding on the
 same machinery: a small draft GPT runs k greedy steps per tick over its
 own page pool (same allocator, same block tables), the target scores
-all k+1 positions in one `gpt_paged_verify_fns` forward, and a
+all k+1 positions in one verify forward (the kind's `verify_fn`), and a
 rejection rolls back by truncating `cache_len` and releasing the
 stranded block-table tail (`PageAllocator.release_range`). Enabled via
 PADDLE_TPU_DECODE_SPECULATE / PADDLE_TPU_DECODE_DRAFT_MODEL or serve's
@@ -113,14 +113,12 @@ from ..memory.migration import (HostPageStore, MigrationEngine,
                                 TieredPageAllocator, deserialize_pages,
                                 serialize_pages, tier_metrics)
 from ..memory.page_allocator import (PageAllocator, PageExhausted,
-                                     copy_page, gather_pages, write_pages)
-from ..models.gpt import (GPTConfig, gpt_paged_rollout_fns,
-                          gpt_paged_verify_fns)
+                                     gather_pages, write_pages)
+from ..models.gpt import GPTConfig
 from ..observability import counter, gauge, histogram
 from ..observability import memz as _memz
 from ..observability.spans import SpanRecorder, next_request_id
 from ..observability.tracez import RING as _RING
-from ..quant.kv import kv_pool_sds, kv_pool_zeros
 from ..quant.ptq import is_quantized as _params_quantized
 from ..quant.ptq import quantize_params
 from ..testing import chaos
@@ -132,7 +130,7 @@ from .errors import (ERR_FAILED_PRECONDITION, ERR_INVALID_ARGUMENT,
                      ERR_RESOURCE_EXHAUSTED, ERR_UNAVAILABLE,
                      TypedServeError)
 from .model_kinds import (GPTKind,  # noqa: F401
-                          kv_fingerprint, kv_page_bytes, kv_slot_bytes)
+                          kv_fingerprint, kv_page_bytes)
 
 DEFAULT_MAX_SLOTS = 8          # CPU fallback when HBM stats are absent
 DEFAULT_MAX_NEW_TOKENS = 64
@@ -2491,7 +2489,7 @@ class SpecDecodeEngine(DecodeEngine):
     scheduler tick over its OWN page pool — same shape discipline, same
     `PageAllocator`, same per-slot block tables, so one page id names
     one target page AND one draft page. The target then scores all
-    drafted positions in a single `gpt_paged_verify_fns` forward (which
+    drafted positions in a single verify forward (which
     also writes their target K/V rows); acceptance is
     sample-then-compare — the committed token at each position is the
     target's own (argmax, or the per-(seed, position) sampler over the
@@ -2563,28 +2561,26 @@ class SpecDecodeEngine(DecodeEngine):
         self._draft_params = {n: jnp.asarray(v)
                               for n, v in draft_params.items()}
         self.k_ladder = spec_k_ladder(k)
-        dprefill = GPTKind(draft_cfg, self.draft_eps).prefill_fn(
-            self.page_tokens, name="paged_prefill")
-        rollout = gpt_paged_rollout_fns(
-            draft_cfg, eps=self.draft_eps, page_tokens=self.page_tokens)
-        verify = gpt_paged_verify_fns(
-            self.cfg, eps=self.eps, page_tokens=self.page_tokens)
+        draft = self._draft_kind = model_kinds.for_config(
+            draft_cfg, self.draft_eps)
         # Draft/target pools donated for the same in-place-update
         # reason as the base engine's executables.
         self._dprefill_aot = AotCache(
-            jax.jit(dprefill, donate_argnums=(1,)), "decode.dprefill",
+            jax.jit(draft.prefill_fn(self.page_tokens, name="paged_prefill"),
+                    donate_argnums=(1,)), "decode.dprefill",
             donate_argnums=(1,))
         self._droll_aot = AotCache(
-            jax.jit(rollout, donate_argnums=(1, 2)), "decode.droll",
-            donate_argnums=(1, 2))
+            jax.jit(draft.rollout_fn(self.page_tokens),
+                    donate_argnums=(1,)), "decode.droll",
+            donate_argnums=(1,))
         self._dcopy_aot = AotCache(
-            jax.jit(copy_page, donate_argnums=(0,)), "decode.dcow",
+            jax.jit(draft.copy_page, donate_argnums=(0,)), "decode.dcow",
             donate_argnums=(0,))
         self._verify_aot = AotCache(
-            jax.jit(verify, donate_argnums=(1, 2)), "decode.verify",
-            donate_argnums=(1, 2))
-        self._dkpool = None          # draft pools, lazy like the target's
-        self._dvpool = None
+            jax.jit(self._kind.verify_fn(self.page_tokens),
+                    donate_argnums=(1,)), "decode.verify",
+            donate_argnums=(1,))
+        self._dpool_tree = None      # draft pools, lazy like the target's
         self._drafted_total = 0
         self._accepted_total = 0
 
@@ -2599,59 +2595,31 @@ class SpecDecodeEngine(DecodeEngine):
             return super()._owner_for(req)
         return ("draft", req.id)
 
-    # The target's pools are a GPT's (k_pool, v_pool): this engine
-    # names them, behind its typed refusal of every other kind.
-
-    @property
-    def _kpool(self):
-        return None if self._pool_tree is None else self._pool_tree[0]
-
-    @_kpool.setter
-    def _kpool(self, pool):
-        self._pool_tree = (pool, self._vpool)
-
-    @property
-    def _vpool(self):
-        return None if self._pool_tree is None else self._pool_tree[1]
-
-    @_vpool.setter
-    def _vpool(self, pool):
-        self._pool_tree = (self._kpool, pool)
-
-    def _pool_sds(self):
-        return self._model_pools_sds()[0]
-
-    def _dpool_shape(self):
-        c = self.draft_cfg
-        return (c.layers, self.num_pages, self.page_tokens, c.heads,
-                c.head_dim)
-
-    def _dpool_sds(self):
-        return kv_pool_sds(self._dpool_shape(), self.kv_dtype)
+    def _dpools_sds(self):
+        return self._draft_kind.pools_sds(self.num_pages, self.page_tokens,
+                                          self.kv_dtype)
 
     # Host tiering migrates the draft pools with the target pools: one
-    # page id names a page in all four, so a spilled page's full
+    # page id names a page in both, so a spilled page's full
     # footprint moves as one chunk and a restore brings the draft rows
     # back warm. (Even when restored draft rows are stale, acceptance
     # is sample-then-compare — draft content can only cost acceptance
     # rate, never change emitted tokens.)
 
     def _pools(self):
-        return (self._kpool, self._vpool, self._dkpool, self._dvpool)
+        return (self._pool_tree, self._dpool_tree)
 
     def _set_pools(self, pools):
-        self._pool_tree = tuple(pools[:2])
-        self._dkpool, self._dvpool = pools[2:]
+        self._pool_tree, self._dpool_tree = pools
 
     def _pools_sds(self):
-        p, d = self._pool_sds(), self._dpool_sds()
-        return (p, p, d, d)
+        return (self._model_pools_sds(), self._dpools_sds())
 
     def _ensure_pool(self):
         super()._ensure_pool()
-        if self._dkpool is None:
-            self._dkpool = kv_pool_zeros(self._dpool_shape(), self.kv_dtype)
-            self._dvpool = kv_pool_zeros(self._dpool_shape(), self.kv_dtype)
+        if self._dpool_tree is None:
+            self._dpool_tree = self._draft_kind.pools_zeros(
+                self.num_pages, self.page_tokens, self.kv_dtype)
 
     def _cow(self, req: _Req, slot: int):
         """Copy-on-write for speculation copies the page in BOTH pools —
@@ -2659,14 +2627,13 @@ class SpecDecodeEngine(DecodeEngine):
         old = req.pages[slot]
         super()._cow(req, slot)     # the target's copy; repoints the slot
         i32 = jnp.int32
-        dpools = (self._dkpool, self._dvpool)
         dexe = self._dcopy_aot.get_or_compile(
-            dpools,
+            self._dpool_tree,
             jax.ShapeDtypeStruct((), i32), jax.ShapeDtypeStruct((), i32),
             key=("dcow",))
         # the other owners still hold `old`, so its draft rows stand
-        self._dkpool, self._dvpool = dexe(
-            dpools, jnp.asarray(old, i32),
+        self._dpool_tree = dexe(
+            self._dpool_tree, jnp.asarray(old, i32),
             jnp.asarray(req.pages[slot], i32))
 
     # ---------------------------------------------------------- warmup
@@ -2680,12 +2647,12 @@ class SpecDecodeEngine(DecodeEngine):
         before = len(profiler.compile_events())
         super().warmup(verbose=False)
         i32 = jnp.int32
-        pool, dpool = self._pool_sds(), self._dpool_sds()
+        pools, dpools = self._model_pools_sds(), self._dpools_sds()
         for r in self.kv_ladder:
             self._prefill_exe(self._dprefill_aot, self._draft_params,
-                              (dpool, dpool), r)
+                              dpools, r)
         self._dcopy_aot.get_or_compile(
-            (dpool, dpool),
+            dpools,
             jax.ShapeDtypeStruct((), i32), jax.ShapeDtypeStruct((), i32),
             key=("dcow",))
         # When the full (batch x page x k) cross product overflows the
@@ -2703,7 +2670,7 @@ class SpecDecodeEngine(DecodeEngine):
             sigs = sigs[:_WARMUP_SIG_CAP]
         for b, w, kk in sigs:
             self._droll_aot.get_or_compile(
-                self._draft_params, dpool, dpool,
+                self._draft_params, dpools,
                 jax.ShapeDtypeStruct((b, w), i32),
                 jax.ShapeDtypeStruct((b, kk), i32),
                 jax.ShapeDtypeStruct((b,), i32),
@@ -2714,7 +2681,7 @@ class SpecDecodeEngine(DecodeEngine):
             vsigs = vsigs[:_WARMUP_SIG_CAP]
         for b, w, k1 in vsigs:
             self._verify_aot.get_or_compile(
-                self.params, pool, pool,
+                self.params, pools,
                 jax.ShapeDtypeStruct((b, w), i32),
                 jax.ShapeDtypeStruct((b, k1), i32),
                 jax.ShapeDtypeStruct((b,), i32),
@@ -2750,9 +2717,9 @@ class SpecDecodeEngine(DecodeEngine):
         the rows hold committed K/V — the one thing every mapper of a
         shared prefix page agrees on."""
         seq = (req.prompt + req.generated)[:req.cache_len]
-        _, (self._dkpool, self._dvpool) = self._prefill_into_pages(
-            self._dprefill_aot, self._draft_params,
-            (self._dkpool, self._dvpool), seq, req.pages)
+        _, self._dpool_tree = self._prefill_into_pages(
+            self._dprefill_aot, self._draft_params, self._dpool_tree, seq,
+            req.pages)
 
     def _preempt_stash(self, req: _Req):
         """Stash only PROMPT-region pages at preemption. Generated-region
@@ -2833,13 +2800,13 @@ class SpecDecodeEngine(DecodeEngine):
             for i in range(tick_k):
                 forced[j, i] = seq[dl + i] if dl + i < len(seq) else -1
         dexe = self._droll_aot.get_or_compile(
-            self._draft_params, self._dkpool, self._dvpool,
+            self._draft_params, self._dpool_tree,
             jax.ShapeDtypeStruct((b_rung, w_rung), jnp.int32),
             jax.ShapeDtypeStruct((b_rung, tick_k), jnp.int32),
             jax.ShapeDtypeStruct((b_rung,), jnp.int32),
             key=("droll", b_rung, w_rung, tick_k))
-        dout, self._dkpool, self._dvpool = dexe(
-            self._draft_params, self._dkpool, self._dvpool,
+        dout, self._dpool_tree = dexe(
+            self._draft_params, self._dpool_tree,
             tables_j, jnp.asarray(forced), jnp.asarray(dlen))
         dnp = np.asarray(dout)
         self._m["spec_draft_steps"].inc(tick_k)
@@ -2867,14 +2834,14 @@ class SpecDecodeEngine(DecodeEngine):
             clen[j] = req.cache_len
             meta.append((n_known, nd))
         vexe = self._verify_aot.get_or_compile(
-            self.params, self._kpool, self._vpool,
+            self.params, self._pool_tree,
             jax.ShapeDtypeStruct((b_rung, w_rung), jnp.int32),
             jax.ShapeDtypeStruct((b_rung, K1), jnp.int32),
             jax.ShapeDtypeStruct((b_rung,), jnp.int32),
             key=("verify", b_rung, w_rung, K1))
         t0 = time.perf_counter()
-        logits, amax, self._kpool, self._vpool = vexe(
-            self.params, self._kpool, self._vpool,
+        logits, amax, self._pool_tree = vexe(
+            self.params, self._pool_tree,
             tables_j, jnp.asarray(vtoks), jnp.asarray(clen))
         amaxnp = np.asarray(amax)
         lognp = None   # full logits only cross to host when sampling

@@ -15,12 +15,16 @@ dispatch, donated, and never looks inside.
         -> (logits [1, V] float32, pools)
 
 The logits stay on the device unless a row samples: a tick of greedy
-rows pulls the engine's `greedy_picks` of them, [B] ids.
+rows pulls the engine's `greedy_picks` of them, [B] ids. A kind that
+has speculative decoding also gives `verify_fn` and `rollout_fn`, which
+`SpecDecodeEngine` asks of its target and of its draft.
 
 Kinds: `gpt` (`GPTKind`: a K and a V pool, each one array a layer `[P,
-page_tokens, heads * head_dim]`, float32 or int8) and `axk1`
-(`AXK1Kind`: one latent pool a layer, bfloat16, plus the
-routed-assignment counters). Both keep the page axis at 0 on every
+page_tokens, heads * head_dim]`, float32 or int8; its programs are the
+four of the one builder `models.gpt.gpt_paged_fns`, returned as they
+are) and `axk1` (`AXK1Kind`: one latent pool a layer, bfloat16, plus the
+routed-assignment counters; `models.axk1.axk1_paged_fns`). Both keep
+the page axis at 0 on every
 page-holding leaf and a token's row whole and lane-dense, so the
 compiled step writes rows into the arrays it was given and
 `memory.page_allocator`'s page ops serve either. The manifest of a
@@ -44,8 +48,7 @@ from ..core import flags as _flags
 from ..memory.page_allocator import copy_page
 from ..models.axk1 import (AXK1, AXK1Config, axk1_paged_fns,
                            latent_pools_sds)
-from ..models.gpt import (GPTConfig, gpt_paged_decode_fns,
-                          gpt_paged_prefill_fns)
+from ..models.gpt import GPTConfig, gpt_paged_fns
 from ..quant.kv import kv_pool_sds, kv_pool_zeros, validate_kv_dtype
 from .errors import ERR_FAILED_PRECONDITION, TypedServeError
 
@@ -69,14 +72,6 @@ def unsupported(kind: str, feature: str, roadmap: str):
 
 
 # ------------------------------------------------------------------ gpt
-
-
-def kv_slot_bytes(cfg: GPTConfig, capacity: Optional[int] = None) -> int:
-    """HBM bytes one sequence's full K+V panel occupies at `capacity`
-    (the contiguous-pool cost model; the paged analog is
-    `kv_page_bytes` x pages actually mapped)."""
-    cap = capacity or cfg.max_seq_len
-    return cfg.layers * 2 * cap * cfg.heads * cfg.head_dim * 4
 
 
 def kv_page_bytes(cfg: GPTConfig, page_tokens: int,
@@ -152,27 +147,25 @@ class GPTKind:
             kv_dtype if kv_dtype is not None
             else _flags.env_value("PADDLE_TPU_DECODE_KV_DTYPE"))
 
-    def step_fn(self, page_tokens):
-        _, step = gpt_paged_decode_fns(self.cfg, eps=self.eps,
-                                       page_tokens=page_tokens)
-
-        def paged_step(params, pools, tables, last_tok, cache_len):
-            logits, k_pool, v_pool = step(params, *pools, tables, last_tok,
-                                          cache_len)
-            return logits, (k_pool, v_pool)
-
-        return paged_step
+    def _fns(self, page_tokens, prefill_name="prefill"):
+        return gpt_paged_fns(self.cfg, self.eps, page_tokens, prefill_name)
 
     def prefill_fn(self, page_tokens, name="prefill"):
-        inner = gpt_paged_prefill_fns(self.cfg, eps=self.eps,
-                                      page_tokens=page_tokens, name=name)
+        return self._fns(page_tokens, name)[0]
 
-        def prefill(params, pools, toks, tables, n):
-            logits, k_pool, v_pool = inner(params, *pools, toks, tables, n)
-            return logits, (k_pool, v_pool)
+    def step_fn(self, page_tokens):
+        return self._fns(page_tokens)[1]
 
-        prefill.__name__ = prefill.__qualname__ = name
-        return prefill
+    def verify_fn(self, page_tokens):
+        """The target side of speculative decoding (`SpecDecodeEngine`):
+        verify(params, pools, tables [B, W], toks [B, K1], cache_len [B])
+        -> (logits [B, K1, V], argmax [B, K1], pools)."""
+        return self._fns(page_tokens)[2]
+
+    def rollout_fn(self, page_tokens):
+        """The draft side: rollout(params, pools, tables [B, W],
+        forced [B, K], cache_len [B]) -> (drafts [B, K], pools)."""
+        return self._fns(page_tokens)[3]
 
     def _pool_shape(self, num_pages, page_tokens):
         c = self.cfg
@@ -195,7 +188,7 @@ class GPTKind:
         return kv_page_bytes(self.cfg, page_tokens, kv_dtype)
 
     def slot_bytes(self):
-        return kv_slot_bytes(self.cfg)
+        return self.page_bytes(self.max_seq_len, "float32")
 
     def fingerprint(self, params):
         return kv_fingerprint(self.cfg, self.eps, params)

@@ -976,8 +976,8 @@ def _qmm(bp, name, x):
     `quant.ptq.quantize_params` stores an int8 weight under its original
     key with an fp32 per-output-channel scale sibling at `name::scale`.
     When the sibling is absent this is literally `x @ w` — the fp32 path
-    traces identically to unquantized code — otherwise the matmul routes
-    through the fused dequant kernel (`ops.pallas.quant_matmul`)."""
+    traces identically to unquantized code — otherwise the scale factors
+    out of the product (`ops.pallas.quant_matmul`)."""
     s = bp.get(name + "::scale")
     if s is None:
         return x @ bp[name]
@@ -993,7 +993,7 @@ def _qmm(bp, name, x):
 # heads * head_dim], scale f32 [P, page_tokens, heads])` with one scale
 # per (page, row, head). The page axis is 0 on every leaf. The helpers
 # below branch on a layer pool's structure at trace time, so every
-# paged decode fn serves both pool dtypes from one code path.
+# paged program serves both pool dtypes from one code path.
 
 def _kv_pool_write(pool, page_idx, offset, rows):
     """Scatter fresh fp32 K/V rows [..., heads, head_dim] into one
@@ -1009,258 +1009,259 @@ def _kv_pool_write(pool, page_idx, offset, rows):
     return pool.at[page_idx, offset].set(rows.reshape(flat))
 
 
-def _paged_attend(q, k_layer, v_layer, tables, lengths):
-    """Paged decode attention over one layer's pool, fused-dequant
-    variant when the pool is int8."""
-    if isinstance(k_layer, tuple):
-        from ..ops.pallas.decode_attention import paged_decode_attention_quant
-        return paged_decode_attention_quant(
-            q, k_layer[0], k_layer[1], v_layer[0], v_layer[1],
-            tables, lengths)
-    from ..ops.pallas.decode_attention import paged_decode_attention
-    return paged_decode_attention(q, k_layer, v_layer, tables, lengths)
+def _page_address(tables, pos, pt, valid=None):
+    """Where position `pos` ([B], or [B, K] for K positions a row) lies
+    through the block tables [B, W]: (page id, row in the page). A slot
+    past the table reads the table's last entry, and table padding is
+    null pages; where `valid` is given and false (a position at or past
+    max_seq_len) the address is the null page's, so the write of an
+    overrun never lands on live rows."""
+    slot = jnp.minimum(pos // pt, tables.shape[1] - 1)
+    if pos.ndim == 1:
+        page_idx = jnp.take_along_axis(tables, slot[:, None], axis=1)[:, 0]
+    else:
+        page_idx = jnp.take_along_axis(tables, slot, axis=1)
+    if valid is not None:
+        page_idx = jnp.where(valid, page_idx, 0)
+    return page_idx, pos % pt
 
 
-def gpt_decode_fns(cfg: GPTConfig, eps: float = 1e-5):
-    """Pure `(prefill, decode_step)` over the functional param dict.
+def _embed(embed, tok, pos):
+    return embed["wte.weight"][tok] + embed["wpe.weight"][pos]
 
-    prefill(params, tokens [B,T] i32, lens [B] i32)
-        -> (logits [B,V] at each row's position lens-1,
-            k, v    [layers, B, T, heads, head_dim])
-    decode_step(params, k, v, last_tok [B] i32, cache_len [B] i32)
-        -> (logits [B,V], k, v) — writes the new token's K/V at row
-           index cache_len via lax.dynamic_update_slice, attends the
-           masked prefix 0..cache_len, so one executable serves every
-           occupancy of a (batch-rung x kv-capacity-rung) bucket.
+
+def _ffn(bp, x, eps):
+    h2 = _pp_ln(x, bp["ln2.weight"], bp["ln2.bias"], eps)
+    m = jax.nn.gelu(_qmm(bp, "fc1.weight", h2) + bp["fc1.bias"],
+                    approximate=False)
+    return x + _qmm(bp, "fc2.weight", m) + bp["fc2.bias"]
+
+
+def _serving_block(bp, x, eps, attend):
+    """One block of every serving program: ln1 -> qkv -> split, the
+    program's own `attend(q, k_new, v_new) -> (o, kept)` over whole rows
+    [..., heads * head_dim], the proj residual, the FFN. `kept` is what
+    the program keeps of the layer's K/V: its pools after the write
+    (step, rollout), or the fresh rows to land later (verify, prefill).
 
     The math mirrors the pipeline block cores above (same op order as
     F.scaled_dot_product_attention's XLA path: f32 scores, -1e30 mask,
-    f32 softmax, exact gelu), so prefill+N steps reproduce the full
-    forward within fp32 tolerance — tests/test_decode.py enforces it.
-    Rows past `lens` / inactive slots compute garbage that causality and
-    the cache_len mask keep out of every live row's logits.
-    """
-    if cfg.moe_experts > 0:
-        raise NotImplementedError(
-            "gpt_decode_fns: MoE blocks have no KV-decode path yet")
-    D = cfg.head_dim
-    nh = cfg.heads
+    f32 softmax, exact gelu), so prefill + N steps reproduce the full
+    forward within fp32 tolerance — tests/test_decode.py enforces it."""
+    h1 = _pp_ln(x, bp["ln1.weight"], bp["ln1.bias"], eps)
+    qkv = _qmm(bp, "attn.qkv.weight", h1) + bp["attn.qkv.bias"]
+    q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
+    o, kept = attend(q, k_new, v_new)
+    x = x + _qmm(bp, "attn.proj.weight", o) + bp["attn.proj.bias"]
+    with jax.named_scope("mlp"):
+        x = _ffn(bp, x, eps)
+    return x, kept
+
+
+def _head(embed, head, x, eps, lens=None):
+    """ln_f and the tied embedding's logits; of x [B, T, C], `lens` [B]
+    keeps each row's position lens - 1 between the two."""
+    with jax.named_scope("head"):
+        xf = _pp_ln(x, head["ln_f.weight"], head["ln_f.bias"], eps)
+        if lens is not None:
+            last = jnp.clip(lens.astype(jnp.int32) - 1, 0, x.shape[1] - 1)
+            xf = jnp.take_along_axis(xf, last[:, None, None], axis=1)[:, 0]
+        return xf @ embed["wte.weight"].T
+
+
+def gpt_dense_prefill(cfg: GPTConfig, params, tokens, lens, eps=1e-5):
+    """The whole prompt in one parallel pass, dense causal attention:
+
+    gpt_dense_prefill(cfg, params, tokens [B,T] i32, lens [B] i32)
+        -> (logits [B,V] at each row's position lens-1,
+            k, v    [layers, B, T, heads, head_dim])
+
+    The body of `gpt_paged_fns`' prefill, which lands the panels in pool
+    pages, and what tests hold the landed pages against. Rows past
+    `lens` compute garbage that causality keeps out of every live row's
+    logits."""
+    nh, D = cfg.heads, cfg.head_dim
     scale = 1.0 / math.sqrt(D)
+    embed, blocks, head = split_decode_params(params, cfg)
+    B, T = tokens.shape
+    pos = jnp.arange(T, dtype=jnp.int32)
+    x = _embed(embed, tokens, pos)
+    ks, vs = [], []
+    causal = jnp.tril(jnp.ones((T, T), bool))
 
-    def _ffn(bp, x):
-        h2 = _pp_ln(x, bp["ln2.weight"], bp["ln2.bias"], eps)
-        m = jax.nn.gelu(_qmm(bp, "fc1.weight", h2) + bp["fc1.bias"],
-                        approximate=False)
-        return x + _qmm(bp, "fc2.weight", m) + bp["fc2.bias"]
+    def attend(q, k, v):
+        q = q.reshape(B, T, nh, D)
+        k = k.reshape(B, T, nh, D)
+        v = v.reshape(B, T, nh, D)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        s = s.astype(jnp.float32)
+        s = jnp.where(causal[None, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        o = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(B, T, -1)
+        return o, (k, v)
 
-    def prefill(params, tokens, lens):
-        embed, blocks, head = split_decode_params(params, cfg)
-        B, T = tokens.shape
-        pos = jnp.arange(T, dtype=jnp.int32)
-        x = embed["wte.weight"][tokens] + embed["wpe.weight"][pos]
-        ks, vs = [], []
-        causal = jnp.tril(jnp.ones((T, T), bool))
-        for bp in blocks:
-            h1 = _pp_ln(x, bp["ln1.weight"], bp["ln1.bias"], eps)
-            qkv = _qmm(bp, "attn.qkv.weight", h1) + bp["attn.qkv.bias"]
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(B, T, nh, D)
-            k = k.reshape(B, T, nh, D)
-            v = v.reshape(B, T, nh, D)
-            ks.append(k)
-            vs.append(v)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-            s = s.astype(jnp.float32)
-            s = jnp.where(causal[None, None], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-            o = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(B, T, -1)
-            x = x + _qmm(bp, "attn.proj.weight", o) + bp["attn.proj.bias"]
-            x = _ffn(bp, x)
-        xf = _pp_ln(x, head["ln_f.weight"], head["ln_f.bias"], eps)
-        last = jnp.clip(lens.astype(jnp.int32) - 1, 0, T - 1)
-        xl = jnp.take_along_axis(xf, last[:, None, None], axis=1)[:, 0]
-        logits = xl @ embed["wte.weight"].T
-        return logits, jnp.stack(ks), jnp.stack(vs)
-
-    def _write_row(cache, new, p):
-        # cache [cap, nh, D]; new [nh, D]; p scalar row index
-        z = jnp.zeros((), p.dtype)
-        return jax.lax.dynamic_update_slice(cache, new[None], (p, z, z))
-
-    def decode_step(params, k_cache, v_cache, last_tok, cache_len):
-        from ..ops.pallas.decode_attention import decode_attention
-        embed, blocks, head = split_decode_params(params, cfg)
-        B = last_tok.shape[0]
-        pos = jnp.clip(cache_len.astype(jnp.int32), 0,
-                       cfg.max_seq_len - 1)
-        x = embed["wte.weight"][last_tok] + embed["wpe.weight"][pos]
-        k_out, v_out = [], []
-        lengths = pos + 1                 # the row just written is live
-        for i, bp in enumerate(blocks):
-            h1 = _pp_ln(x, bp["ln1.weight"], bp["ln1.bias"], eps)
-            qkv = _qmm(bp, "attn.qkv.weight", h1) + bp["attn.qkv.bias"]
-            q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(B, nh, D)
-            k_new = k_new.reshape(B, nh, D)
-            v_new = v_new.reshape(B, nh, D)
-            ki = jax.vmap(_write_row)(k_cache[i], k_new, pos)
-            vi = jax.vmap(_write_row)(v_cache[i], v_new, pos)
-            k_out.append(ki)
-            v_out.append(vi)
-            o = decode_attention(q, ki, vi, lengths).reshape(B, -1)
-            x = x + _qmm(bp, "attn.proj.weight", o) + bp["attn.proj.bias"]
-            x = _ffn(bp, x)
-        xf = _pp_ln(x, head["ln_f.weight"], head["ln_f.bias"], eps)
-        logits = xf @ embed["wte.weight"].T
-        return logits, jnp.stack(k_out), jnp.stack(v_out)
-
-    return prefill, decode_step
+    for bp in blocks:
+        x, (k, v) = _serving_block(bp, x, eps, attend)
+        ks.append(k)
+        vs.append(v)
+    return _head(embed, head, x, eps, lens), jnp.stack(ks), jnp.stack(vs)
 
 
-def gpt_paged_decode_fns(cfg: GPTConfig, eps: float = 1e-5,
-                         page_tokens: int = 16):
-    """Pure `(prefill, paged_step)` over a PAGED KV cache.
+def gpt_paged_fns(cfg: GPTConfig, eps: float = 1e-5, page_tokens: int = 16,
+                  prefill_name: str = "prefill"):
+    """The four pure programs the decode engines run over a PAGED KV
+    cache, `(prefill, paged_step, paged_verify, paged_rollout)`, each
+    over `pools = (k_pool, v_pool)` as one pytree in and out (a pool:
+    layers x [P, page_tokens, heads * head_dim], or the int8 pair).
+    `tables` [B, W] int32 are the block tables, unused entries aimed at
+    the null page 0; position p of a row lives at page
+    tables[b, p // page_tokens], row p % page_tokens, in every program.
 
-    `prefill` is gpt_decode_fns' — `gpt_paged_prefill_fns` wraps it to
-    land the contiguous panel it returns in pool pages. The step
-    replaces the per-slot contiguous panel with a shared page pool +
-    block tables:
+    prefill(params, pools, toks [1, R], tables [1, W], n [1])
+        -> (logits [1, V], pools)
+      Fused prefill-into-pages: `gpt_dense_prefill` over the prompt
+      padded to the rung R, each layer's panel cut into whole pages and
+      page j landed on tables[0, j] (`write_pages`, one index per page,
+      in place), so an admission is a single dispatch and nothing of K
+      or V crosses to the host. Rows at or past `n` are written as
+      zeros, so rung garbage never enters the pool and a page's tail
+      holds nothing stale; table padding aims at the null page, which
+      takes whatever falls there. An int8 pool quantizes the pages per
+      (row, head) inside the same executable. `logits` is the last
+      position's — callers that only want the K/V ignore it. The
+      target's admission, the KV-handoff export and the draft model's
+      prefill all run it; `prefill_name` is the function's name, and so
+      the compiled program's in a device trace (`jit_<name>`): an engine
+      that runs two of these (target and draft) names them apart.
 
-    paged_step(params,
-               k_pool, v_pool  layers x [P, page_tokens, heads * head_dim],
-               tables   [B, W] int32 (unused entries -> null page 0),
-               last_tok [B] int32,
-               cache_len [B] int32)
-        -> (logits [B,V], k_pool, v_pool)
+    paged_step(params, pools, tables, last_tok [B], cache_len [B])
+        -> (logits [B, V], pools)
+      The new token's K/V lands at its page via one row scatter per
+      layer pool, in place (padded batch rows carry all-null tables, so
+      their garbage writes fall into the reserved scratch page);
+      attention then reads the pool through the block table
+      (`ops.pallas.decode_attention.paged_decode_attention`). One
+      executable serves every occupancy of a (batch-rung x page-rung)
+      bucket, and capacity growth is just a wider block table, never a
+      cache copy.
 
-    The new token's K/V lands at page tables[b, cache_len//pt], row
-    cache_len%pt, via one row scatter per layer pool, in place (padded
-    batch rows carry all-null tables, so their garbage writes fall into
-    the reserved scratch page); attention walks the block table through
-    `ops.pallas.decode_attention.paged_decode_attention`. One executable
-    serves every occupancy of a (batch-rung x page-rung) bucket, and —
-    unlike the contiguous pool — capacity growth is just a wider block
-    table, never a cache copy.
+    paged_verify(params, pools, tables, toks [B, K1], cache_len [B])
+        -> (logits [B, K1, V], argmax [B, K1] int32, pools)
+      The target side of speculative decoding. Row i of `toks` is the
+      token at absolute position `cache_len + i`; `logits[b, i]` is the
+      next-token distribution AFTER consuming toks[b, :i+1], so one call
+      scores every drafted position at once, and position p attends keys
+      0..p, drafted predecessors included. Positions at or past
+      max_seq_len redirect their writes to the null page, so padded
+      verify rows near the sequence cap never clobber live data. A
+      verified-and-accepted token stream is argmax-identical to plain
+      incremental decode.
+
+    paged_rollout(params, pools, tables, forced [B, K], cache_len [B])
+        -> (drafts [B, K] int32, pools)
+      The draft side of speculative decoding: K greedy steps fused into
+      ONE executable (`fori_loop` over the step's body), so a scheduler
+      tick costs two dispatches (rollout + verify) instead of k + 1.
+      Step i consumes one token at position `cache_len + i` —
+      `forced[b, i]` where it is >= 0 (a committed token the draft has
+      not seen: catch-up), else the previous step's own argmax — and
+      records its greedy argmax in `drafts[b, i]`. `forced[:, 0]` must
+      be >= 0: the engine always has at least one committed token the
+      draft has not consumed. Overruns of max_seq_len write to the null
+      page, as in verify.
     """
     if cfg.moe_experts > 0:
         raise NotImplementedError(
-            "gpt_paged_decode_fns: MoE blocks have no KV-decode path yet")
+            "gpt_paged_fns: MoE blocks have no KV-decode path yet")
+    from ..memory.page_allocator import write_pages
+    from ..ops.pallas.decode_attention import (gathered_panel, head_mix,
+                                               head_scores,
+                                               paged_decode_attention)
     D = cfg.head_dim
     nh = cfg.heads
     pt = int(page_tokens)
 
-    def _ffn(bp, x):
-        h2 = _pp_ln(x, bp["ln2.weight"], bp["ln2.bias"], eps)
-        m = jax.nn.gelu(_qmm(bp, "fc1.weight", h2) + bp["fc1.bias"],
-                        approximate=False)
-        return x + _qmm(bp, "fc2.weight", m) + bp["fc2.bias"]
-
     @jax.jit
     def block(bp, x, k_layer, v_layer, tables, page_idx, offset, lengths):
-        """One block over its layer's pools. A jit of its own, so the
+        """One block of the step (and of each step of a rollout) over
+        its layer's pools: the new rows written in place, then the pool
+        attended through the block table. A jit of its own, so the
         step's trace holds the block once and calls it a layer: every
         layer has the same shapes, and an engine traces the step once
         a (batch rung, page rung) — forty times at a hundred slots,
         most of what its warm-up costs. The compiler inlines the calls;
         the pools are still written in place."""
         B = x.shape[0]
-        h1 = _pp_ln(x, bp["ln1.weight"], bp["ln1.bias"], eps)
-        qkv = _qmm(bp, "attn.qkv.weight", h1) + bp["attn.qkv.bias"]
-        q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, nh, D)
-        k_new = k_new.reshape(B, nh, D)
-        v_new = v_new.reshape(B, nh, D)
-        with jax.named_scope("pool_write"):
-            k_layer = _kv_pool_write(k_layer, page_idx, offset, k_new)
-            v_layer = _kv_pool_write(v_layer, page_idx, offset, v_new)
-        with jax.named_scope("attention"):  # "page_gather" inside it
-            o = _paged_attend(q, k_layer, v_layer, tables,
-                              lengths).reshape(B, -1)
-        x = x + _qmm(bp, "attn.proj.weight", o) + bp["attn.proj.bias"]
-        with jax.named_scope("mlp"):
-            x = _ffn(bp, x)
+
+        def attend(q, k_new, v_new):
+            q = q.reshape(B, nh, D)
+            k_new = k_new.reshape(B, nh, D)
+            v_new = v_new.reshape(B, nh, D)
+            with jax.named_scope("pool_write"):
+                k = _kv_pool_write(k_layer, page_idx, offset, k_new)
+                v = _kv_pool_write(v_layer, page_idx, offset, v_new)
+            with jax.named_scope("attention"):  # "page_gather" inside it
+                o = paged_decode_attention(q, k, v, tables, lengths)
+            return o.reshape(B, -1), (k, v)
+
+        x, (k_layer, v_layer) = _serving_block(bp, x, eps, attend)
         return x, k_layer, v_layer
 
-    def paged_step(params, k_pool, v_pool, tables, last_tok, cache_len):
-        embed, blocks, head = split_decode_params(params, cfg)
-        W = tables.shape[1]
-        pos = jnp.clip(cache_len.astype(jnp.int32), 0,
-                       cfg.max_seq_len - 1)
-        x = embed["wte.weight"][last_tok] + embed["wpe.weight"][pos]
-        page_idx = jnp.take_along_axis(
-            tables, jnp.minimum(pos // pt, W - 1)[:, None], axis=1)[:, 0]
-        offset = pos % pt
+    def one_token(embed, blocks, head, pools, tables, tok, pos, valid=None):
+        """Every row one token on: `tok` [B] at position `pos` [B]."""
+        x = _embed(embed, tok, pos)
+        page_idx, offset = _page_address(tables, pos, pt, valid)
         lengths = pos + 1                 # the row just written is live
-        k_pool, v_pool = list(k_pool), list(v_pool)
+        k_pool, v_pool = list(pools[0]), list(pools[1])
         for i, bp in enumerate(blocks):
             x, k_pool[i], v_pool[i] = block(
                 bp, x, k_pool[i], v_pool[i], tables, page_idx, offset,
                 lengths)
-        with jax.named_scope("head"):
-            xf = _pp_ln(x, head["ln_f.weight"], head["ln_f.bias"], eps)
-            logits = xf @ embed["wte.weight"].T
-        return logits, tuple(k_pool), tuple(v_pool)
+        return _head(embed, head, x, eps), (tuple(k_pool), tuple(v_pool))
 
-    prefill, _ = gpt_decode_fns(cfg, eps=eps)
-    return prefill, paged_step
+    def paged_step(params, pools, tables, last_tok, cache_len):
+        parts = split_decode_params(params, cfg)
+        # live rows never overrun (the engine finishes a stream at the
+        # cap), so the step clips and has no redirect to pay for
+        pos = jnp.clip(cache_len.astype(jnp.int32), 0,
+                       cfg.max_seq_len - 1)
+        return one_token(*parts, pools, tables, last_tok, pos)
 
+    def paged_rollout(params, pools, tables, forced, cache_len):
+        parts = split_decode_params(params, cfg)
+        B, K = forced.shape
+        base = cache_len.astype(jnp.int32)
 
-def gpt_paged_verify_fns(cfg: GPTConfig, eps: float = 1e-5,
-                         page_tokens: int = 16):
-    """Pure multi-token verify step over a PAGED KV cache — the target
-    side of speculative decoding.
+        def step(i, carry):
+            prev, drafts, pools = carry
+            want = jax.lax.dynamic_slice_in_dim(forced, i, 1, axis=1)[:, 0]
+            tok = jnp.where(want >= 0, want, prev)
+            pos = base + i
+            logits, pools = one_token(
+                *parts, pools, tables, tok,
+                jnp.minimum(pos, cfg.max_seq_len - 1),
+                pos < cfg.max_seq_len)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            drafts = jax.lax.dynamic_update_slice_in_dim(
+                drafts, nxt[:, None], i, axis=1)
+            return nxt, drafts, pools
 
-    paged_verify(params,
-                 k_pool, v_pool  layers x [P, page_tokens, heads * head_dim],
-                 tables    [B, W]  int32 (unused entries -> null page 0),
-                 toks      [B, K1] int32 (token at position cache_len+i),
-                 cache_len [B]     int32)
-        -> (logits [B, K1, V], k_pool, v_pool)
+        _, drafts, pools = jax.lax.fori_loop(
+            0, K, step, (forced[:, 0], jnp.zeros((B, K), jnp.int32),
+                         (tuple(pools[0]), tuple(pools[1]))))
+        return drafts, pools
 
-    Row i of `toks` is the token at absolute position `cache_len + i`;
-    its K/V lands at page tables[b, pos//pt], row pos%pt — the exact
-    addressing `paged_step` uses, via one [B, K1] row scatter per layer
-    pool. `logits[b, i]` is the target's next-token distribution
-    AFTER consuming toks[b, :i+1], so one call scores every drafted
-    position at once. Attention gathers the block table like the XLA
-    reference kernel and masks per query: position p attends keys
-    0..p, which includes the rows this very call just wrote (drafted
-    tokens see their drafted predecessors). Positions at or past
-    max_seq_len redirect their writes to the null page, so padded
-    verify rows near the sequence cap never clobber live data. The math
-    (f32 scores, -1e30 mask, exact gelu) mirrors `gpt_decode_fns` so a
-    verified-and-accepted token stream is argmax-identical to plain
-    incremental decode.
-    """
-    if cfg.moe_experts > 0:
-        raise NotImplementedError(
-            "gpt_paged_verify_fns: MoE blocks have no KV-decode path yet")
-    D = cfg.head_dim
-    nh = cfg.heads
-    pt = int(page_tokens)
-
-    def _ffn(bp, x):
-        h2 = _pp_ln(x, bp["ln2.weight"], bp["ln2.bias"], eps)
-        m = jax.nn.gelu(_qmm(bp, "fc1.weight", h2) + bp["fc1.bias"],
-                        approximate=False)
-        return x + _qmm(bp, "fc2.weight", m) + bp["fc2.bias"]
-
-    def paged_verify(params, k_pool, v_pool, tables, toks, cache_len):
-        from ..ops.pallas.decode_attention import (gathered_panel, head_mix,
-                                                   head_scores)
+    def paged_verify(params, pools, tables, toks, cache_len):
         embed, blocks, head = split_decode_params(params, cfg)
+        k_pool, v_pool = pools
         B, K1 = toks.shape
-        W = tables.shape[1]
         pos = cache_len.astype(jnp.int32)[:, None] \
             + jnp.arange(K1, dtype=jnp.int32)[None]          # [B, K1]
         valid = pos < cfg.max_seq_len
         pos_c = jnp.minimum(pos, cfg.max_seq_len - 1)
-        x = embed["wte.weight"][toks] + embed["wpe.weight"][pos_c]
-        slot = jnp.minimum(pos_c // pt, W - 1)
-        page_idx = jnp.take_along_axis(tables, slot, axis=1)  # [B, K1]
-        page_idx = jnp.where(valid, page_idx, 0)  # overruns -> null page
-        offset = pos_c % pt
-        kcap = W * pt
+        x = _embed(embed, toks, pos_c)
+        page_idx, offset = _page_address(tables, pos_c, pt, valid)
+        kcap = tables.shape[1] * pt
         # Attention is split prefix/window so the pool gathers stand
         # before every write: the committed prefix (rows < cache_len) is
         # gathered from each layer's pool as the call found it, while
@@ -1279,20 +1280,19 @@ def gpt_paged_verify_fns(cfg: GPTConfig, eps: float = 1e-5,
         win_causal = (win[None, :] <= win[:, None])[None, :, None]  # [1,K1,1,K1]
         k_news, v_news = [], []
         for i, bp in enumerate(blocks):
-            h1 = _pp_ln(x, bp["ln1.weight"], bp["ln1.bias"], eps)
-            qkv = _qmm(bp, "attn.qkv.weight", h1) + bp["attn.qkv.bias"]
-            q, k_new, v_new = jnp.split(qkv, 3, axis=-1)      # [B, K1, C]
+            def attend(q, k_new, v_new, keys=keys_all[i], vals=vals_all[i]):
+                sp = jnp.where(prefix_live, head_scores(q, keys, nh),
+                               -1e30)                         # [B,K1,nh,kcap]
+                sw = jnp.where(win_causal, head_scores(q, k_new, nh), -1e30)
+                s = jnp.concatenate([sp, sw], axis=-1)
+                p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+                o = head_mix(p[..., :kcap], vals) \
+                    + head_mix(p[..., kcap:], v_new)          # [B, K1, C]
+                return o, (k_new, v_new)
+
+            x, (k_new, v_new) = _serving_block(bp, x, eps, attend)
             k_news.append(k_new)
             v_news.append(v_new)
-            sp = jnp.where(prefix_live, head_scores(q, keys_all[i], nh),
-                           -1e30)                             # [B,K1,nh,kcap]
-            sw = jnp.where(win_causal, head_scores(q, k_new, nh), -1e30)
-            s = jnp.concatenate([sp, sw], axis=-1)
-            p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-            o = head_mix(p[..., :kcap], vals_all[i]) \
-                + head_mix(p[..., kcap:], v_new)              # [B, K1, C]
-            x = x + _qmm(bp, "attn.proj.weight", o) + bp["attn.proj.bias"]
-            x = _ffn(bp, x)
         # the fresh K/V of every layer (page_idx/offset are
         # layer-invariant); accepted rows persist, rejected rows become
         # garbage above the rolled-back cache_len, overruns hit page 0
@@ -1302,52 +1302,15 @@ def gpt_paged_verify_fns(cfg: GPTConfig, eps: float = 1e-5,
         v_pool = tuple(
             _kv_pool_write(p, page_idx, offset, r.reshape(B, K1, nh, D))
             for p, r in zip(v_pool, v_news))
-        xf = _pp_ln(x, head["ln_f.weight"], head["ln_f.bias"], eps)
-        logits = xf @ embed["wte.weight"].T
+        logits = _head(embed, head, x, eps)
         amax = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return logits, amax, k_pool, v_pool
+        return logits, amax, (k_pool, v_pool)
 
-    return paged_verify
-
-
-def gpt_paged_prefill_fns(cfg: GPTConfig, eps: float = 1e-5,
-                          page_tokens: int = 16,
-                          name: str = "paged_prefill"):
-    """Pure fused prefill-into-pages: one executable computes the
-    prompt's K/V panel (the parallel `gpt_decode_fns` prefill) AND
-    scatters it into pool pages, so an admission is a single dispatch
-    and nothing of K or V crosses to the host. The target model's
-    admission, the KV-handoff export and the draft model's prefill all
-    run it.
-
-    paged_prefill(params,
-                  k_pool, v_pool  layers x [P, page_tokens, heads * head_dim],
-                  toks   [1, R] int32 (prompt padded to the rung),
-                  tables [1, W] int32 (W >= ceil(R / page_tokens)),
-                  n      [1]    int32 (true prompt length)
-        -> (logits [1, V], k_pool, v_pool)
-
-    Each layer's panel is cut into whole pages and page j lands on
-    tables[0, j] of that layer's pool (`write_pages`, one index per
-    page, in place): rows at or past `n` are written
-    as zeros, so rung garbage never enters the pool and a page's tail
-    holds nothing stale; table padding aims at the null page, which
-    takes whatever falls there. An int8 pool quantizes the pages per
-    (row, head) inside the same executable. `logits` is the prefill's
-    last-position head — callers that only want the K/V ignore it.
-
-    `name` is the returned function's name, and so the compiled
-    program's in a device trace (`jit_<name>`): an engine that runs two
-    of these (target and draft) names them apart.
-    """
-    from ..memory.page_allocator import write_pages
-    pt = int(page_tokens)
-    prefill, _ = gpt_decode_fns(cfg, eps=eps)
-
-    def paged_prefill(params, k_pool, v_pool, toks, tables, n):
+    def prefill(params, pools, toks, tables, n):
+        k_pool, v_pool = pools
         R = toks.shape[1]
         W = tables.shape[1]
-        logits, k, v = prefill(params, toks, n)
+        logits, k, v = gpt_dense_prefill(cfg, params, toks, n, eps)
         live = (jnp.arange(R, dtype=jnp.int32) < n[0])[:, None, None]
 
         def pages(panel):              # [1, R, nh, D] -> [W, pt, nh * D]
@@ -1363,104 +1326,7 @@ def gpt_paged_prefill_fns(cfg: GPTConfig, eps: float = 1e-5,
             return tuple(write_pages(p, pages(panels[i]), tables[0])
                          for i, p in enumerate(pool))
 
-        return logits, land(k_pool, k), land(v_pool, v)
+        return logits, (land(k_pool, k), land(v_pool, v))
 
-    paged_prefill.__name__ = paged_prefill.__qualname__ = name
-    return paged_prefill
-
-
-def gpt_paged_rollout_fns(cfg: GPTConfig, eps: float = 1e-5,
-                          page_tokens: int = 16):
-    """Pure K-step greedy draft rollout over a PAGED KV cache — the
-    draft side of speculative decoding fused into ONE executable, so a
-    scheduler tick costs two dispatches (rollout + verify) instead of
-    k + 1.
-
-    paged_rollout(params,
-                  k_pool, v_pool  layers x [P, page_tokens, heads * head_dim],
-                  tables [B, W] int32 (unused entries -> null page 0),
-                  forced [B, K] int32 (>= 0: the committed token to
-                          consume at step i — catch-up; -1: chain the
-                          previous step's own argmax),
-                  cache_len [B] int32)
-        -> (drafts [B, K] int32, k_pool, v_pool)
-
-    Step i consumes one token at absolute position `cache_len + i`,
-    writes its K/V at page tables[b, pos//pt] row pos%pt (the exact
-    `paged_step` addressing) and records the greedy argmax in
-    `drafts[b, i]`. `forced[:, 0]` must be >= 0 — the engine always has
-    at least one committed token the draft has not consumed. Positions
-    at or past max_seq_len redirect their writes to the null page, so a
-    slot drafting into the sequence cap never clobbers live rows.
-    Attention is the gathered-pool XLA path of `paged_verify` with a
-    single query row; draft numerics only move the acceptance rate,
-    never output correctness, so no Pallas kernel is spent here.
-    """
-    if cfg.moe_experts > 0:
-        raise NotImplementedError(
-            "gpt_paged_rollout_fns: MoE blocks have no KV-decode path yet")
-    D = cfg.head_dim
-    nh = cfg.heads
-    pt = int(page_tokens)
-
-    def _ffn(bp, x):
-        h2 = _pp_ln(x, bp["ln2.weight"], bp["ln2.bias"], eps)
-        m = jax.nn.gelu(_qmm(bp, "fc1.weight", h2) + bp["fc1.bias"],
-                        approximate=False)
-        return x + _qmm(bp, "fc2.weight", m) + bp["fc2.bias"]
-
-    def paged_rollout(params, k_pool, v_pool, tables, forced, cache_len):
-        from ..ops.pallas.decode_attention import (gathered_panel, head_mix,
-                                                   head_scores)
-        embed, blocks, head = split_decode_params(params, cfg)
-        B, K = forced.shape
-        W = tables.shape[1]
-        kcap = W * pt
-        base = cache_len.astype(jnp.int32)
-
-        def step(i, carry):
-            prev, drafts, k_pool, v_pool = carry
-            want = jax.lax.dynamic_slice_in_dim(forced, i, 1, axis=1)[:, 0]
-            tok = jnp.where(want >= 0, want, prev)
-            pos = base + i
-            valid = pos < cfg.max_seq_len
-            pos_c = jnp.minimum(pos, cfg.max_seq_len - 1)
-            x = embed["wte.weight"][tok] + embed["wpe.weight"][pos_c]
-            slot = jnp.minimum(pos_c // pt, W - 1)
-            page_idx = jnp.take_along_axis(
-                tables, slot[:, None], axis=1)[:, 0]
-            page_idx = jnp.where(valid, page_idx, 0)
-            offset = pos_c % pt
-            live = jnp.arange(kcap, dtype=jnp.int32)[None, :] \
-                < (pos_c + 1)[:, None]                       # [B, kcap]
-            k_pool, v_pool = list(k_pool), list(v_pool)
-            for li, bp in enumerate(blocks):
-                h1 = _pp_ln(x, bp["ln1.weight"], bp["ln1.bias"], eps)
-                qkv = _qmm(bp, "attn.qkv.weight", h1) + bp["attn.qkv.bias"]
-                q, k_new, v_new = jnp.split(qkv, 3, axis=-1)  # [B, C]
-                k_pool[li] = _kv_pool_write(k_pool[li], page_idx, offset,
-                                            k_new.reshape(B, nh, D))
-                v_pool[li] = _kv_pool_write(v_pool[li], page_idx, offset,
-                                            v_new.reshape(B, nh, D))
-                keys = gathered_panel(k_pool[li], tables)     # [B, kcap, C]
-                vals = gathered_panel(v_pool[li], tables)
-                s = head_scores(q[:, None], keys, nh)         # [B,1,nh,kcap]
-                s = jnp.where(live[:, None, None], s, -1e30)
-                p = jax.nn.softmax(s, axis=-1).astype(vals.dtype)
-                o = head_mix(p, vals)[:, 0]
-                x = x + _qmm(bp, "attn.proj.weight", o) + bp["attn.proj.bias"]
-                x = _ffn(bp, x)
-            xf = _pp_ln(x, head["ln_f.weight"], head["ln_f.bias"], eps)
-            logits = xf @ embed["wte.weight"].T
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            drafts = jax.lax.dynamic_update_slice_in_dim(
-                drafts, nxt[:, None], i, axis=1)
-            return nxt, drafts, tuple(k_pool), tuple(v_pool)
-
-        prev0 = forced[:, 0]
-        drafts0 = jnp.zeros((B, K), jnp.int32)
-        _, drafts, k_pool, v_pool = jax.lax.fori_loop(
-            0, K, step, (prev0, drafts0, tuple(k_pool), tuple(v_pool)))
-        return drafts, k_pool, v_pool
-
-    return paged_rollout
+    prefill.__name__ = prefill.__qualname__ = prefill_name
+    return prefill, paged_step, paged_verify, paged_rollout

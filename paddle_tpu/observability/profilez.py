@@ -15,8 +15,7 @@ each compiled executable so every dispatch reports
 Observations land in the ``paddle_tpu_exec_*`` metric families (labeled
 by executable) and in a process-global :class:`ExecProfiler` whose
 :meth:`top` ranks executables by total block time — served live as the
-AdminServer's ``/profilez`` and embedded in serve_bench ``--decode``
-JSON as ``profilez_top``.  Compiles are counted per executable too, so
+AdminServer's ``/profilez``.  Compiles are counted per executable too, so
 "did steady state stay compile-free" is one scrape away.
 """
 from __future__ import annotations

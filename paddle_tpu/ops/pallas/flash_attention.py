@@ -69,9 +69,8 @@ def _block_sizes(T, D, env_key="PT_FLASH_FWD_BLOCKS"):
     (measured 8.5 ms/layer fwd+bwd vs 3.9 ms at (512,1024) on v5e). The env
     keys PT_FLASH_{FWD,BWD}_BLOCKS are perf-tuning escape hatches.
 
-    (1024, 1024) caps were the long-context sweep's optimum on v5e when
-    last swept (reproduce with benchmarks/longctx.py). 2048-wide blocks
-    exceed VMEM at D=64 (the f32 score tile alone is 16 MB)."""
+    Blocks are capped at (1024, 1024): 2048-wide blocks exceed VMEM at
+    D=64 (the f32 score tile alone is 16 MB)."""
     if env_key in os.environ:
         return _env_blocks(env_key, T)
     return _pick_block(T, 1024), _pick_block(T, 1024)
@@ -85,8 +84,6 @@ def _bwd_block_sizes(T, D):
     (q/k/v/do/o bf16 + lse f32: ~(4*max(bq,bk)*D*2 + bq*128*4)*2 B) and
     the dk/dv f32 scratch (2*bk*D*4 B). At (1024, 1024):
       D=64 : 12 MB + 1.9 MB + 0.5 MB ~= 14.4 MB -> fits 16 MB VMEM
-             (exercised fwd+bwd by the benchmarks/longctx.py training
-             sweep at T=1k..16k, D=64)
       D=128: 12 MB + 3.5 MB + 1.0 MB ~= 16.5 MB -> over budget, so wide
              heads cap bq at 512, halving the score tiles to 2 MB each
              (~9.75 MB total) with the same nk==1 fused-path eligibility

@@ -37,7 +37,6 @@ the chip the kernel body runs in interpret mode.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +46,6 @@ from jax.experimental import pallas as pl
 from . import _common
 from ._common import NEG_INF, LANE, I0 as _I0, pltpu
 
-_ENV = "PADDLE_TPU_DECODE_KERNEL"
 PAGES_PER_STEP = 4      # pages one grid cell attends (W permitting)
 KERNEL_NAME = "paged_latent_decode_attention"
 
@@ -159,17 +157,14 @@ def _pallas(q_abs, q_rope, pool, tables, lengths, scale):
 
 def paged_latent_decode_attention(q_abs, q_rope, pool, tables, lengths,
                                   scale, kernel=None):
-    """Dispatch on `kernel`, else $PADDLE_TPU_DECODE_KERNEL where it is
-    set, else the Pallas kernel on a TPU and the reference off it (the
-    interpreter is for tests). The reference gathers every mapped page
-    into a panel: on the chip it is the slow path by construction."""
-    choice = (kernel or os.environ.get(_ENV, "")).strip().lower()
-    if not choice:
-        choice = "pallas" if _common.on_tpu() else "xla"
+    """The Pallas kernel on a TPU and the reference off it (the
+    interpreter is for tests), unless `kernel` ("pallas" | "xla") says.
+    The reference gathers every mapped page into a panel: on the chip
+    it is the slow path by construction."""
+    choice = kernel or ("pallas" if _common.on_tpu() else "xla")
     if choice == "pallas":
         return _pallas(q_abs, q_rope, pool, tables, lengths, scale)
     if choice == "xla":
         return paged_latent_decode_attention_reference(
             q_abs, q_rope, pool, tables, lengths, scale)
-    raise ValueError(f"{_ENV}={choice!r}: expected 'pallas' or 'xla'")
-
+    raise ValueError(f"kernel={choice!r}: expected 'pallas' or 'xla'")

@@ -191,8 +191,9 @@ def record_step(step: int, **segments):
     waiting for the result), plus ``in_flight``.  Overlap is proven when
     ``collate_s + dispatch_s + fetch_s`` (the dispatch gap the host pays)
     is well under ``compute_s`` (the device step time).  Always
-    collected, like compiles — bench.py aggregates these into its
-    ``host_blocked_s`` / ``steps_in_flight`` JSON fields."""
+    collected, like compiles — ``step_timeline_summary`` aggregates
+    these (``host_blocked_s`` / ``steps_in_flight``; chipbench's
+    ``fit.host_blocked_share`` reads it)."""
     with _lock:
         _steps.append({"step": int(step), **segments})
         if len(_steps) > _STEP_CAP:
@@ -259,8 +260,7 @@ def record_serve_batch(rows: int, capacity: int, real_elems: int,
     packed into a ``capacity``-row bucket, ``real_elems``/``padded_elems``
     element counts before/after shape-bucket padding, and the request
     queue depth observed at dispatch. Always collected (like compiles):
-    the serve stats line and benchmarks/serve_bench.py read these with
-    the host profiler off."""
+    the serve stats line reads these with the host profiler off."""
     _SRV_BATCHES.inc()
     _SRV_ROWS.inc(int(rows))
     _SRV_CAP.inc(int(capacity))

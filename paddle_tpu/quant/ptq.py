@@ -7,7 +7,7 @@ fp32 scale rides along under ``name + "::scale"``. Consumers that never
 look for the suffix (``split_decode_params``, the npz writer, the
 engine's host->device upload) work unchanged, and the decode fns in
 ``models.gpt`` route any matmul whose weight has a ``::scale`` sibling
-through the fused dequant matmul (`ops.pallas.quant_matmul`).
+through the dequant-after-product matmul (`ops.pallas.quant_matmul`).
 
 Convention (symmetric, per-channel over the contraction axis)::
 

@@ -3,6 +3,7 @@
 `DecodeEngine`) against the benchmark's plain reference; the pieces
 (YaRN, absorbed against expanded MLA, the latent kernel, the routed
 layer and its share); what the model kind refuses; its artifact."""
+import functools
 import json
 import math
 
@@ -22,7 +23,7 @@ from paddle_tpu.inference.errors import (ERR_FAILED_PRECONDITION,
                                          TypedServeError)
 from paddle_tpu.models import axk1
 from paddle_tpu.models.axk1 import AXK1, AXK1Config, axk1_tiny
-from paddle_tpu.models.gpt import GPT, gpt_paged_decode_fns, gpt_tiny
+from paddle_tpu.models.gpt import GPT, gpt_tiny
 from paddle_tpu.nn.layer import moe
 from paddle_tpu.ops.pallas import latent_attention as la
 
@@ -140,7 +141,7 @@ def test_latent_kernel_matches_its_reference_in_interpret_mode(shape):
 def test_absorbed_attention_equals_expanded(monkeypatch):
     """The decode step's absorbed form over the latent pool gives, for
     the last position, what the expanded form gives over the whole
-    sequence."""
+    sequence, through either reader of the pool."""
     model, params = build()
     cfg = model.cfg
     lp = axk1.layer_params(params, 1)
@@ -156,8 +157,10 @@ def test_absorbed_attention_equals_expanded(monkeypatch):
     pool = pool.at[jnp.arange(1, W + 1)].set(
         pages.reshape(W, pt, cfg.pool_row_width))
     tables = jnp.arange(1, W + 1, dtype=jnp.int32)[None]
+    reader = la.paged_latent_decode_attention
     for kernel in ("xla", "pallas"):
-        monkeypatch.setenv("PADDLE_TPU_DECODE_KERNEL", kernel)
+        monkeypatch.setattr(la, "paged_latent_decode_attention",
+                            functools.partial(reader, kernel=kernel))
         got = axk1.mla_absorbed(cfg, lp, q_nope[-1:], q_rope[-1:], pool,
                                 tables, jnp.asarray([T], jnp.int32))[0]
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -465,6 +468,24 @@ def test_speculation_is_refused_for_target_and_for_draft():
         assert "speculative" in str(err.value)
 
 
+def test_speculation_over_axk1_never_reaches_a_gpt_builder(monkeypatch):
+    """`SpecDecodeEngine` asks its kinds for verify and rollout; an
+    `AXK1Kind` has neither and says so (typed) before anything is built:
+    no gpt program is asked for on the way."""
+    model, params = build()
+    asked = []
+    monkeypatch.setattr(model_kinds, "gpt_paged_fns",
+                        lambda *a, **k: asked.append(a) or 1 / 0)
+    with pytest.raises(TypedServeError) as err:
+        SpecDecodeEngine(model, draft_cfg=model.cfg, draft_params=params,
+                         speculate_k=2, max_slots=1)
+    assert err.value.code == ERR_FAILED_PRECONDITION
+    assert "speculative" in str(err.value) and "ROADMAP" in str(err.value)
+    assert not asked
+    assert not hasattr(model_kinds.AXK1Kind, "verify_fn")
+    assert not hasattr(model_kinds.AXK1Kind, "rollout_fn")
+
+
 # ------------------------------------------------------------ artifacts
 
 
@@ -522,10 +543,10 @@ def test_an_artifact_without_the_key_is_a_gpt(tmp_path):
 
 
 def test_the_seam_leaves_the_gpt_step_program_as_it_was():
-    """The chat cell's step through `GPTKind` lowers to the HLO that
-    `gpt_paged_decode_fns`' own step lowers to: same module name, same
-    parameters in the same order, same operations (the text is compared
-    with source locations stripped)."""
+    """The chat cell's step through `GPTKind` is the builder's own
+    (`gpt_paged_fns`, no closure between): the module is
+    `jit_paged_step`, and its arguments are the parameters, 2 x `layers`
+    pool leaves, tables, tokens, lengths, in that order."""
     import re
 
     cfg = gpt_tiny()
@@ -536,25 +557,27 @@ def test_the_seam_leaves_the_gpt_step_program_as_it_was():
     i32 = jnp.int32
     rest = (jax.ShapeDtypeStruct((2, 4), i32),
             jax.ShapeDtypeStruct((2,), i32), jax.ShapeDtypeStruct((2,), i32))
-    through = jax.jit(kind.step_fn(4), donate_argnums=(1,)).lower(
-        params, pools, *rest)
-    _, step = gpt_paged_decode_fns(cfg, eps=kind.eps, page_tokens=4)
-    direct = jax.jit(step, donate_argnums=(1, 2)).lower(
-        params, *pools, *rest)
-
-    def text(lowered):
-        t = lowered.compiler_ir(dialect="stablehlo").operation.get_asm(
-            enable_debug_info=False)
-        return re.sub(r'jax\.result_info = "[^"]*"', "", t)
-
-    assert text(through) == text(direct)
-    assert "jit_paged_step" in text(through)
+    step = kind.step_fn(4)
+    assert step.__name__ == "paged_step" \
+        and step.__module__ == "paddle_tpu.models.gpt"
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pools, *rest).compiler_ir(
+            dialect="stablehlo").operation.get_asm(enable_debug_info=False)
+    assert "module @jit_paged_step" in text
+    main = re.search(r"func\.func public @main\((.*?)\) ->", text, re.S)
+    got = re.findall(r"%arg\d+: tensor<([^>]*)>", main.group(1))
+    leaves = jax.tree.leaves((params, pools, *rest))
+    assert len(leaves) == len(params) + 2 * cfg.layers + 3
+    dt = {"float32": "f32", "int32": "i32"}
+    assert got == ["x".join([*map(str, a.shape), dt[str(a.dtype)]])
+                   for a in leaves]
 
 
 def test_the_base_engine_names_no_gpt():
     """The base `DecodeEngine` reaches a model only through its kind:
     no `GPTConfig`, head count, head size or pair of pools in its source
-    (`SpecDecodeEngine`, behind its typed refusal, keeps GPT's)."""
+    (`SpecDecodeEngine`, behind its typed refusal, keeps GPT's), and
+    nothing in `decode.py` reaches round a kind for a gpt builder."""
     import inspect
 
     from paddle_tpu.inference import decode
@@ -564,3 +587,6 @@ def test_the_base_engine_names_no_gpt():
     for word in ("GPTConfig", "gpt_paged", ".heads", "head_dim", "k_pool",
                  "v_pool", "_kpool", "_vpool", "cfg.layers"):
         assert word not in src, word
+    # (its module docstring says where a GPT's programs come from)
+    assert "gpt_paged" not in inspect.getsource(decode).replace(
+        decode.__doc__, "")

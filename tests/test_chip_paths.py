@@ -28,12 +28,12 @@ def _run(cmd, timeout=300, **env):
 
 # -- a failed accelerator is a traceback and a non-zero exit ----------------
 
-@pytest.mark.parametrize("script", [
-    ["bench.py"], ["benchmarks/serve_bench.py", "--requests", "4"]])
-def test_bench_backend_failure_exits_nonzero(script):
+def test_bench_backend_failure_exits_nonzero():
     """A backend that cannot initialise raises out of the benchmark: no
-    JSON line, no rc 0, no second life on the CPU."""
-    p = _run([sys.executable] + script, JAX_PLATFORMS="no_such_backend")
+    result line, no rc 0, no second life on the CPU."""
+    p = _run([sys.executable, "chipbench/run.py", "--workload",
+              "serve-gpt2-124m-chat", "--seed", "1", "--seconds", "1"],
+             JAX_PLATFORMS="no_such_backend")
     assert p.returncode != 0
     assert "no_such_backend" in p.stderr
     assert not p.stdout.strip(), p.stdout
@@ -151,7 +151,7 @@ def test_hbm_derived_slot_count_uses_compiled_footprint(monkeypatch):
     sizes itself so the largest compiled step fits the budget."""
     from paddle_tpu import profiler
     from paddle_tpu.core import monitor
-    from paddle_tpu.inference import decode
+    from paddle_tpu.inference import decode, model_kinds
     from paddle_tpu.models import GPT, gpt_tiny
 
     paddle.seed(0)
@@ -165,7 +165,8 @@ def test_hbm_derived_slot_count_uses_compiled_footprint(monkeypatch):
         sized = [e["label"] for e in profiler.compile_events()
                  if e["label"].startswith("decode.sizing:")]
         assert sized and sized[-1] == f"decode.sizing:{eng.max_slots}"
-        logical = (limit - used) // decode.kv_slot_bytes(eng.cfg)
+        logical = (limit - used) \
+            // model_kinds.for_model(model).slot_bytes()
         assert 1 <= eng.max_slots < logical      # padding + temporaries
     finally:
         eng.stop()
@@ -227,6 +228,29 @@ def test_router_process_never_initialises_a_backend():
     p = _run([sys.executable, "-c", code], JAX_PLATFORMS="cpu")
     assert p.returncode == 0, p.stderr[-2000:]
     assert "BACKEND False" in p.stdout
+
+
+# -- a trace leaves no tracer behind in the global generator ----------------
+
+def test_a_constructor_under_eval_shape_leaves_the_generator_usable():
+    """`jax.eval_shape(lambda: param_arrays(GPT(cfg)))` (the probe below,
+    the benchmark's `param_shapes`) draws initial weights under a trace.
+    The global generator comes out of it holding a concrete key, its
+    chain where the same draws made eagerly leave it: a traced key kept
+    there failed whichever test drew next in the worker."""
+    from paddle_tpu import framework
+    from paddle_tpu.core import random as rng
+    from paddle_tpu.models import GPT, gpt_tiny
+
+    paddle.seed(3)
+    jax.eval_shape(lambda: framework.param_arrays(GPT(gpt_tiny())))
+    traced_state, traced_next = rng.get_rng_state(), rng.next_key()
+    paddle.seed(3)
+    GPT(gpt_tiny())
+    assert rng.get_rng_state() == traced_state
+    np.testing.assert_array_equal(jax.random.key_data(rng.next_key()),
+                                  jax.random.key_data(traced_next))
+    assert paddle.create_parameter([2, 2], "float32").shape == [2, 2]
 
 
 # -- the gpt pools are used in place by the compiled step --------------------

@@ -204,13 +204,14 @@ def test_custom_decoder_generic_path():
 
 # -- continuous-batching KV-cache decode engine (inference/decode.py) ----
 #
-# Correctness gate: the incremental prefill/decode_step path must emit
+# Correctness gate: the incremental prefill / paged-step path must emit
 # logits identical (to fp32 rounding) to the full forward pass, on BOTH
 # parameter layouts a GPT can produce (scan-stacked and per-block
 # unrolled). Everything downstream (engine, serving, bench) rides on it.
 
 import time
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu.framework as framework
@@ -218,7 +219,7 @@ from paddle_tpu import profiler
 from paddle_tpu.inference.decode import DecodeEngine, save_for_decode
 from paddle_tpu.inference.errors import (ERR_INVALID_ARGUMENT,
                                          ERR_UNAVAILABLE, TypedServeError)
-from paddle_tpu.models.gpt import GPT, GPTConfig, gpt_decode_fns, gpt_tiny
+from paddle_tpu.models.gpt import GPT, GPTConfig, gpt_tiny
 from paddle_tpu.testing import chaos
 
 _DECODE_CFGS = [
@@ -254,11 +255,15 @@ def _ref_greedy(model, prompt, n, eos_id=None):
 
 @pytest.mark.parametrize("name", [n for n, _ in _DECODE_CFGS])
 def test_incremental_decode_matches_full_forward(gpt_models, name):
-    """prefill + N decode_steps == full forward, token for token AND
-    logit for logit, on both param layouts."""
+    """prefill-into-pages + N paged steps == full forward, token for
+    token AND logit for logit, on both param layouts."""
+    from paddle_tpu.inference import model_kinds
     model = gpt_models[name]
     cfg = model.cfg
-    prefill, step = gpt_decode_fns(cfg, eps=model.ln_f._epsilon)
+    kind = model_kinds.for_model(model)
+    pt = 8
+    prefill = jax.jit(kind.prefill_fn(pt))
+    step = jax.jit(kind.step_fn(pt))
     params = {k: jnp.asarray(v)
               for k, v in framework.param_arrays(model).items()}
 
@@ -267,17 +272,20 @@ def test_incremental_decode_matches_full_forward(gpt_models, name):
     toks = [int(t) for t in rng.randint(0, cfg.vocab_size, size=plen)]
     padded = np.zeros((1, cap), np.int32)
     padded[0, :plen] = toks
-    logits, k, v = prefill(params, jnp.asarray(padded),
-                           jnp.asarray([plen], np.int32))
+    W = cap // pt
+    pools = kind.pools_zeros(W + 1, pt, "float32")
+    tables = jnp.arange(1, W + 1, dtype=jnp.int32)[None]
+    logits, pools = prefill(params, pools, jnp.asarray(padded), tables,
+                            jnp.asarray([plen], np.int32))
     np.testing.assert_allclose(np.asarray(logits)[0],
                                _full_logits(model, toks), atol=1e-4)
     cache_len = plen
     last = int(np.asarray(logits)[0].argmax())
     for _ in range(steps):
         toks.append(last)
-        logits, k, v = step(params, k, v,
-                            jnp.asarray([last], np.int32),
-                            jnp.asarray([cache_len], np.int32))
+        logits, pools = step(params, pools, tables,
+                             jnp.asarray([last], np.int32),
+                             jnp.asarray([cache_len], np.int32))
         np.testing.assert_allclose(np.asarray(logits)[0],
                                    _full_logits(model, toks), atol=1e-4)
         cache_len += 1
@@ -445,45 +453,6 @@ def test_serve_decode_wire_roundtrip(gpt_models, tmp_path):
         assert srv._status()["engine"] == "decode"
     finally:
         srv.stop()
-
-
-def test_decode_attention_pallas_matches_reference():
-    """Kernel gate for the PADDLE_TPU_DECODE_KERNEL=pallas fast path:
-    max-abs-error vs the jnp composition, ragged lengths included."""
-    from paddle_tpu.ops.pallas.decode_attention import (
-        _decode_attention_pallas, decode_attention,
-        decode_attention_reference)
-    rng = np.random.RandomState(41)
-    B, cap, H, D = 3, 32, 4, 16
-    q = jnp.asarray(rng.randn(B, H, D).astype(np.float32))
-    k = jnp.asarray(rng.randn(B, cap, H, D).astype(np.float32))
-    v = jnp.asarray(rng.randn(B, cap, H, D).astype(np.float32))
-    lengths = jnp.asarray([1, 17, 32], np.int32)
-    want = decode_attention_reference(q, k, v, lengths)
-    got = _decode_attention_pallas(q, k, v, lengths)
-    err = float(jnp.max(jnp.abs(got - want)))
-    assert err < 1e-5, f"pallas decode attention max abs err {err}"
-    # dispatch: explicit kernel= and the env knob agree; junk rejected
-    np.testing.assert_array_equal(
-        np.asarray(decode_attention(q, k, v, lengths, kernel="pallas")),
-        np.asarray(got))
-    with pytest.raises(ValueError):
-        decode_attention(q, k, v, lengths, kernel="cuda")
-
-
-def test_decode_engine_on_pallas_kernel(gpt_models, monkeypatch):
-    """The whole engine, attention routed through the Pallas kernel via
-    the env knob, still matches the full-forward reference."""
-    model = gpt_models["tiny-scan"]
-    monkeypatch.setenv("PADDLE_TPU_DECODE_KERNEL", "pallas")
-    prompt = np.random.RandomState(13).randint(0, 512, size=6)
-    ref = _ref_greedy(model, prompt, 5)
-    eng = DecodeEngine(model, max_slots=1, max_new_tokens=8)
-    try:
-        assert eng.submit(prompt, max_new_tokens=5).result(timeout=180) \
-            == ref
-    finally:
-        eng.stop()
 
 
 def test_decode_request_error_after_partial(gpt_models, tmp_path):

@@ -171,17 +171,19 @@ _FUSED_CFG = GPTConfig(vocab_size=256, max_seq_len=30, hidden=32, layers=3,
     "plen", [1, _PT - 1, _PT, _PT + 1, 16, 29],
     ids=["one", "pt-1", "pt", "pt+1", "rung-last", "rung-not-page-multiple"])
 def test_fused_prefill_writes_what_the_three_hops_wrote(plen, kv_dtype):
-    """`gpt_paged_prefill_fns` against the path it replaced: the logits
-    are `gpt_decode_fns.prefill`'s, the request's pages hold
+    """`gpt_paged_fns`' prefill against the path it replaced: the logits
+    are `gpt_dense_prefill`'s, the request's pages hold
     `write_pages` of the zero-padded panel (quantized per (row, head)
     for an int8 pool) bit for bit, and every other page but the null
     page is untouched."""
+    import functools
+
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu import framework
     from paddle_tpu.inference.batching import next_bucket
-    from paddle_tpu.models.gpt import gpt_decode_fns, gpt_paged_prefill_fns
+    from paddle_tpu.models.gpt import gpt_dense_prefill, gpt_paged_fns
     from paddle_tpu.quant.kv import quantize_kv
 
     cfg, pt = _FUSED_CFG, _PT
@@ -217,12 +219,12 @@ def test_fused_prefill_writes_what_the_three_hops_wrote(plen, kv_dtype):
 
     k0, v0 = dirty_pool(1), dirty_pool(2)
     n = jnp.asarray([plen], jnp.int32)
-    fused = jax.jit(gpt_paged_prefill_fns(cfg, page_tokens=pt))
-    logits, k1, v1 = fused(params, k0, v0, jnp.asarray(toks),
-                           jnp.asarray(table), n)
+    fused = jax.jit(gpt_paged_fns(cfg, page_tokens=pt)[0])
+    logits, (k1, v1) = fused(params, (k0, v0), jnp.asarray(toks),
+                             jnp.asarray(table), n)
 
-    prefill, _ = gpt_decode_fns(cfg)
-    want_logits, k, v = jax.jit(prefill)(params, jnp.asarray(toks), n)
+    want_logits, k, v = jax.jit(functools.partial(gpt_dense_prefill, cfg))(
+        params, jnp.asarray(toks), n)
     np.testing.assert_array_equal(np.asarray(logits),
                                   np.asarray(want_logits))
     others = [p for p in range(1, P) if p not in pages]
@@ -289,7 +291,7 @@ def test_prefill_program_is_named_for_the_trace(gpt_models):
     `jit_paged_step`. Any other `name=` goes through the same way."""
     import jax
 
-    from paddle_tpu.models.gpt import gpt_paged_prefill_fns
+    from paddle_tpu.models.gpt import gpt_paged_fns
 
     eng = DecodeEngine(gpt_models["tiny-scan"], max_slots=2,
                        max_new_tokens=4, page_tokens=4)
@@ -301,8 +303,8 @@ def test_prefill_program_is_named_for_the_trace(gpt_models):
         assert eng._step_aot._jitted.__name__ == "paged_step"
     finally:
         eng.stop()
-    assert gpt_paged_prefill_fns(eng.cfg).__name__ == "paged_prefill"
-    draft = jax.jit(gpt_paged_prefill_fns(eng.cfg, name="draft_prefill"))
+    assert gpt_paged_fns(eng.cfg)[0].__name__ == "prefill"
+    draft = jax.jit(gpt_paged_fns(eng.cfg, prefill_name="draft_prefill")[0])
     assert draft.__name__ == "draft_prefill"
 
 
@@ -660,16 +662,171 @@ def test_trie_eviction_is_leaf_first_lru_in_one_pass(n_evict):
     assert alloc.stats()["pages_used"] == len(trie._entries)
 
 
+# ------------------- one serving block: the programs agree with each other
+
+def _programs_rig(kv_dtype, lens=(6, 9), W=5):
+    """A GPT, its four programs, and pools with two prompts of `lens`
+    tokens prefilled, a table of W pages a row:
+    (params, kind, fns, pools, tables, cache_len)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import framework
+    from paddle_tpu.inference import model_kinds
+    from paddle_tpu.inference.batching import next_bucket
+
+    cfg, pt = _FUSED_CFG, _PT
+    paddle.seed(5)
+    params = {k: jnp.asarray(v)
+              for k, v in framework.param_arrays(GPT(cfg)).items()}
+    kind = model_kinds.for_config(cfg)
+    fns = {"prefill": jax.jit(kind.prefill_fn(pt)),
+           "step": jax.jit(kind.step_fn(pt)),
+           "verify": jax.jit(kind.verify_fn(pt)),
+           "rollout": jax.jit(kind.rollout_fn(pt))}
+    pools = kind.pools_zeros(2 * W + 1, pt, kv_dtype)
+    tables = np.arange(1, 2 * W + 1, dtype=np.int32).reshape(2, W)
+    rs = np.random.RandomState(17)
+    ladder = kv_capacity_ladder(cfg.max_seq_len, floor=pt)
+    for b, n in enumerate(lens):
+        rung = next_bucket(n, ladder)
+        toks = np.zeros((1, rung), np.int32)
+        toks[0, :n] = rs.randint(0, cfg.vocab_size, size=n)
+        _, pools = fns["prefill"](params, pools, jnp.asarray(toks),
+                                  jnp.asarray(tables[b:b + 1, :-(-rung // pt)]),
+                                  jnp.asarray([n], jnp.int32))
+    return (params, kind, fns, pools, jnp.asarray(tables),
+            jnp.asarray(lens, jnp.int32))
+
+
+def _live_rows(pools):
+    """Every pool leaf as float32 rows, the null page left out (padding
+    and overruns write there)."""
+    from paddle_tpu.ops.pallas.decode_attention import dequantize_rows
+
+    def rows(layer):
+        if isinstance(layer, tuple):
+            layer = dequantize_rows(*layer)
+        return np.asarray(layer)[1:]
+
+    return [rows(layer) for pool in pools for layer in pool]
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+@pytest.mark.parametrize(
+    "lens,W,row0", [
+        ((6, 9), 5, [7]),                   # 20 of max_seq_len 30 positions
+        # row 0 runs past the cap, a new token at every step
+        ((27, 9), 8, [7, 19, 23, 29, 31])],
+    ids=["mid-sequence", "overrun"])
+def test_rollout_is_k_paged_steps(lens, W, row0, kv_dtype):
+    """`paged_rollout` is `fori_loop` over the step's own body: K steps
+    of it leave the tokens and the pools that K calls of `paged_step`
+    leave, forced (catch-up) tokens and chained argmaxes alike. A row
+    that runs past max_seq_len writes to the null page from there on
+    (the step has no such rule: it is given the null tables a padding
+    row carries), so its last live row keeps what position
+    max_seq_len - 1 wrote; its drafts past the cap are nobody's."""
+    import jax.numpy as jnp
+
+    params, kind, fns, pools, tables, cache_len = _programs_rig(
+        kv_dtype, lens, W)
+    K = 5                                   # crosses a page boundary
+    forced = np.full((2, K), -1, np.int32)
+    forced[0, :len(row0)] = row0
+    forced[1, :2] = [11, 13]                # row 1 catches up two tokens
+    drafts, rolled = fns["rollout"](params, pools, tables,
+                                    jnp.asarray(forced), cache_len)
+    stepped, prev = pools, None
+    for i in range(K):
+        tok = forced[:, i] if prev is None \
+            else np.where(forced[:, i] >= 0, forced[:, i], prev)
+        live = np.asarray(cache_len) + i < _FUSED_CFG.max_seq_len
+        logits, stepped = fns["step"](params, stepped,
+                                      jnp.where(live[:, None], tables, 0),
+                                      jnp.asarray(tok, jnp.int32),
+                                      cache_len + i)
+        prev = np.asarray(logits).argmax(-1).astype(np.int32)
+        np.testing.assert_array_equal(np.asarray(drafts)[live, i],
+                                      prev[live])
+    assert live.all() == (max(lens) + K <= _FUSED_CFG.max_seq_len)
+    # int8 rows may round a float's last bit to the next quantum
+    tol = 1e-5 if kv_dtype == "float32" else 2e-2
+    for got, ref in zip(_live_rows(rolled), _live_rows(stepped)):
+        np.testing.assert_allclose(got, ref, atol=tol, rtol=0)
+
+
+@pytest.mark.parametrize("kv_dtype,tol", [("float32", 1e-4), ("int8", 5e-2)])
+def test_verify_is_the_same_tokens_through_paged_steps(kv_dtype, tol):
+    """`paged_verify` over K1 tokens scores and writes what feeding the
+    same tokens through `paged_step` one by one scores and writes (an
+    int8 pool within its quantization: verify's window attends the
+    fresh rows before they are quantized)."""
+    import jax.numpy as jnp
+
+    params, kind, fns, pools, tables, cache_len = _programs_rig(kv_dtype)
+    K1 = 4
+    toks = np.random.RandomState(23).randint(0, kind.vocab_size,
+                                             size=(2, K1)).astype(np.int32)
+    logits, amax, verified = fns["verify"](params, pools, tables,
+                                           jnp.asarray(toks), cache_len)
+    np.testing.assert_array_equal(np.asarray(amax),
+                                  np.asarray(logits).argmax(-1))
+    stepped = pools
+    for i in range(K1):
+        want, stepped = fns["step"](params, stepped, tables,
+                                    jnp.asarray(toks[:, i]), cache_len + i)
+        np.testing.assert_allclose(np.asarray(logits)[:, i],
+                                   np.asarray(want), atol=tol, rtol=0)
+    for got, ref in zip(_live_rows(verified), _live_rows(stepped)):
+        np.testing.assert_allclose(got, ref, atol=tol, rtol=0)
+
+
+def test_moe_config_is_refused_once_by_the_builder():
+    """A GPT with expert blocks has no KV-decode path: the one builder
+    says so before any of the four programs exists, whichever of them
+    the kind was asked for."""
+    from paddle_tpu.inference import model_kinds
+    from paddle_tpu.models.gpt import gpt_paged_fns
+
+    cfg = GPTConfig(vocab_size=64, max_seq_len=16, hidden=16, layers=1,
+                    heads=2, moe_experts=2)
+    with pytest.raises(NotImplementedError, match="no KV-decode path"):
+        gpt_paged_fns(cfg)
+    kind = model_kinds.for_config(cfg)
+    for ask in (kind.prefill_fn, kind.step_fn, kind.verify_fn,
+                kind.rollout_fn):
+        with pytest.raises(NotImplementedError, match="gpt_paged_fns"):
+            ask(4)
+
+
+@pytest.mark.parametrize("cfg,want", [
+    (GPTConfig(vocab_size=50304, max_seq_len=1024, hidden=768, layers=12,
+               heads=12), 75_497_472),          # gpt2-124m: the chat cell
+    (gpt_tiny(), None)], ids=["gpt2-124m", "gpt-tiny"])
+def test_gpt_slot_bytes_is_a_full_sequence_of_pages(cfg, want):
+    """The slot probe starts from what one sequence's pages hold at
+    float32, said the paged way (as `AXK1Kind.slot_bytes` says it)."""
+    from paddle_tpu.inference import model_kinds
+
+    kind = model_kinds.for_config(cfg)
+    assert kind.slot_bytes() == kind.page_bytes(cfg.max_seq_len, "float32") \
+        == cfg.max_seq_len // 16 * kv_page_bytes(cfg, 16)
+    assert kind.slot_bytes() == cfg.layers * 2 * cfg.max_seq_len \
+        * cfg.heads * cfg.head_dim * 4
+    if want is not None:
+        assert kind.slot_bytes() == want
+
+
 # --------------------- attention over rows that hold every head whole
 
-@pytest.mark.parametrize("kernel", ["xla", "pallas"])
 @pytest.mark.parametrize("heads,dim", [(3, 8), (2, 64), (12, 64)],
                          ids=["3x8", "2x64", "12x64"])
-def test_paged_attention_matches_per_head_einsum(kernel, heads, dim):
+def test_paged_attention_matches_per_head_einsum(heads, dim):
     """A layer's pool keeps a token's heads side by side in one row
-    `[P, pt, heads * head_dim]`; both readers (the gather that keeps the
-    panel in that layout, and the opt-in Pallas kernel) give what the
-    per-head einsum over `[.., heads, head_dim]` gives."""
+    `[P, pt, heads * head_dim]`; the one reader of a page (the gather
+    that keeps the panel in that layout) gives what the per-head einsum
+    over `[.., heads, head_dim]` gives."""
     import jax
     import jax.numpy as jnp
 
@@ -685,7 +842,7 @@ def test_paged_attention_matches_per_head_einsum(kernel, heads, dim):
     got = paged_decode_attention(
         jnp.asarray(q), jnp.asarray(k.reshape(P, pt, heads * dim)),
         jnp.asarray(v.reshape(P, pt, heads * dim)), jnp.asarray(tables),
-        jnp.asarray(lengths), kernel=kernel)
+        jnp.asarray(lengths))
     kk = k[tables].reshape(B, W * pt, heads, dim)
     vv = v[tables].reshape(B, W * pt, heads, dim)
     s = np.einsum("bhd,bkhd->bhk", q, kk) / np.sqrt(dim)

@@ -28,9 +28,6 @@ from paddle_tpu.observability import (PROFILER, REGISTRY, RING,
 from paddle_tpu.observability.tracez import main as tracez_main
 from paddle_tpu.static import InputSpec
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks", "serve_bench.py")
-
 
 class SmallNet(nn.Layer):
     def __init__(self):
@@ -435,32 +432,6 @@ def test_span_ts_is_wall_anchored(tmp_path):
 
 
 # -- slow: end-to-end artifacts --------------------------------------------
-
-@pytest.mark.slow
-def test_serve_bench_decode_emits_trace_artifact():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    res = subprocess.run(
-        [sys.executable, BENCH, "--decode", "--decode-requests", "8",
-         "--decode-slots", "4", "--decode-tokens", "8"],
-        capture_output=True, text=True, timeout=600, env=env)
-    assert res.returncode == 0, res.stderr
-    out = json.loads(res.stdout.strip().splitlines()[-1])
-    assert out["metric"] == "decode_throughput"
-    assert "trace_file" in out and "profilez_top" in out
-    with open(out["trace_file"]) as f:
-        doc = json.load(f)                      # valid trace-event JSON
-    evs = doc["traceEvents"]
-    assert evs and all("ph" in e for e in evs)
-    assert all("ts" in e for e in evs if e["ph"] != "M")
-    names = {e["name"] for e in evs}
-    assert {"decode.step", "decode.sample"} <= names
-    top = out["profilez_top"]
-    assert top and len(top) <= 5
-    assert any(r["exe"].startswith("decode.") for r in top)
-    # every ranked row saw real work: a dispatch or at least a compile
-    assert all(r["calls"] > 0 or r["compiles"] > 0 for r in top)
-    assert any(r["calls"] > 0 for r in top)
-
 
 @pytest.mark.slow
 def test_merge_cli_over_router_and_two_backends(tmp_path):
