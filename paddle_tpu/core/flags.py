@@ -426,7 +426,7 @@ define_env_flag(
     "picks the most-underserved tenant by tokens_served/weight; unlisted "
     "tenants weigh 1.0.")
 define_env_flag(
-    "PADDLE_TPU_TRACEZ_CAPACITY", 524288,
+    "PADDLE_TPU_TRACEZ_CAPACITY", 1048576,
     "Event capacity of the per-process tracez ring (observability/"
     "tracez.py); oldest events are overwritten when full. 0 disables "
     "event recording entirely.")

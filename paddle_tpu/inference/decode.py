@@ -38,8 +38,15 @@ KV cache instead of per-slot contiguous panels:
   * pool exhaustion is typed RESOURCE_EXHAUSTED backpressure on the
     victim stream (after LRU-evicting cold prefix-cache pages), never
     an engine crash — batch-mates keep streaming;
-  * sampling is host-side numpy (greedy, or temperature with optional
-    top-k), so the device graph stays deterministic per shape.
+  * a greedy row's next token is picked on the device and stays there
+    (one int32 a slot, the next step's input), so **a tick runs ahead
+    of the host**: step k+1 is dispatched before step k's tokens are
+    read, and the push, EOS test, page release and the next tables are
+    made beside the device (`_step_once`); a row that ends by EOS is
+    found out one step late and that step's result for it dropped.
+    Sampling with temperature (optional top-k) is host-side numpy over
+    the pulled logits, and a tick that holds such a row reads before it
+    dispatches; the device graph stays deterministic per shape.
 
 Streams: `submit()` returns a `DecodeStream`; tokens are pushed as they
 are sampled (serve.py forwards them as incremental PDI2 frames), and a
@@ -150,6 +157,10 @@ def _decode_metrics():
             "steps": counter(
                 "paddle_tpu_decode_steps_total",
                 "Batched decode steps executed (one per token column)"),
+            "ahead_steps": counter(
+                "paddle_tpu_decode_ahead_steps_total",
+                "Decode steps dispatched before the tokens of the step "
+                "ahead of them were read on the host"),
             "prefills": counter(
                 "paddle_tpu_decode_prefills_total",
                 "Fused prefill-into-pages dispatches: one per miss "
@@ -169,10 +180,12 @@ def _decode_metrics():
                 "paddle_tpu_decode_prefill_latency_seconds",
                 "Fused prefill-into-pages latency per dispatch: prefill "
                 "plus the K/V page write until the pools are ready, "
-                "plus the first token's logits pull on an admission"),
+                "until the first token is read on an admission"),
             "step_latency": histogram(
                 "paddle_tpu_decode_step_latency_seconds",
-                "Batched decode-step execution latency"),
+                "Batched decode-step latency, dispatch until its tokens "
+                "are on the device (or, of a tick that samples on the "
+                "host, until its logits are pulled)"),
             "ttft": histogram(
                 "paddle_tpu_decode_ttft_seconds",
                 "Submit-to-first-token latency per request"),
@@ -362,6 +375,35 @@ def greedy_picks(logits):
     """Every row's first best id, [B] int32: what `np.argmax` reads
     from the pulled rows, computed where the logits are."""
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+# The last token of every slot stays on the device between steps, one
+# int32 a slot. A step's packed rows [3, B] say, per row, whose slot it
+# is (the slot count for a padding row: no such entry), the input token
+# where the host knows it (-1: the slot's entry), and the cache length.
+
+def step_inputs(last, rows):
+    """(last_tok [B], cache_len [B]) of a step: the host's token for
+    the rows that have one, the slot's entry of `last` for the rest."""
+    slot, tok, clen = rows[0], rows[1], rows[2]
+    return jnp.where(tok >= 0, tok, last.at[slot].get(mode="clip")), clen
+
+
+def store_picks(logits, last, rows):
+    """`last` with every row's greedy pick in its slot's entry."""
+    return last.at[rows[0]].set(greedy_picks(logits), mode="drop")
+
+
+def store_first(logits, last, slot):
+    """`last` with a prefill's greedy pick ([1, V] logits) at `slot`."""
+    return last.at[slot].set(greedy_picks(logits)[0])
+
+
+def _launch(exe, *args):
+    """Call `exe` without waiting for its outputs where it can be
+    (`AotCache` hands out executables with a `dispatch`); anything else
+    that stands in an executable's place is plainly called."""
+    return getattr(exe, "dispatch", exe)(*args)
 
 
 def _trie_owner(digest: bytes) -> tuple:
@@ -584,7 +626,7 @@ class _Req:
                  "eos_id", "seed", "stream", "cache_len", "last_tok",
                  "generated", "pages", "input_tail", "feeding",
                  "t_submit", "t_admit", "prefill_s", "tenant", "priority",
-                 "preempts", "deferred")
+                 "preempts", "deferred", "slot", "pending", "closing")
 
     def __init__(self, prompt, max_new, temperature, top_k, eos_id,
                  seed=None, tenant=DEFAULT_TENANT, priority=0):
@@ -609,6 +651,37 @@ class _Req:
         self.priority = priority         # higher wins; may preempt lower
         self.preempts = 0                # times evicted to host
         self.deferred = False            # quota deferral counted once
+        self.slot: Optional[int] = None  # its entry of the last tokens
+        self.pending = 0                 # tokens on the device, unread
+        self.closing = False             # its last token is dispatched
+
+
+class _Batch:
+    """The next step's inputs as the host prepares them, from counts:
+    the requests in row order, the block tables [b_rung, w_rung] and
+    the packed rows [3, b_rung] of `step_inputs`, both numpy until the
+    step is dispatched (a request admitted meanwhile takes a row)."""
+    __slots__ = ("reqs", "b_rung", "w_rung", "tables", "rows")
+
+    def __init__(self, reqs, b_rung, w_rung, tables, rows):
+        self.reqs = reqs
+        self.b_rung = b_rung
+        self.w_rung = w_rung
+        self.tables = tables
+        self.rows = rows
+
+
+class _Flight:
+    """A dispatched step whose tokens the host has not read: its rows
+    as (request, whether the row's output is a token of the request: a
+    row that feeds a prompt tail has none), the logits where the host
+    is to sample from them, and when it was dispatched."""
+    __slots__ = ("rows", "logits", "t0")
+
+    def __init__(self, rows, logits, t0):
+        self.rows = rows
+        self.logits = logits
+        self.t0 = t0
 
 
 class _SpecReq(_Req):
@@ -1010,9 +1083,13 @@ class DecodeEngine:
         self._copy_aot = AotCache(
             jax.jit(kind.copy_page, donate_argnums=(0,)), "decode.pcow",
             donate_argnums=(0,))
-        # a tick whose rows are all greedy pulls [B] ids, not [B, V]
-        # logits (17 MB at 84 rows of a 50k vocabulary, every tick)
-        self._pick_aot = AotCache(jax.jit(greedy_picks), "decode.ppick")
+        # the slots' last tokens stay on the device (`step_inputs`):
+        # a greedy tick stores its picks there and the host pulls one
+        # id a slot, not [B, V] logits (17 MB at 84 rows of a 50k
+        # vocabulary, every tick), after the next step is dispatched
+        self._tok_aot = AotCache(jax.jit(step_inputs), "decode.ptok")
+        self._pick_aot = AotCache(jax.jit(store_picks), "decode.ppick")
+        self._first_aot = AotCache(jax.jit(store_first), "decode.pfirst")
         # host-tier / handoff executables: `pgather` snapshots pages
         # into an independent buffer (pools NOT donated — the engine
         # keeps stepping on them), `ptier` scatters rows back in. The
@@ -1054,6 +1131,19 @@ class DecodeEngine:
             _flags.env_value("PADDLE_TPU_DECODE_PREEMPT")) \
             if preempt is None else bool(preempt)
         self._pool_tree = None       # the kind's pools pytree, lazy
+        # run-ahead state, the scheduler thread's alone: the slots'
+        # last tokens on the device (lazy with the pools) and each
+        # slot's index as a device scalar, the slots no request holds,
+        # the step in flight, the admissions whose first token the
+        # device picked and the host has not read, as (request, when
+        # its prefill was dispatched), and the next step as prepared
+        self._last = None
+        self._slot_ids: List = []
+        self._free_slots = list(range(self.max_slots))[::-1]
+        self._flight: Optional[_Flight] = None
+        self._firsts: List = []
+        self._batch: Optional[_Batch] = None
+        self._ahead_steps = 0
         self._routed_seen = None     # routed counters last exported
         # host tier (lazy with the pools): arena store + migration
         # worker + requests parked on an in-flight refetch
@@ -1175,6 +1265,9 @@ class DecodeEngine:
         if self._pool_tree is None:
             self._pool_tree = self._kind.pools_zeros(
                 self.num_pages, self.page_tokens, self.kv_dtype)
+            self._last = jnp.zeros((self.max_slots,), jnp.int32)
+            self._slot_ids = [jnp.asarray(i, jnp.int32)
+                              for i in range(self.max_slots)]
         if self.host_pages and self._migrate is None:
             self._store = HostPageStore(self._pools_sds(), self.host_pages)
             self._migrate = MigrationEngine(
@@ -1202,11 +1295,13 @@ class DecodeEngine:
             jax.ShapeDtypeStruct((1,), i32),
             key=("prefill", 1, rung))
 
-    def _prefill_into_pages(self, aot, params, pools, toks, pages):
+    def _prefill_into_pages(self, aot, params, pools, toks, pages,
+                            wait: bool = True):
         """One dispatch: `toks` prefilled at their kv rung and their
         cache rows written into `pages` of the (donated) pools; table
         padding aims at the null page. Returns (logits [1, V], pools),
-        all on the device."""
+        all on the device: ready, or with `wait` off as soon as the
+        program is enqueued."""
         plen = len(toks)
         rung = next_bucket(plen, self.kv_ladder)
         inp = np.zeros((1, rung), np.int32)
@@ -1214,19 +1309,39 @@ class DecodeEngine:
         table = np.zeros((1, -(-rung // self.page_tokens)), np.int32)
         table[0, :len(pages)] = pages
         exe = self._prefill_exe(aot, params, pools, rung)
-        return exe(params, pools, jnp.asarray(inp),
-                   jnp.asarray(table), jnp.asarray([plen], np.int32))
+        # numpy arrays go up as they are; a list would cost a program
+        # to cast it (`convert_element_type`) every admission
+        args = (params, pools, jnp.asarray(inp), jnp.asarray(table),
+                jnp.asarray(np.asarray([plen], np.int32)))
+        return exe(*args) if wait else _launch(exe, *args)
 
-    def _pick_exe(self, b_rung):
-        return self._pick_aot.get_or_compile(
-            jax.ShapeDtypeStruct((b_rung, self._kind.vocab_size),
-                                 jnp.float32), key=("ppick", b_rung))
+    def _token_exes(self, b_rung):
+        """The two tiny programs around a step of `b_rung` rows:
+        `step_inputs` before it, `store_picks` after it."""
+        i32 = jnp.int32
+        last = jax.ShapeDtypeStruct((self.max_slots,), i32)
+        rows = jax.ShapeDtypeStruct((3, b_rung), i32)
+        logits = jax.ShapeDtypeStruct((b_rung, self._kind.vocab_size),
+                                      jnp.float32)
+        return (self._tok_aot.get_or_compile(last, rows,
+                                             key=("ptok", b_rung)),
+                self._pick_aot.get_or_compile(logits, last, rows,
+                                              key=("ppick", b_rung)))
+
+    def _first_exe(self):
+        i32 = jnp.int32
+        return self._first_aot.get_or_compile(
+            jax.ShapeDtypeStruct((1, self._kind.vocab_size), jnp.float32),
+            jax.ShapeDtypeStruct((self.max_slots,), i32),
+            jax.ShapeDtypeStruct((), i32), key=("pfirst",))
 
     def warmup(self, verbose: bool = False) -> int:
         """AOT-compile the fused prefill-into-pages prompt rungs, the
-        copy-on-write executable, the greedy pick, and the decode
-        (batch-rung x page-rung) cross product (capped, largest rungs
-        first dropped last). Returns the number of fresh compiles."""
+        copy-on-write executable, the three token programs (a step's
+        inputs and its picks per batch rung, a prefill's pick), and the
+        decode (batch-rung x page-rung) cross product (capped, largest
+        rungs first dropped last). Returns the number of fresh
+        compiles."""
         before = len(profiler.compile_events())
         i32 = jnp.int32
         pool = self._model_pools_sds()
@@ -1263,7 +1378,8 @@ class DecodeEngine:
                 jax.ShapeDtypeStruct((b,), i32),
                 key=("pstep", b, w))
         for b in self.batch_ladder:
-            self._pick_exe(b)
+            self._token_exes(b)
+        self._first_exe()
         n = len(profiler.compile_events()) - before
         if verbose:
             print(f"DECODE WARMUP compiles={n} "
@@ -1279,6 +1395,7 @@ class DecodeEngine:
             "paused": len(self._paused),
             "max_slots": self.max_slots,
             "steps": self._steps,
+            "ahead_steps": self._ahead_steps,
             "tokens": self._tokens,
             # rung of the most recent dispatch; the smallest formable
             # rung before the first one (never a bogus 0)
@@ -1384,77 +1501,53 @@ class DecodeEngine:
             with _RING.span("decode.loop", {"admits": 0, "active": 0}) as it:
                 if not self._loop_once(it.args):
                     it.drop()            # the stop is no iteration
-                    return
+                    break
+        # stop() fails every open stream: what the device still runs
+        # for them is waited for and not read
+        self._drain(read=False)
 
     def _loop_once(self, counts: dict) -> bool:
         """One scheduler iteration: wait for work, schedule, admit, step.
         False once the engine is stopped. `counts` is the `decode.loop`
         span's args. Every phase is a ring span on this thread
         (docs/observability.md lists them), so an iteration is tiled:
-        what no span covers is the loop's own overhead."""
-        newly, victims = [], []
-        with _RING.span("decode.schedule", {}) as sched:
-            with self._cond:
-                while (not self._stop and not self._pending
-                       and not self._paused and not self._active
-                       and not self._migrating and not self._handoff_q):
-                    with _RING.span("decode.idle"):
-                        self._cond.wait(timeout=0.1)
-                if self._stop:
-                    sched.drop()
-                    return False
-                sched.args["pending"] = len(self._pending)
-                sched.args["paused"] = len(self._paused)
-                self._refill_quota()
-                newly, victims = self._schedule()
-                self._admitting = list(newly) + list(victims)
-                if not newly and not victims and not self._active \
-                        and not self._handoff_q:
-                    # everything queued is quota-blocked (or parked on
-                    # an in-flight refetch): wait for the bucket refill
-                    # / migration wake instead of spinning
-                    with _RING.span("decode.idle"):
-                        self._cond.wait(timeout=0.02)
+        what no span covers is the loop's own overhead.
+
+        A tick (`_step_once`) leaves its step on the device, its tokens
+        unread. The iteration that follows admits what is pending
+        beside it (each prefill dispatched, not awaited: it runs as
+        soon as the step ends), waits until the device is through
+        (`decode.step.wait`), admits whoever arrived meanwhile, and
+        dispatches the next step: between "ready" and that dispatch
+        the host does nothing but look at the queue, admit and
+        dispatch, so no step is queued ahead of a late admission."""
+        round_ = self._schedule_round(idle=True)
+        if round_ is None:
+            return False
         try:
-            if self._handoff_q:
-                self._handoff_drain()
-            if self._migrating:
-                self._tier_poll()
-            for vic in victims:
-                self._preempt(vic)
-            for req in newly:
-                if len(self._active) >= self.max_slots:
-                    # a preemption was abandoned (chaos) and its
-                    # candidate has no slot: requeue at the front
-                    with self._cond:
-                        if req.preempts:
-                            self._paused.appendleft(req)
-                        else:
-                            self._pending.appendleft(req)
-                    continue
-                with _RING.span("decode.admit", {"req": req.id}) as adm:
-                    admitted = self._admit(req, adm.args)
-                    adm.args["ok"] = admitted
-                counts["admits"] += 1
-                if admitted:
-                    self._active.append(req)
-                    self._m["tenant_admissions"].labels(
-                        tenant=req.tenant).inc()
-                    if req.preempts:
-                        self._m["preempt_resumes"].inc()
-            if self._admitting:
-                with self._cond:
-                    self._admitting = []
-            if newly or victims:
-                self._update_gauges()
+            self._admit_round(round_, counts)
+            if self._flight is not None:
+                with _RING.span("decode.step.wait"):
+                    # the interpreter lock is free here: callers submit
+                    jax.block_until_ready(self._last)
+                self._m["step_latency"].observe(
+                    time.perf_counter() - self._flight.t0)
+                if self._pending or self._paused:
+                    round_ = self._schedule_round(idle=False)
+                    if round_ is None:
+                        return False
+                    self._admit_round(round_, counts)
             counts["active"] = len(self._active)
             if self._active:
                 self._step_once()
+            else:
+                self._drain()    # rows that failed left a step behind
         except Exception as exc:  # engine-level failure: fail the
             # batch (typed), free its pages, keep serving newcomers
             err = exc if isinstance(exc, TypedServeError) else \
                 TypedServeError(ERR_UNAVAILABLE,
                                 f"decode scheduler failure: {exc}")
+            self._drain(read=False)
             for req in self._active:
                 req.stream._push_error(err)
                 self._m["evictions"].labels(reason="error").inc()
@@ -1462,6 +1555,73 @@ class DecodeEngine:
             self._active = []
             self._update_gauges()
         return True
+
+    def _schedule_round(self, idle: bool):
+        """Look at the queue under the scheduler lock (`decode.schedule`):
+        (admissions, preemption victims), or None once the engine is
+        stopped. With `idle` it first waits for there to be work at
+        all; the round after a step's wait never waits."""
+        with _RING.span("decode.schedule", {}) as sched:
+            with self._cond:
+                while (idle and not self._stop and not self._pending
+                       and not self._paused and not self._active
+                       and not self._migrating and not self._handoff_q):
+                    with _RING.span("decode.idle"):
+                        self._cond.wait(timeout=0.1)
+                if self._stop:
+                    sched.drop()
+                    return None
+                sched.args["pending"] = len(self._pending)
+                sched.args["paused"] = len(self._paused)
+                self._refill_quota()
+                newly, victims = self._schedule()
+                self._admitting = list(newly) + list(victims)
+                if idle and not newly and not victims \
+                        and not self._active and not self._handoff_q:
+                    # everything queued is quota-blocked (or parked on
+                    # an in-flight refetch): wait for the bucket refill
+                    # / migration wake instead of spinning
+                    with _RING.span("decode.idle"):
+                        self._cond.wait(timeout=0.02)
+        return newly, victims
+
+    def _admit_round(self, round_, counts: dict):
+        """Act on what `_schedule_round` picked: parked handoff jobs and
+        landed refetches, the preemptions, then the admissions."""
+        newly, victims = round_
+        if self._handoff_q:
+            self._handoff_drain()
+        if self._migrating:
+            self._tier_poll()
+        for vic in victims:
+            self._preempt(vic)
+        for req in newly:
+            if len(self._active) >= self.max_slots:
+                # a preemption was abandoned (chaos) and its
+                # candidate has no slot: requeue at the front
+                with self._cond:
+                    if req.preempts:
+                        self._paused.appendleft(req)
+                    else:
+                        self._pending.appendleft(req)
+                continue
+            with _RING.span("decode.admit", {"req": req.id}) as adm:
+                admitted = self._admit(req, adm.args)
+                adm.args["ok"] = admitted
+                if admitted:
+                    self._active.append(req)
+                    self._join_batch(req)
+            counts["admits"] += 1
+            if admitted:
+                self._m["tenant_admissions"].labels(
+                    tenant=req.tenant).inc()
+                if req.preempts:
+                    self._m["preempt_resumes"].inc()
+        if self._admitting:
+            with self._cond:
+                self._admitting = []
+        if newly or victims:
+            self._update_gauges()
 
     # ------------------------------------------------- QoS scheduling
 
@@ -1536,6 +1696,11 @@ class DecodeEngine:
             chaos.maybe_fail("decode.preempt", detail=req.id)
         except Exception:
             return False
+        # a resume replays prompt + generated: every token of the
+        # victim comes home first (and may turn out to be its last)
+        self._drain()
+        if req.slot is None:
+            return False
         self._preempt_stash(req)
         self._release_pages(req)
         req.cache_len = 0
@@ -1546,6 +1711,7 @@ class DecodeEngine:
         self._m["preemptions"].inc()
         self._m["preempted_tokens"].inc(len(req.generated))
         self._active = [r for r in self._active if r.id != req.id]
+        self._batch = None
         with self._cond:
             self._paused.append(req)
         return True
@@ -1626,7 +1792,13 @@ class DecodeEngine:
 
     def _release_pages(self, req: _Req):
         """Drop the slot's reference on every page it maps (exactly one
-        ref per block-table entry). Idempotent via the list reset."""
+        ref per block-table entry), and its slot. Idempotent via the
+        resets. A step in flight may still write the request's row:
+        whoever takes the pages or the slot next writes them later on
+        the device, and reads no row it has not written."""
+        if req.slot is not None:
+            self._free_slots.append(req.slot)
+            req.slot = None
         owner = self._owner_for(req)
         pages, req.pages = req.pages, []
         for p in pages:
@@ -1814,6 +1986,7 @@ class DecodeEngine:
         trie back at them; the request's next admission then sees a
         full device hit. False on allocation pressure — the entries
         drop and the request re-prefills instead."""
+        self._drain()
         try:
             # the tier (not the parked slot) owns these pages until
             # restore_entry retags each one to its trie node
@@ -1890,7 +2063,9 @@ class DecodeEngine:
     def _handoff_drain(self):
         """Run parked handoff jobs (scheduler thread, outside `_cond`).
         A job's failure goes back through its reply box — it must never
-        poison the active batch the way a step failure does."""
+        poison the active batch the way a step failure does. The step
+        in flight comes home first: a job's calls wait for the device."""
+        self._drain()
         while True:
             with self._cond:
                 if not self._handoff_q:
@@ -2119,14 +2294,16 @@ class DecodeEngine:
     # ------------------------------------------------------- admission
 
     def _admit(self, req: _Req, note: dict) -> bool:
-        """Give the request KV pages and a first token source. `note`
-        is the `decode.admit` span's args, filled as they are learnt.
+        """Give the request KV pages, a slot and a first token source.
+        `note` is the `decode.admit` span's args, filled as they are
+        learnt.
 
         Prefix hit: map the cached pages (refcount++), queue the
         uncached prompt tail to be fed through the batched decode step
         — no prefill, no device work here at all. Miss: fresh pages,
         one fused B=1 prefill-into-pages dispatch at the prompt rung,
-        the first sampled token delivered immediately. True if the
+        the first token picked where the logits are and read with the
+        tokens of the step in flight (`_admit_prefill`). True if the
         request now occupies a decode slot.
 
         A preempted request resumes through this same path over
@@ -2137,7 +2314,6 @@ class DecodeEngine:
         resumed stream is token-identical to an unpreempted run."""
         toks = req.prompt + req.generated
         plen = len(toks)
-        pt = self.page_tokens
         self._ensure_pool()
         req.t_admit = time.monotonic()
         note["plen"] = plen
@@ -2149,6 +2325,7 @@ class DecodeEngine:
         note["hit_tokens"] = usable
         if usable:
             req.pages = hit_pages
+            req.slot = self._free_slots.pop()
             req.cache_len = usable
             req.last_tok = toks[usable]
             req.input_tail = deque(toks[usable + 1:])
@@ -2198,9 +2375,14 @@ class DecodeEngine:
                        note: dict) -> bool:
         """The miss path of `_admit`: fresh pages, then ONE dispatch
         that prefills at the prompt's kv rung and writes the K/V panel
-        into those pages where it was computed, then the first token.
-        Each phase is a `decode.admit.*` span (the dispatch is
-        `exec:decode.prefill`)."""
+        into those pages where it was computed, not awaited; its
+        greedy pick goes into the slot's entry of the last tokens
+        (`exec:decode.pfirst`) and the host reads it with the tokens of
+        the step in flight, once the next step is on the device. A
+        request that samples needs its logits on the host: that
+        admission waits, pulls the [1, V] row and emits here
+        (`decode.admit.logits_pull`, `decode.admit.emit`), as an engine
+        that does not run ahead does for every request."""
         plen = len(toks)
         note["rung"] = next_bucket(plen, self.kv_ladder)
         n_pages = -(-plen // self.page_tokens)
@@ -2211,120 +2393,248 @@ class DecodeEngine:
                 req.stream._push_error(err)
                 self._m["evictions"].labels(reason="exhausted").inc()
                 return False
+        req.slot = self._free_slots.pop()
+        ahead = self._run_ahead and req.temperature <= 0.0
         t0 = time.perf_counter()
         logits, self._pool_tree = self._prefill_into_pages(
             self._prefill_aot, self.params, self._pool_tree, toks,
-            req.pages)
-        with _RING.span("decode.admit.logits_pull"):
-            row = np.asarray(logits)[0]
-        req.prefill_s = time.perf_counter() - t0
+            req.pages, wait=not ahead)
         self._m["prefills"].inc()
-        self._m["prefill_latency"].observe(req.prefill_s)
-        with _RING.span("decode.admit.emit"):
-            return self._admit_emit(req, toks, row)
-
-    def _admit_emit(self, req: _Req, toks: List[int],
-                    row: np.ndarray) -> bool:
-        """Sample and push the first token of a prefilled request, and
-        seed the prefix cache with its prompt pages."""
-        plen = len(toks)
-        if not req.generated:        # resumes already saw first-token
-            self._m["ttft"].observe(time.monotonic() - req.t_submit)
-        try:
-            chaos.maybe_fail("decode.stream", detail=req.id)
-            tok = self._sample(row, req)
-        except Exception as exc:
-            req.stream._push_error(TypedServeError(
-                ERR_UNAVAILABLE, f"decode stream killed: {exc}"))
-            self._m["evictions"].labels(reason="error").inc()
-            self._release_pages(req)
-            return False
         req.cache_len = plen
-        req.last_tok = tok
-        req.generated.append(tok)
-        self._tokens += 1
-        self._m["tokens"].inc()
-        self._note_token(req)
+        self._expect(req)
+        # the pages hold the prompt for whatever is dispatched from here
         if self._prefix is not None:
             self._prefix.insert(
                 toks, req.pages[:plen // self.page_tokens])
-        eos = req.eos_id is not None and tok == req.eos_id
-        req.stream._push_token(tok, eos)
-        _RING.instant("decode.emit", {"req": req.id})
-        if eos or len(req.generated) >= req.max_new \
-                or req.cache_len >= self._kind.max_seq_len:
-            self._finish(req, "eos" if eos else "length")
-            self._release_pages(req)
-            return False
-        return True
+        if ahead:
+            self._last = _launch(self._first_exe(), logits, self._last,
+                                 self._slot_ids[req.slot])
+            self._firsts.append((req, t0))
+            return True
+        with _RING.span("decode.admit.logits_pull"):
+            row = np.asarray(logits)[0]
+        self._note_prefill(req, t0)
+        with _RING.span("decode.admit.emit"):
+            return not self._deliver(req, row)
+
+    def _note_prefill(self, req: _Req, t0: float):
+        req.prefill_s = time.perf_counter() - t0
+        self._m["prefill_latency"].observe(req.prefill_s)
 
     # ------------------------------------------------------------ step
+    #
+    # One algorithm, two orders. A step is dispatched from counts
+    # (pages, cache lengths, who leaves at `max_new`) and from the last
+    # tokens where they are: on the device for a greedy row, on the host
+    # for a row that feeds a prompt tail or whose token the host
+    # sampled. Running ahead, a tick dispatches step k+1, then reads
+    # step k's tokens, then prepares step k+2; the loop admits beside
+    # step k+1 and waits for it (`_loop_once`). A tick that holds a
+    # sampling row (or `_run_ahead` off) reads each step before it
+    # dispatches the next, as the host's sampler needs.
+
+    _run_ahead = True     # the speculative engine reads every tick
 
     def _step_once(self):
         """One tick: every active slot advances one position. The tick
-        is a `decode.step` ring span tiled by `decode.step.provision`,
-        `decode.step.build`, `exec:decode.pstep`, `decode.step.pull`
-        and `decode.sample`; a tick that dispatches nothing writes no
-        `decode.step`."""
+        is a `decode.step` ring span; running ahead it is tiled by the
+        dispatch (`decode.step.build`, the uploads, and
+        `exec:decode.ptok`, `exec:decode.pstep`, `exec:decode.ppick`,
+        each the dispatch call alone, `decode.step.advance`, the rows'
+        counts), `decode.step.pull` and
+        `decode.sample` for the step BEFORE, and
+        `decode.step.provision` and `decode.step.build` for the step
+        AFTER; the step it dispatched is still running when it ends. A
+        tick that dispatches nothing writes no `decode.step`."""
+        sampling = any(r.temperature > 0.0 for r in self._active)
+        ahead = self._run_ahead and not sampling
+        if not ahead:
+            self._drain()
         with _RING.span("decode.step", {}) as tick:
-            with _RING.span("decode.step.provision", {}) as prov:
-                prov.args["new_pages"] = self._provision_rows()
-            reqs = self._active
-            if not reqs:
+            batch = self._batch or self._build_batch()
+            self._batch = None
+            if not batch.reqs:           # every slot awaits its last token
                 tick.drop()
+                self._drain()
                 return
-            with _RING.span("decode.step.build"):
-                b_rung = next_bucket(len(reqs), self.batch_ladder)
-                w_rung = next_bucket(max(len(r.pages) for r in reqs),
-                                     self.page_ladder)
-                tables = np.zeros((b_rung, w_rung), np.int32)  # pad -> null
-                ltok = np.zeros(b_rung, np.int32)
-                clen = np.zeros(b_rung, np.int32)
-                for j, req in enumerate(reqs):
-                    tables[j, :len(req.pages)] = req.pages
-                    ltok[j] = req.last_tok
-                    clen[j] = req.cache_len
-                exe = self._step_aot.get_or_compile(
-                    self.params, self._pool_tree,
-                    jax.ShapeDtypeStruct((b_rung, w_rung), jnp.int32),
-                    jax.ShapeDtypeStruct((b_rung,), jnp.int32),
-                    jax.ShapeDtypeStruct((b_rung,), jnp.int32),
-                    key=("pstep", b_rung, w_rung))
-                tables, ltok, clen = (jnp.asarray(tables),
-                                      jnp.asarray(ltok), jnp.asarray(clen))
-            t0 = time.perf_counter()
-            logits, self._pool_tree = exe(
-                self.params, self._pool_tree, tables, ltok, clen)
-            with _RING.span("decode.step.pull", {}) as pull:
-                # greedy rows need their best id and nothing else of
-                # the logits: the device picks it and [B] ids cross;
-                # one sampling row and the tick pulls the logits
-                if all(r.temperature <= 0.0 for r in reqs):
-                    rows = np.asarray(self._pick_exe(b_rung)(logits))
-                else:
-                    rows = np.asarray(logits)
-                pull.args["bytes"] = rows.nbytes
-            self._m["step_latency"].observe(time.perf_counter() - t0)
-            self._last_b_rung, self._last_w_rung = b_rung, w_rung
-            self._steps += 1
-            self._m["steps"].inc()
-            with _RING.span("decode.sample", {"reqs": len(reqs)}):
-                finished = self._sample_rows(reqs, rows)
-            tick.args.update(batch=len(reqs), b_rung=b_rung, w_rung=w_rung)
-        if finished:
-            done = {r.id for r in finished}
-            self._active = [r for r in reqs if r.id not in done]
+            unread = self._flight, self._firsts, self._last
+            self._dispatch(batch, sampling)
+            t0 = self._flight.t0
+            tick.args.update(batch=len(batch.reqs), b_rung=batch.b_rung,
+                             w_rung=batch.w_rung,
+                             ahead=unread[0] is not None)
+            if unread[0] is not None:
+                self._ahead_steps += 1
+                self._m["ahead_steps"].inc()
+            if ahead:
+                self._read(*unread)
+                self._batch = self._build_batch()
+            else:
+                self._drain()
+                self._m["step_latency"].observe(time.perf_counter() - t0)
+
+    def _build_batch(self) -> _Batch:
+        """The next step from counts: a write target for every row
+        (`_provision_rows`), then tables and packed rows."""
+        with _RING.span("decode.step.provision", {}) as prov:
+            prov.args["new_pages"] = self._provision_rows(self._active)
+        reqs = [r for r in self._active if not r.closing]
+        if not reqs:
+            return _Batch(reqs, 0, 0, None, None)
+        with _RING.span("decode.step.build"):
+            b_rung = next_bucket(len(reqs), self.batch_ladder)
+            w_rung = next_bucket(max(len(r.pages) for r in reqs),
+                                 self.page_ladder)
+            tables = np.zeros((b_rung, w_rung), np.int32)   # pad -> null
+            rows = np.zeros((3, b_rung), np.int32)
+            rows[0] = self.max_slots        # a padding row has no slot
+            batch = _Batch([], b_rung, w_rung, tables, rows)
+            for req in reqs:
+                self._fill_row(batch, req)
+        return batch
+
+    @staticmethod
+    def _fill_row(batch: _Batch, req: _Req):
+        j = len(batch.reqs)
+        batch.tables[j, :len(req.pages)] = req.pages
+        batch.rows[:, j] = req.slot, req.last_tok, req.cache_len
+        batch.reqs.append(req)
+
+    def _join_batch(self, req: _Req):
+        """A request admitted after the next step was prepared takes a
+        row of it where the rungs hold it; else the step is built anew."""
+        batch = self._batch
+        if batch is None or req.closing:
+            return
+        self._provision_rows([req])
+        if req.slot is None:
+            return                       # the pool had no page for it
+        if len(batch.reqs) < batch.b_rung and len(req.pages) <= batch.w_rung:
+            self._fill_row(batch, req)
+        else:
+            self._batch = None
+
+    def _dispatch(self, batch: _Batch, sampling: bool):
+        """Put `batch` on the device: the uploads, the step's inputs
+        from the last tokens, the step and, unless the host is to
+        sample from the logits, its picks into the last tokens. The
+        rows' counts advance here; the tokens are read later (`_read`)."""
+        b_rung, w_rung = batch.b_rung, batch.w_rung
+        with _RING.span("decode.step.build"):
+            exe = self._step_aot.get_or_compile(
+                self.params, self._pool_tree,
+                jax.ShapeDtypeStruct((b_rung, w_rung), jnp.int32),
+                jax.ShapeDtypeStruct((b_rung,), jnp.int32),
+                jax.ShapeDtypeStruct((b_rung,), jnp.int32),
+                key=("pstep", b_rung, w_rung))
+            inputs, picks = self._token_exes(b_rung)
+            tables, rows = jnp.asarray(batch.tables), jnp.asarray(batch.rows)
+        t0 = time.perf_counter()
+        ltok, clen = _launch(inputs, self._last, rows)
+        logits, self._pool_tree = _launch(
+            exe, self.params, self._pool_tree, tables, ltok, clen)
+        if not sampling:
+            self._last = _launch(picks, logits, self._last, rows)
+        self._last_b_rung, self._last_w_rung = b_rung, w_rung
+        self._steps += 1
+        self._m["steps"].inc()
+        pt = self.page_tokens
+        out = []
+        with _RING.span("decode.step.advance"):
+            for req in batch.reqs:
+                req.cache_len += 1
+                if req.input_tail:       # still consuming prompt tail:
+                    req.last_tok = req.input_tail.popleft()
+                    out.append((req, False))    # mid-prompt logits: none
+                    continue
+                if req.feeding:
+                    # the step consumes the final prompt token — its
+                    # pages then hold the whole prompt: cache them; the
+                    # row's output is this request's FIRST token
+                    req.feeding = False
+                    if self._prefix is not None:
+                        self._prefix.insert(
+                            req.prompt, req.pages[:len(req.prompt) // pt])
+                self._expect(req)
+                out.append((req, True))
+        self._flight = _Flight(out, logits if sampling else None, t0)
+        self._firsts = []
+
+    def _expect(self, req: _Req):
+        """One more token of `req` is being computed: counted now,
+        delivered when read. Its next input is then the slot's entry
+        on the device; from the count the host knows whether it is the
+        request's last."""
+        req.pending += 1
+        req.last_tok = -1
+        if len(req.generated) + req.pending >= req.max_new \
+                or req.cache_len >= self._kind.max_seq_len:
+            req.closing = True
+
+    def _drain(self, read: bool = True):
+        """Bring home whatever the device still owes the host: the step
+        in flight and the first tokens of the admissions since. With
+        `read` off (the error path, the stop) it is waited for and
+        dropped."""
+        unread = self._flight, self._firsts, self._last
+        self._flight, self._firsts = None, []
+        if unread[0] is None and not unread[1]:
+            return
+        if read:
+            self._read(*unread)
+            return
+        self._batch = None
+        try:
+            jax.block_until_ready((self._last, self._pool_tree))
+        except Exception:                # a poisoned step: nothing to keep
+            pass
+
+    def _read(self, flight: Optional[_Flight], firsts: List, last):
+        """Read a step's tokens and the first tokens of the admissions
+        dispatched after it, deliver them, and let go of the requests
+        that ended. `last` is the slots' last tokens from before any
+        later step stored into them: one [slots] pull for all of them,
+        or the step's [B, V] logits where the host samples."""
+        with _RING.span("decode.step.pull", {}) as pull:
+            # greedy rows need their best id and nothing else of the
+            # logits: the device picked it and one id a slot crosses;
+            # one sampling row and the tick pulls the logits
+            logits = ids = None
+            if flight is not None and flight.logits is not None:
+                logits = np.asarray(flight.logits)
+            if firsts or (flight is not None and logits is None):
+                ids = np.asarray(last)
+            pull.args["bytes"] = sum(a.nbytes for a in (logits, ids)
+                                     if a is not None)
+        ended = False
+        rows = flight.rows if flight is not None else []
+        with _RING.span("decode.sample", {"reqs": len(rows)}):
+            for j, (req, sampled) in enumerate(rows):
+                # a row of a request that ended meanwhile (EOS read
+                # while this step ran, a failed page) is dropped
+                if sampled and req.slot is not None:
+                    ended |= self._deliver(
+                        req, ids[req.slot] if logits is None else logits[j])
+            for req, t0 in firsts:
+                if req.slot is not None:
+                    self._note_prefill(req, t0)
+                    ended |= self._deliver(req, ids[req.slot])
+        if ended:
+            self._active = [r for r in self._active if r.slot is not None]
+            self._batch = None
             self._update_gauges()
 
-    def _provision_rows(self) -> int:
-        """Provision every active slot's write target for row
-        cache_len: a fresh page at a page boundary, a copy-on-write if
-        the target page is shared. A slot the pool cannot serve fails
-        alone. Returns the pages taken."""
+    def _provision_rows(self, reqs: List[_Req]) -> int:
+        """Provision each request's write target for row cache_len: a
+        fresh page at a page boundary, a copy-on-write if the target
+        page is shared. A slot the pool cannot serve fails alone.
+        Returns the pages taken."""
         pt = self.page_tokens
         taken = 0
         victims = []
-        for req in self._active:
+        for req in reqs:
+            if req.closing or req.slot is None:
+                continue
             slot = req.cache_len // pt
             try:
                 if slot >= len(req.pages):
@@ -2335,6 +2645,12 @@ class DecodeEngine:
                         self._cow(req, slot)
                     taken += 1
             except TypedServeError as err:
+                if req.pending:
+                    # the tokens it is owed reach its stream before the
+                    # error does (and may end it: then there is none)
+                    self._drain()
+                    if req.slot is None:
+                        continue
                 req.stream._push_error(err)
                 self._m["evictions"].labels(reason="exhausted").inc()
                 self._release_pages(req)
@@ -2345,56 +2661,40 @@ class DecodeEngine:
             self._update_gauges()
         return taken
 
-    def _sample_rows(self, reqs: List[_Req], rows: np.ndarray):
-        """Advance every slot past the step just run: feed the next
-        prompt-tail token, or sample, push and account one new token.
-        `rows` is the step's logits [B, V], or the device's greedy
-        picks [B] when every row is greedy. Returns the requests that
-        ended."""
-        picked = rows.ndim == 1
-        pt = self.page_tokens
-        finished = []
-        for j, req in enumerate(reqs):
-            req.cache_len += 1
-            if req.input_tail:           # still consuming prompt tail:
-                req.last_tok = req.input_tail.popleft()
-                continue                 # logits are mid-prompt, discard
-            if req.feeding:
-                # the step just consumed the final prompt token — its
-                # pages now hold the whole prompt: cache them, and fall
-                # through to sample this request's FIRST token
-                req.feeding = False
-                if self._prefix is not None:
-                    self._prefix.insert(
-                        req.prompt, req.pages[:len(req.prompt) // pt])
-            first = not req.generated
-            try:
-                chaos.maybe_fail("decode.stream", detail=req.id)
-                tok = int(rows[j]) if picked \
-                    else self._sample(rows[j], req)
-            except Exception as exc:
-                req.stream._push_error(TypedServeError(
-                    ERR_UNAVAILABLE, f"decode stream killed: {exc}"))
-                self._m["evictions"].labels(reason="error").inc()
-                self._release_pages(req)
-                finished.append(req)
-                continue
-            req.generated.append(tok)
-            req.last_tok = tok
-            self._tokens += 1
-            self._m["tokens"].inc()
-            self._note_token(req)
-            if first:
-                self._m["ttft"].observe(time.monotonic() - req.t_submit)
-            eos = req.eos_id is not None and tok == req.eos_id
-            req.stream._push_token(tok, eos)
-            _RING.instant("decode.emit", {"req": req.id})
-            if eos or len(req.generated) >= req.max_new \
-                    or req.cache_len >= self._kind.max_seq_len:
-                self._finish(req, "eos" if eos else "length")
-                self._release_pages(req)
-                finished.append(req)
-        return finished
+    def _deliver(self, req: _Req, value) -> bool:
+        """One token of `req` is on the host: push and account it.
+        `value` is the device's pick, or the logits row [V] the host
+        samples from (its next input is then the host's to give). True
+        if the request ended with it: EOS, its count, or a killed
+        stream."""
+        req.pending -= 1
+        first = not req.generated        # resumes already saw first-token
+        try:
+            chaos.maybe_fail("decode.stream", detail=req.id)
+            if np.ndim(value):
+                tok = req.last_tok = self._sample(value, req)
+            else:
+                tok = int(value)
+        except Exception as exc:
+            req.stream._push_error(TypedServeError(
+                ERR_UNAVAILABLE, f"decode stream killed: {exc}"))
+            self._m["evictions"].labels(reason="error").inc()
+            self._release_pages(req)
+            return True
+        req.generated.append(tok)
+        self._tokens += 1
+        self._m["tokens"].inc()
+        self._note_token(req)
+        if first:
+            self._m["ttft"].observe(time.monotonic() - req.t_submit)
+        eos = req.eos_id is not None and tok == req.eos_id
+        req.stream._push_token(tok, eos)
+        _RING.instant("decode.emit", {"req": req.id})
+        if eos or (req.closing and not req.pending):
+            self._finish(req, "eos" if eos else "length")
+            self._release_pages(req)
+            return True
+        return False
 
     def _finish(self, req: _Req, reason: str):
         req.stream._push_done()
@@ -2518,6 +2818,7 @@ class SpecDecodeEngine(DecodeEngine):
 
     _req_cls = _SpecReq
     _speculative = True
+    _run_ahead = False    # its tick reads drafts and verdicts as it goes
 
     def __init__(self, model=None, *, draft_model=None,
                  draft_cfg: Optional[GPTConfig] = None,

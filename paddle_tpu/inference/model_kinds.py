@@ -15,7 +15,10 @@ dispatch, donated, and never looks inside.
         -> (logits [1, V] float32, pools)
 
 The logits stay on the device unless a row samples: a tick of greedy
-rows pulls the engine's `greedy_picks` of them, [B] ids. A kind that
+rows stores the engine's `greedy_picks` of them in its slots' last
+tokens, on the device, where the next step's `last_tok` is gathered
+from; the host pulls one id a slot, after that step is dispatched. The
+signatures above know nothing of it. A kind that
 has speculative decoding also gives `verify_fn` and `rollout_fn`, which
 `SpecDecodeEngine` asks of its target and of its draft.
 

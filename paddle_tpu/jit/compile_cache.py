@@ -149,18 +149,24 @@ def aot_compile(jitted, *args, label: str = "step", use_cache: bool = True,
 class _ProfiledExecutable:
     """The per-executable dispatch hook shared by tracez and profilez.
 
-    Wraps one compiled executable: each call is timed twice — the call
-    itself (JAX dispatches asynchronously, so this is host dispatch
-    cost) and ``block_until_ready`` on the outputs (device execution).
-    Both land in the tracez event ring (one live ``exec:<label>`` span
-    per dispatch, so it is a profiler annotation too whenever a
-    profiler session is on) and the profilez ``paddle_tpu_exec_*``
-    aggregates, keyed by the owning cache's label.  Every current
-    AotCache call site reads the outputs on the host immediately after
-    dispatching, so blocking here moves the wait, it does not add one.
-    A poisoned dispatch is NOT re-raised from the hook — it surfaces at
-    the caller's read with its original traceback, exactly as without
-    the wrapper.
+    Wraps one compiled executable, with two ways to call it.
+    ``exe(*args)`` is timed twice — the call itself (JAX dispatches
+    asynchronously, so this is host dispatch cost) and
+    ``block_until_ready`` on the outputs (device execution) — for the
+    call sites that read the outputs on the host right after
+    dispatching: blocking there moves the wait, it does not add one.
+    ``exe.dispatch(*args)`` returns as soon as the program is enqueued,
+    for the one caller that has host work to do meanwhile (the decode
+    engine's tick); whoever reads an output waits for it. Either way
+    the call lands in the tracez event ring (one live ``exec:<label>``
+    span per dispatch — the whole call, so dispatch -> ready for
+    ``exe(...)`` and the dispatch alone for ``dispatch`` — and a
+    profiler annotation too whenever a profiler session is on) and in
+    the profilez ``paddle_tpu_exec_*`` aggregates, keyed by the owning
+    cache's label (``dispatch`` has no block time to give). A poisoned
+    dispatch is NOT re-raised from the hook — it surfaces at the
+    caller's read with its original traceback, exactly as without the
+    wrapper.
     """
 
     __slots__ = ("_exe", "_label", "_span_name", "_donate")
@@ -174,7 +180,7 @@ class _ProfiledExecutable:
     def __getattr__(self, name):      # cost_analysis() etc. pass through
         return getattr(self._exe, name)
 
-    def __call__(self, *args):
+    def _call(self, args, wait: bool):
         import jax
 
         from ..observability import profilez as _profilez
@@ -187,13 +193,21 @@ class _ProfiledExecutable:
         with _tracez.RING.span(self._span_name) as span:
             out = self._exe(*args)
             t1 = time.perf_counter()
-            try:
-                jax.block_until_ready(out)
-            except Exception:
-                pass                   # deferred failure: caller's read
+            if wait:
+                try:
+                    jax.block_until_ready(out)
+                except Exception:
+                    pass               # deferred failure: caller's read
         _profilez.PROFILER.observe(self._label, t1 - span.t0,
-                                   span.t1 - t1, donated)
+                                   span.t1 - t1 if wait else 0.0, donated)
         return out
+
+    def __call__(self, *args):
+        return self._call(args, wait=True)
+
+    def dispatch(self, *args):
+        """The call without the wait."""
+        return self._call(args, wait=False)
 
 
 class AotCache:

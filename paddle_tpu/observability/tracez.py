@@ -55,10 +55,11 @@ from . import metrics as _metrics
 __all__ = ["TraceRing", "RING", "ring_capacity", "merge_traces",
            "fetch_trace", "load_trace", "main"]
 
-# a 45 s window of a decode engine at 6,000 tokens/s writes 330k events
-# (a `decode.emit` a token and some twenty spans a tick); a ring that
-# wraps inside the window has holes, and nothing is read from it
-DEFAULT_CAPACITY = 524288
+# a 45 s window of a decode engine at 9,500 tokens/s writes 560k events
+# (a `decode.emit` a token and some twenty-five spans a tick: 1.3 a
+# token at a hundred rows); a ring that wraps inside the window has
+# holes, and nothing is read from it
+DEFAULT_CAPACITY = 1048576
 
 
 def ring_capacity() -> int:

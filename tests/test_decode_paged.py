@@ -257,8 +257,10 @@ def test_fused_prefill_writes_what_the_three_hops_wrote(plen, kv_dtype):
 
 
 def test_admission_is_one_dispatch_and_no_host_trip(gpt_models):
-    """A miss admission's ring: `exec:decode.prefill` once, and nothing
-    of the K/V panel's old trip through numpy or its second dispatch."""
+    """A miss admission's ring: `exec:decode.prefill` once, the first
+    token's pick beside it on the device (`exec:decode.pfirst`: a
+    greedy admission pulls nothing), and nothing of the K/V panel's old
+    trip through numpy or its second dispatch."""
     from paddle_tpu.observability.tracez import RING
 
     eng = DecodeEngine(gpt_models["tiny-scan"], max_slots=2,
@@ -278,7 +280,7 @@ def test_admission_is_one_dispatch_and_no_host_trip(gpt_models):
     assert inner.count("exec:decode.prefill") == 1
     assert sorted(inner) == sorted(
         ["decode.admit.lookup", "decode.admit.alloc", "exec:decode.prefill",
-         "decode.admit.logits_pull", "decode.admit.emit"])
+         "exec:decode.pfirst"])
     for gone in ("decode.admit.kv_pull", "decode.admit.repack",
                  "decode.admit.upload", "exec:decode.pwrite"):
         assert not any(name == gone for name, _, _ in events), gone
@@ -471,9 +473,11 @@ def test_page_exhaustion_fails_only_victim(gpt_models):
 def test_ring_spans_tile_the_scheduler_loop():
     """The engine thread's ring spans after a short run: every
     `decode.loop` contains its schedule, admissions and tick and counts
-    them; a miss admission is tiled by its five phases, in order, and
-    carries how long the request queued; the tick's phases cover at
-    least 95% of a `decode.step`."""
+    them; a miss admission is tiled by its four phases, in order, and
+    carries how long the request queued; the tick's phases (the
+    dispatch, the read of the step before, the preparation of the step
+    after) and the wait for its step, which the iteration that
+    follows does, cover at least 95% of the two."""
     import time
 
     from paddle_tpu.observability.tracez import RING
@@ -510,16 +514,23 @@ def test_ring_spans_tile_the_scheduler_loop():
         assert sum(len(inside(name, lp)) for lp in loops) \
             == len(spans[name]), name        # none outside an iteration
     for lp in loops:
-        (sched,) = inside("decode.schedule", lp)
-        assert set(sched[2]) == {"pending", "paused"}
+        # one look at the queue, and one more after a step's wait if
+        # somebody arrived meanwhile
+        scheds = inside("decode.schedule", lp)
+        assert 1 <= len(scheds) <= 2 and all(
+            set(s[2]) == {"pending", "paused"} for s in scheds)
+        assert len(inside("decode.step.wait", lp)) <= 1
         assert lp[2]["admits"] == len(inside("decode.admit", lp))
-        assert len(inside("decode.step", lp)) == (lp[2]["active"] > 0)
+        # a tick, unless every slot only awaits its last token: then
+        # the iteration reads them and dispatches nothing
+        n_ticks = len(inside("decode.step", lp))
+        assert n_ticks == (lp[2]["active"] > 0) or (
+            n_ticks == 0 and inside("decode.step.pull", lp))
     assert sum(s[2]["pending"] for s in spans["decode.schedule"]) >= 3
     assert spans["decode.idle"]              # it waited for the first
 
     phases = ["decode.admit.lookup", "decode.admit.alloc",
-              "exec:decode.prefill", "decode.admit.logits_pull",
-              "decode.admit.emit"]
+              "exec:decode.prefill", "exec:decode.pfirst"]
     for adm, plen in zip(admits, (5, 9, 6)):
         args = adm[2]
         assert args["plen"] == plen and args["ok"] is True
@@ -531,17 +542,29 @@ def test_ring_spans_tile_the_scheduler_loop():
         assert starts == sorted(starts)
         assert inner[1][0][2] == {"pages": -(-plen // 4)}
 
-    tiles = ["decode.step.provision", "decode.step.build",
-             "exec:decode.pstep", "decode.step.pull", "decode.sample"]
+    tiles = ["decode.step.build", "decode.step.provision",
+             "exec:decode.ptok", "exec:decode.pstep", "exec:decode.ppick",
+             "decode.step.pull", "decode.sample", "decode.step.advance"]
     shares = []
-    for tick in ticks:
-        assert set(tick[2]) == {"batch", "b_rung", "w_rung"}
+    waits = sorted(spans["decode.step.wait"])
+    ticks = sorted(ticks)
+    for tick, nxt in zip(ticks, ticks[1:] + [(float("inf"),)]):
+        assert set(tick[2]) == {"batch", "b_rung", "w_rung", "ahead"}
         inner = [inside(name, tick) for name in tiles]
-        assert all(len(c) == 1 for c in inner), inner
-        covered = sum(c[0][1] - c[0][0] for c in inner)
-        shares.append((covered / (tick[1] - tick[0]), covered,
-                       tick[1] - tick[0]))
-        assert inner[3][0][2]["bytes"] > 0
+        # a tick builds the step it dispatches only where the tick
+        # before could not prepare it, and the step after where one is
+        # due; the rest it does once
+        assert all(len(c) == 1 for c in inner[2:]), inner
+        assert 1 <= len(inner[0]) <= 3 and 1 <= len(inner[1]) <= 2, inner
+        # the step is waited for by the iteration that follows: the
+        # tick's phases and that wait, over the tick and that wait
+        (wait,) = [w for w in waits if tick[1] <= w[0] < nxt[0]]
+        covered = sum(c[1] - c[0] for cs in inner + [[wait]] for c in cs)
+        whole = tick[1] - tick[0] + wait[1] - wait[0]
+        shares.append((covered / whole, covered, whole))
+        assert inner[5][0][2]["bytes"] > 0
+        # the step goes out before the step before it is read
+        assert inner[3][0][0] < inner[5][0][0]
     # every tick, but for the odd one in which a loaded machine took
     # the thread off the CPU between two spans (six test workers share
     # these cores): the median tick and the ticks taken together
