@@ -13,7 +13,12 @@ KV cache instead of per-slot contiguous panels:
     page_tokens, heads * head_dim]`, for a GPT, one latent row a token
     a layer for `axk1`; page axis 0 on every leaf, a token's row whole,
     so a step writes rows into the arrays it was given); the engine
-    threads the pools as one pytree. Admission allocates pages,
+    threads the pools as one pytree. A kind may also keep state BY
+    SLOT beside its pages (`kimi_linear`'s recurrent KDA state): the
+    engine then hands the step its rows' slots and the prefill its
+    slot, an admission overwrites its slot's state, and no prefix
+    trie is built (`model_kinds`' docstring has the contract).
+    Admission allocates pages,
     eviction releases them — capacity growth is a wider block table, never a cache copy
     (the contiguous engine re-packed the whole pool on every rung
     change);
@@ -286,6 +291,11 @@ def _decode_metrics():
                 "Preempted requests currently parked host-side "
                 "awaiting re-admission"),
             # quantized serving
+            "state_pool_bytes": gauge(
+                "paddle_tpu_decode_state_pool_bytes",
+                "Bytes of state that lives by slot beside the KV pages "
+                "(a recurrent model kind's state pool; 0 for a kind "
+                "whose streams keep pages only)"),
             "kv_page_bytes": gauge(
                 "paddle_tpu_decode_kv_page_bytes",
                 "HBM bytes one K+V page occupies at the engine's pool "
@@ -389,6 +399,12 @@ def step_inputs(last, rows):
     return jnp.where(tok >= 0, tok, last.at[slot].get(mode="clip")), clen
 
 
+def step_inputs_by_slot(last, rows):
+    """`step_inputs` and the rows' slots [B], for a kind whose streams
+    keep state by slot (a padding row: the slot count, its null slot)."""
+    return step_inputs(last, rows) + (rows[0],)
+
+
 def store_picks(logits, last, rows):
     """`last` with every row's greedy pick in its slot's entry."""
     return last.at[rows[0]].set(greedy_picks(logits), mode="drop")
@@ -409,6 +425,18 @@ def _launch(exe, *args):
 def _trie_owner(digest: bytes) -> tuple:
     """Allocator owner tag for a prefix-trie node (short digest hex)."""
     return ("trie", digest.hex()[:12])
+
+
+def _slot_state(kind) -> bool:
+    """Whether `kind`'s streams keep state by slot beside their pages
+    (`model_kinds`' module docstring has the contract)."""
+    return bool(getattr(kind, "slot_state", False))
+
+
+def _slots_kw(kind, slots: int) -> Dict:
+    """What such a kind's pools are told beside the page count (how
+    many slots the state pool serves); nothing for the other kinds."""
+    return {"slots": int(slots)} if _slot_state(kind) else {}
 
 
 def fit_slot_count(step_bytes, budget: int, upper: int,
@@ -461,15 +489,18 @@ def default_slot_count(step_jit, params, kind, page_tokens: int,
     budget = used + int(max(limit - used, 0) * hbm_fraction)
     pages_per_seq = -(-kind.max_seq_len // page_tokens)
     i32 = jnp.int32
+    by_slot = _slot_state(kind)
 
     def step_bytes(n):
-        pools = kind.pools_sds(n * pages_per_seq + 1, page_tokens, kv_dtype)
+        # a kind with state by slot grows its state pool with n too
+        pools = kind.pools_sds(n * pages_per_seq + 1, page_tokens, kv_dtype,
+                               **_slots_kw(kind, n))
+        rows = jax.ShapeDtypeStruct((n,), i32)
         try:
             exe, _ = aot_compile(
                 step_jit, params, pools,
                 jax.ShapeDtypeStruct((n, pages_per_seq), i32),
-                jax.ShapeDtypeStruct((n,), i32),
-                jax.ShapeDtypeStruct((n,), i32),
+                *(rows,) * (3 if by_slot else 2),
                 label=f"decode.sizing:{n}")
         except jax.errors.JaxRuntimeError as e:
             if "RESOURCE_EXHAUSTED" not in str(e):
@@ -974,7 +1005,8 @@ class _PrefixCache:
 
 class DecodeEngine:
     """Slot-pool continuous batcher over a model kind's paged
-    incremental forward (`model_kinds`: a GPT, or `axk1`): fixed device
+    incremental forward (`model_kinds`: a GPT, `axk1`, or
+    `kimi_linear`, whose streams also keep state by slot): fixed device
     page pool + per-slot block tables, prefix sharing with
     copy-on-write, typed backpressure on exhaustion. `model` (a layer)
     or `cfg` + `params` say which model; the kind follows from their
@@ -1007,6 +1039,9 @@ class DecodeEngine:
         else:
             kind = model_kinds.for_config(cfg, eps)
         self._kind = kind
+        # state that lives by slot (a recurrent kind): the step takes
+        # its rows' slots, the prefill its slot, the pools a slot count
+        self._by_slot = _slot_state(kind)
         self.cfg = kind.cfg
         self.eps = kind.eps
         self.params = {k: jnp.asarray(v) for k, v in params.items()}
@@ -1072,6 +1107,12 @@ class DecodeEngine:
         # prefix cache
         if self.host_pages or self.handoff:
             use_prefix = True
+        # a page hit without the slot's state at that boundary would
+        # serve wrong tokens: no trie for a kind with state by slot
+        # (so nothing is shared, copied on write or stashed at a
+        # preemption; a resume prefills prompt + generated anew)
+        if self._by_slot:
+            use_prefix = False
         self._prefix = _PrefixCache(self._alloc, self.page_tokens) \
             if use_prefix else None
 
@@ -1087,7 +1128,9 @@ class DecodeEngine:
         # a greedy tick stores its picks there and the host pulls one
         # id a slot, not [B, V] logits (17 MB at 84 rows of a 50k
         # vocabulary, every tick), after the next step is dispatched
-        self._tok_aot = AotCache(jax.jit(step_inputs), "decode.ptok")
+        self._tok_aot = AotCache(
+            jax.jit(step_inputs_by_slot if self._by_slot else step_inputs),
+            "decode.ptok")
         self._pick_aot = AotCache(jax.jit(store_picks), "decode.ppick")
         self._first_aot = AotCache(jax.jit(store_first), "decode.pfirst")
         # host-tier / handoff executables: `pgather` snapshots pages
@@ -1112,6 +1155,7 @@ class DecodeEngine:
         self._m["kv_page_bytes"].set(
             kind.page_bytes(self.page_tokens, self.kv_dtype))
         self._m["kv_quantized"].set(1 if self.kv_dtype == "int8" else 0)
+        self._m["state_pool_bytes"].set(self._state_pool_bytes())
         self._spans = SpanRecorder(
             component="decode", metric="paddle_tpu_decode_span_seconds",
             help="Decode request stage latency (queue/prefill/decode)")
@@ -1240,11 +1284,15 @@ class DecodeEngine:
     def _quota_rate(self, tenant: str) -> float:
         return self._quota.get(tenant, self._quota["*"])
 
+    def _state_pool_bytes(self) -> int:
+        return int(self._kind.state_bytes(self.max_slots)) \
+            if self._by_slot else 0
+
     def _model_pools_sds(self):
         """The model kind's pools, described: what step, prefill and
         copy-on-write take."""
         return self._kind.pools_sds(self.num_pages, self.page_tokens,
-                                    self.kv_dtype)
+                                    self.kv_dtype, **_slots_kw(self._kind, self.max_slots))
 
     # The tier moves every pool an engine owns as ONE pytree — the base
     # engine's model pools, the speculative engine's plus its draft's —
@@ -1264,7 +1312,8 @@ class DecodeEngine:
     def _ensure_pool(self):
         if self._pool_tree is None:
             self._pool_tree = self._kind.pools_zeros(
-                self.num_pages, self.page_tokens, self.kv_dtype)
+                self.num_pages, self.page_tokens, self.kv_dtype,
+                **_slots_kw(self._kind, self.max_slots))
             self._last = jnp.zeros((self.max_slots,), jnp.int32)
             self._slot_ids = [jnp.asarray(i, jnp.int32)
                               for i in range(self.max_slots)]
@@ -1288,20 +1337,22 @@ class DecodeEngine:
         """`aot`'s fused prefill-into-pages executable for one kv rung
         (the pools may be arrays or their ShapeDtypeStructs)."""
         i32 = jnp.int32
+        slot = (jax.ShapeDtypeStruct((), i32),) if self._by_slot else ()
         return aot.get_or_compile(
             params, pools,
             jax.ShapeDtypeStruct((1, rung), i32),
             jax.ShapeDtypeStruct((1, -(-rung // self.page_tokens)), i32),
-            jax.ShapeDtypeStruct((1,), i32),
+            jax.ShapeDtypeStruct((1,), i32), *slot,
             key=("prefill", 1, rung))
 
     def _prefill_into_pages(self, aot, params, pools, toks, pages,
-                            wait: bool = True):
+                            wait: bool = True, slot: Optional[int] = None):
         """One dispatch: `toks` prefilled at their kv rung and their
         cache rows written into `pages` of the (donated) pools; table
-        padding aims at the null page. Returns (logits [1, V], pools),
-        all on the device: ready, or with `wait` off as soon as the
-        program is enqueued."""
+        padding aims at the null page. A kind with state by slot also
+        overwrites the state of `slot` with the sequence's. Returns
+        (logits [1, V], pools), all on the device: ready, or with
+        `wait` off as soon as the program is enqueued."""
         plen = len(toks)
         rung = next_bucket(plen, self.kv_ladder)
         inp = np.zeros((1, rung), np.int32)
@@ -1313,11 +1364,14 @@ class DecodeEngine:
         # to cast it (`convert_element_type`) every admission
         args = (params, pools, jnp.asarray(inp), jnp.asarray(table),
                 jnp.asarray(np.asarray([plen], np.int32)))
+        if self._by_slot:
+            args += (self._slot_ids[slot],)
         return exe(*args) if wait else _launch(exe, *args)
 
     def _token_exes(self, b_rung):
         """The two tiny programs around a step of `b_rung` rows:
-        `step_inputs` before it, `store_picks` after it."""
+        `step_inputs` (with the rows' slots for a kind with state by
+        slot) before it, `store_picks` after it."""
         i32 = jnp.int32
         last = jax.ShapeDtypeStruct((self.max_slots,), i32)
         rows = jax.ShapeDtypeStruct((3, b_rung), i32)
@@ -1327,6 +1381,16 @@ class DecodeEngine:
                                              key=("ptok", b_rung)),
                 self._pick_aot.get_or_compile(logits, last, rows,
                                               key=("ppick", b_rung)))
+
+    def _step_exe(self, pools, b_rung, w_rung):
+        """The step's executable at one (batch rung, page rung); the
+        pools may be arrays or their ShapeDtypeStructs."""
+        rows = jax.ShapeDtypeStruct((b_rung,), jnp.int32)
+        return self._step_aot.get_or_compile(
+            self.params, pools,
+            jax.ShapeDtypeStruct((b_rung, w_rung), jnp.int32),
+            *(rows,) * (3 if self._by_slot else 2),
+            key=("pstep", b_rung, w_rung))
 
     def _first_exe(self):
         i32 = jnp.int32
@@ -1371,12 +1435,7 @@ class DecodeEngine:
         if len(sigs) > _WARMUP_SIG_CAP:
             sigs = sigs[:_WARMUP_SIG_CAP]
         for b, w in sigs:
-            self._step_aot.get_or_compile(
-                self.params, pool,
-                jax.ShapeDtypeStruct((b, w), i32),
-                jax.ShapeDtypeStruct((b,), i32),
-                jax.ShapeDtypeStruct((b,), i32),
-                key=("pstep", b, w))
+            self._step_exe(pool, b, w)
         for b in self.batch_ladder:
             self._token_exes(b)
         self._first_exe()
@@ -1409,6 +1468,10 @@ class DecodeEngine:
             "kv_page_bytes": self._kind.page_bytes(self.page_tokens,
                                                    self.kv_dtype),
             "model_kind": self._kind.name,
+            # state that lives by slot beside the pages (0, 0 for a
+            # kind whose streams keep pages only)
+            "state_pool_bytes": self._state_pool_bytes(),
+            "state_slots": self.max_slots if self._by_slot else 0,
             "pages": self._alloc.stats(),
             "tenants": {t: round(v, 4)
                         for t, v in sorted(dict(self._vtokens).items())},
@@ -2394,11 +2457,13 @@ class DecodeEngine:
                 self._m["evictions"].labels(reason="exhausted").inc()
                 return False
         req.slot = self._free_slots.pop()
+        if self._by_slot:
+            note["state_slot"] = req.slot    # whose state it overwrites
         ahead = self._run_ahead and req.temperature <= 0.0
         t0 = time.perf_counter()
         logits, self._pool_tree = self._prefill_into_pages(
             self._prefill_aot, self.params, self._pool_tree, toks,
-            req.pages, wait=not ahead)
+            req.pages, wait=not ahead, slot=req.slot)
         self._m["prefills"].inc()
         req.cache_len = plen
         self._expect(req)
@@ -2521,18 +2586,15 @@ class DecodeEngine:
         rows' counts advance here; the tokens are read later (`_read`)."""
         b_rung, w_rung = batch.b_rung, batch.w_rung
         with _RING.span("decode.step.build"):
-            exe = self._step_aot.get_or_compile(
-                self.params, self._pool_tree,
-                jax.ShapeDtypeStruct((b_rung, w_rung), jnp.int32),
-                jax.ShapeDtypeStruct((b_rung,), jnp.int32),
-                jax.ShapeDtypeStruct((b_rung,), jnp.int32),
-                key=("pstep", b_rung, w_rung))
+            exe = self._step_exe(self._pool_tree, b_rung, w_rung)
             inputs, picks = self._token_exes(b_rung)
             tables, rows = jnp.asarray(batch.tables), jnp.asarray(batch.rows)
         t0 = time.perf_counter()
-        ltok, clen = _launch(inputs, self._last, rows)
+        # (last_tok, cache_len), and the rows' slots where state lives
+        # by slot
         logits, self._pool_tree = _launch(
-            exe, self.params, self._pool_tree, tables, ltok, clen)
+            exe, self.params, self._pool_tree, tables,
+            *_launch(inputs, self._last, rows))
         if not sampling:
             self._last = _launch(picks, logits, self._last, rows)
         self._last_b_rung, self._last_w_rung = b_rung, w_rung

@@ -22,6 +22,37 @@ signatures above know nothing of it. A kind that
 has speculative decoding also gives `verify_fn` and `rollout_fn`, which
 `SpecDecodeEngine` asks of its target and of its draft.
 
+**State that lives by slot.** A kind whose streams keep state that is
+no page (a recurrent state of fixed size) sets `slot_state = True`. Its
+pools then hold, beside the pages, arrays with a SLOT axis of
+`slots + 1` entries (`pools_sds` / `pools_zeros` take `slots`; the last
+entry is the null slot), and its functions take the slot:
+
+    step(params, pools, tables, last_tok, cache_len, slots [B])
+    prefill(params, pools, toks, tables, n, slot [])
+
+The engine hands the step the slot of every row (a padding row carries
+the slot COUNT, i.e. the null slot) and the prefill the slot it gave
+the request. Who owns a slot's state when:
+
+* *admission* OVERWRITES it: a prefill runs its sequence from an empty
+  state and writes the state after its last position into its slot,
+  whatever was there (a reused slot never sees the last stream's; a
+  resume after preemption is such a prefill over prompt + generated);
+* *a step* advances the slots of its live rows in place, and with its
+  padding rows only the null slot;
+* *finish and preemption* ABANDON it: the engine frees the slot and
+  keeps nothing of its state (`state_bytes` of the pool stay
+  allocated); a step still in flight may write the abandoned slot once
+  more, before, in device order, whatever admission takes it next;
+* it is never shared: the engine builds no prefix trie for such a kind
+  (a page hit without the state at that boundary would serve wrong
+  tokens), so no copy-on-write and nothing stashed at preemption.
+
+`slot_bytes()` of such a kind counts the state with the pages of a
+slot; `state_bytes(slots)` is the state alone. The `gpt` and `axk1`
+kinds have no such state and their signatures carry no slot.
+
 Kinds: `gpt` (`GPTKind`: a K and a V pool, each one array a layer `[P,
 page_tokens, heads * head_dim]`, float32 or int8; its programs are the
 four of the one builder `models.gpt.gpt_paged_fns`, returned as they
@@ -30,7 +61,10 @@ routed-assignment counters; `models.axk1.axk1_paged_fns`). Both keep
 the page axis at 0 on every
 page-holding leaf and a token's row whole and lane-dense, so the
 compiled step writes rows into the arrays it was given and
-`memory.page_allocator`'s page ops serve either. The manifest of a
+`memory.page_allocator`'s page ops serve either. `kimi_linear`
+(`KimiLinearKind`, an `AXK1Kind` with slot state) holds latent pages for
+its MLA layers and, by slot, the recurrent and convolution state of its
+KDA layers (`models.kimi_linear.kimi_linear_paged_fns`). The manifest of a
 `save_for_decode` artifact names its kind under `"model_kind"`; one
 without the key is a GPT.
 """
@@ -52,6 +86,9 @@ from ..memory.page_allocator import copy_page
 from ..models.axk1 import (AXK1, AXK1Config, axk1_paged_fns,
                            latent_pools_sds)
 from ..models.gpt import GPTConfig, gpt_paged_fns
+from ..models.kimi_linear import (KimiLinear, KimiLinearConfig,
+                                  kimi_linear_paged_fns,
+                                  kimi_linear_pools_sds)
 from ..quant.kv import kv_pool_sds, kv_pool_zeros, validate_kv_dtype
 from .errors import ERR_FAILED_PRECONDITION, TypedServeError
 
@@ -212,10 +249,14 @@ class AXK1Kind:
 
     name = "axk1"
     DEFAULT_PAGE_TOKENS = 128   # 640 bfloat16 lanes a row: 160 KB a page
+    config_cls = AXK1Config
+    paged_fns = staticmethod(axk1_paged_fns)
+    roadmap = "R3"              # where what it refuses is queued
 
-    def __init__(self, cfg: AXK1Config, eps: Optional[float] = None):
+    def __init__(self, cfg, eps: Optional[float] = None):
         if eps is not None and float(eps) != float(cfg.rms_norm_eps):
-            raise ValueError("AXK1Kind: eps is the config's rms_norm_eps")
+            raise ValueError(f"{type(self).__name__}: eps is the config's "
+                             f"rms_norm_eps")
         self.cfg = cfg
         self.eps = float(cfg.rms_norm_eps)
         self.vocab_size = cfg.vocab_size
@@ -227,7 +268,7 @@ class AXK1Kind:
 
     @classmethod
     def from_manifest(cls, meta):
-        return cls(AXK1Config(**meta["config"]))
+        return cls(cls.config_cls(**meta["config"]))
 
     def manifest(self):
         return {"config": dataclasses.asdict(self.cfg), "eps": self.eps}
@@ -244,30 +285,31 @@ class AXK1Kind:
                    speculative=False):
         if speculative:
             raise unsupported(self.name, "speculative decoding "
-                              "(SpecDecodeEngine)", "R3")
+                              "(SpecDecodeEngine)", self.roadmap)
         if kv_dtype not in (None, self.cfg.dtype):
             raise unsupported(self.name, f"kv_dtype={kv_dtype!r} (latent "
-                              f"pages are {self.cfg.dtype})", "R3")
+                              f"pages are {self.cfg.dtype})", self.roadmap)
         if host_pages:
             raise unsupported(self.name, "host tiering of latent pages "
-                              "(host_pages)", "R3")
+                              "(host_pages)", self.roadmap)
         if handoff:
             raise unsupported(self.name, "KV handoff of latent pages",
-                              "R3")
+                              self.roadmap)
         return self.cfg.dtype
 
     def step_fn(self, page_tokens):
-        return axk1_paged_fns(self.cfg, page_tokens)[1]
+        return self.paged_fns(self.cfg, page_tokens)[1]
 
     def prefill_fn(self, page_tokens, name="prefill"):
-        return axk1_paged_fns(self.cfg, page_tokens, prefill_name=name)[0]
+        return self.paged_fns(self.cfg, page_tokens, prefill_name=name)[0]
 
     def pools_sds(self, num_pages, page_tokens, kv_dtype):
         return latent_pools_sds(self.cfg, num_pages, page_tokens)
 
-    def pools_zeros(self, num_pages, page_tokens, kv_dtype):
+    def pools_zeros(self, num_pages, page_tokens, kv_dtype, **slots):
         return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                            self.pools_sds(num_pages, page_tokens, kv_dtype))
+                            self.pools_sds(num_pages, page_tokens, kv_dtype,
+                                           **slots))
 
     @staticmethod
     def copy_page(pools, src, dst):
@@ -275,8 +317,12 @@ class AXK1Kind:
 
     def page_bytes(self, page_tokens, kv_dtype):
         c = self.cfg
-        return c.num_hidden_layers * int(page_tokens) * c.pool_row_width \
+        return self.latent_layers * int(page_tokens) * c.pool_row_width \
             * jnp.dtype(c.dtype).itemsize
+
+    @property
+    def latent_layers(self):
+        return self.cfg.num_hidden_layers
 
     def slot_bytes(self):
         return self.page_bytes(self.max_seq_len, None)
@@ -295,19 +341,59 @@ class AXK1Kind:
                 "routed_tokens": int(pools["routed_tokens"])}
 
 
-KINDS = {GPTKind.name: GPTKind, AXK1Kind.name: AXK1Kind}
+# ---------------------------------------------------------- kimi_linear
+
+
+class KimiLinearKind(AXK1Kind):
+    """`models.kimi_linear`: two kinds of state in one pools pytree.
+    Latent pages (as `axk1`'s, bfloat16, page axis 0) for the MLA
+    layers ONLY, and for the KDA layers state that lives by slot: a
+    recurrent state `[slots + 1, H, K, V]` float32 and the convolution's
+    last inputs `[slots + 1, 3 x 3 x width]`, one array a layer each,
+    updated in place; plus the routed counters. The module docstring
+    has the seam's contract for slot state. Refused, typed, at
+    construction: what `axk1` refuses; prefix reuse is off (ROADMAP
+    R5 keeps state snapshots at page boundaries)."""
+
+    name = "kimi_linear"
+    config_cls = KimiLinearConfig
+    paged_fns = staticmethod(kimi_linear_paged_fns)
+    roadmap = "R5"
+    slot_state = True
+
+    def pools_sds(self, num_pages, page_tokens, kv_dtype, slots):
+        return kimi_linear_pools_sds(self.cfg, num_pages, page_tokens, slots)
+
+    @property
+    def latent_layers(self):
+        return len(self.cfg.mla_index)
+
+    def state_bytes(self, slots):
+        """Bytes of the state pool of an engine of `slots` slots (its
+        null slot counted)."""
+        return (int(slots) + 1) * self.cfg.state_slot_bytes
+
+    def slot_bytes(self):
+        return self.page_bytes(self.max_seq_len, None) \
+            + self.cfg.state_slot_bytes
+
+
+KINDS = {GPTKind.name: GPTKind, AXK1Kind.name: AXK1Kind,
+         KimiLinearKind.name: KimiLinearKind}
 
 
 def for_config(cfg, eps=None):
     if isinstance(cfg, GPTConfig):
         return GPTKind(cfg, eps)
-    if isinstance(cfg, AXK1Config):
-        return AXK1Kind(cfg, eps)
+    for kind in (AXK1Kind, KimiLinearKind):
+        if isinstance(cfg, kind.config_cls):
+            return kind(cfg, eps)
     raise TypeError(f"no decode model kind for config {type(cfg).__name__}")
 
 
 def for_model(model, eps=None):
-    kind = AXK1Kind if isinstance(model, AXK1) else GPTKind
+    kind = AXK1Kind if isinstance(model, AXK1) \
+        else KimiLinearKind if isinstance(model, KimiLinear) else GPTKind
     return kind.from_model(model, eps)
 
 

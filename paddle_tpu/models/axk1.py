@@ -243,20 +243,30 @@ def layer_params(params, i):
 
 def mla_project(cfg, lp, x, positions):
     """(q_nope [..., H, dn], q_rope [..., H, dr], cache rows [..., C+dr])
-    of the tokens x [..., hidden] at `positions` [...]."""
+    of the tokens x [..., hidden] at `positions` [...]. A config without
+    a query low-rank (`q_lora_rank` None) projects q straight from x
+    (`self_attn.q_proj`); `positions` None leaves the `dr`-wide parts
+    unrotated (NoPE)."""
     nh = cfg.num_attention_heads
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     eps = cfg.rms_norm_eps
-    c_q = rms_norm(_mm(x, lp["self_attn.q_a_proj"]),
-                   lp["self_attn.q_a_layernorm"], eps)
-    q = _mm(c_q, lp["self_attn.q_b_proj"]).reshape(
-        x.shape[:-1] + (nh, dn + dr))
+    if cfg.q_lora_rank:
+        c_q = rms_norm(_mm(x, lp["self_attn.q_a_proj"]),
+                       lp["self_attn.q_a_layernorm"], eps)
+        q = _mm(c_q, lp["self_attn.q_b_proj"])
+    else:
+        q = _mm(x, lp["self_attn.q_proj"])
+    q = q.reshape(x.shape[:-1] + (nh, dn + dr))
     kv = _mm(x, lp["self_attn.kv_a_proj_with_mqa"])
     c_kv = rms_norm(kv[..., :cfg.kv_lora_rank],
                     lp["self_attn.kv_a_layernorm"], eps)
-    cos, sin = rope_cos_sin(cfg, positions)
-    k_r = apply_rope(kv[..., cfg.kv_lora_rank:], cos, sin)
-    q_rope = apply_rope(q[..., dn:], cos[..., None, :], sin[..., None, :])
+    if positions is None:
+        k_r, q_rope = kv[..., cfg.kv_lora_rank:], q[..., dn:]
+    else:
+        cos, sin = rope_cos_sin(cfg, positions)
+        k_r = apply_rope(kv[..., cfg.kv_lora_rank:], cos, sin)
+        q_rope = apply_rope(q[..., dn:], cos[..., None, :],
+                            sin[..., None, :])
     return q[..., :dn], q_rope, jnp.concatenate([c_kv, k_r], axis=-1)
 
 
@@ -326,7 +336,8 @@ def ffn(cfg, lp, i, x, live=None):
     y, hits = routed_experts(
         x, lp["mlp.experts.router"], lp["mlp.experts.gate_proj"],
         lp["mlp.experts.up_proj"], lp["mlp.experts.down_proj"],
-        live=live, **_routing(cfg))
+        live=live, **_routing(cfg),
+        select_bias=lp.get("mlp.experts.e_score_correction_bias"))
     shared = swiglu(x, lp["mlp.shared_experts.gate_proj"],
                     lp["mlp.shared_experts.up_proj"],
                     lp["mlp.shared_experts.down_proj"])
@@ -361,7 +372,7 @@ class _Weights(nn.Layer):
     def __init__(self, dtype, **shapes):
         super().__init__()
         for name, shape in shapes.items():
-            norm = name.endswith("layernorm") or name == "norm"
+            norm = name.endswith("norm")
             setattr(self, name, self.create_parameter(
                 list(shape), dtype=dtype,
                 default_initializer=Constant(1.0) if norm

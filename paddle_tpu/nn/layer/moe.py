@@ -177,7 +177,7 @@ SORT_CHUNK_TOKENS = 1024    # the sorted path's working set, in tokens
 
 
 def route_sigmoid_grouped(x, router_w, *, top_k, n_group=1, topk_group=1,
-                          norm_topk_prob=True, scale=1.0):
+                          norm_topk_prob=True, scale=1.0, select_bias=None):
     """(idx [N, K] int32 over all routed experts, w [N, K] float32).
 
     s = sigmoid(x W_r) in float32 (six-pass matmul: the router is small
@@ -185,19 +185,26 @@ def route_sigmoid_grouped(x, router_w, *, top_k, n_group=1, topk_group=1,
     experts form `n_group` equal groups, a group scores the sum of its
     two best s, only the `topk_group` best groups stay eligible; then
     the `top_k` best s among the eligible. w = s of the picked, divided
-    by their sum under `norm_topk_prob`, times `scale`."""
+    by their sum under `norm_topk_prob`, times `scale`. With
+    `select_bias` [E] (DeepSeek-V3's `e_score_correction_bias`) groups
+    and picks are chosen on s + bias; the weights stay the picked s."""
     s = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     N, E = s.shape
-    pick_from = s
+    pick_from = s if select_bias is None \
+        else s + select_bias.astype(jnp.float32)
     if n_group > 1:
-        g = s.reshape(N, n_group, E // n_group)
+        g = pick_from.reshape(N, n_group, E // n_group)
         group_score = jax.lax.top_k(g, 2)[0].sum(-1)            # [N, G]
         _, best = jax.lax.top_k(group_score, topk_group)        # [N, g]
         keep = jnp.any(best[:, :, None]
                        == jnp.arange(n_group, dtype=best.dtype), axis=1)
-        pick_from = jnp.where(keep[:, :, None], g, 0.0).reshape(N, E)
+        # an ineligible score is 0, the floor of a sigmoid; a biased one
+        # may lie below 0
+        pick_from = jnp.where(keep[:, :, None], g,
+                              0.0 if select_bias is None
+                              else -jnp.inf).reshape(N, E)
     _, idx = jax.lax.top_k(pick_from, top_k)
     w = jnp.take_along_axis(s, idx, axis=1)
     if norm_topk_prob:
@@ -248,13 +255,15 @@ def _held_sorted(x, local, held, w, wg, wu, wd):
 
 def routed_experts(x, router_w, wg, wu, wd, *, top_k, n_group=1,
                    topk_group=1, norm_topk_prob=True, scale=1.0,
-                   held=None, live=None):
+                   held=None, live=None, select_bias=None):
     """The routed sum of one expert layer over the experts held here.
 
     x [N, H]; router_w [H, n_routed]; wg, wu [count, H, F]; wd
     [count, F, H]: the held experts' SwiGLU weights, expert `first + i`
     at index i. `held = (first, count)`, default all. `live` [N] bool:
     rows that are padding are neither computed nor counted.
+    `select_bias` [n_routed]: the picks are the best of score + bias
+    (`route_sigmoid_grouped`).
 
     Returns (y [N, H] float32, hits [count] int32: live assignments per
     held expert)."""
@@ -265,7 +274,8 @@ def routed_experts(x, router_w, wg, wu, wd, *, top_k, n_group=1,
                          f"for held={held}")
     idx, w = route_sigmoid_grouped(
         x, router_w, top_k=top_k, n_group=n_group, topk_group=topk_group,
-        norm_topk_prob=norm_topk_prob, scale=scale)
+        norm_topk_prob=norm_topk_prob, scale=scale,
+        select_bias=select_bias)
     local = idx - jnp.int32(first)
     mine = (local >= 0) & (local < count)
     if live is not None:
@@ -296,11 +306,14 @@ class RoutedExperts(Layer):
     holds (default all): the router keeps `n_routed` outputs, the expert
     weights are stacked [count, ...]. Input [..., d_model] -> output of
     the same shape. The shared expert of a DeepSeek-style block, which
-    every chip computes alike, is not part of this layer."""
+    every chip computes alike, is not part of this layer. With
+    `select_bias` the layer has a parameter `e_score_correction_bias`
+    [n_routed] that the selection adds to the scores."""
 
     def __init__(self, d_model, d_hidden, n_routed, top_k, n_group=1,
                  topk_group=1, norm_topk_prob=True,
-                 routed_scaling_factor=1.0, held=None, dtype=None):
+                 routed_scaling_factor=1.0, held=None, dtype=None,
+                 select_bias=False):
         super().__init__()
         first, count = held if held is not None else (0, int(n_routed))
         if first < 0 or count < 1 or first + count > n_routed:
@@ -324,12 +337,18 @@ class RoutedExperts(Layer):
         self.down_proj = self.create_parameter(
             [count, d_hidden, d_model], dtype=dtype,
             default_initializer=init)
+        if select_bias:
+            self.e_score_correction_bias = self.create_parameter(
+                [n_routed], dtype="float32", default_initializer=init)
 
     def forward(self, x):
-        def f(xa, rw, wg, wu, wd):
+        def f(xa, rw, wg, wu, wd, *bias):
             y, _ = routed_experts(xa.reshape(-1, xa.shape[-1]), rw, wg, wu,
-                                  wd, held=self.held, **self.routing)
+                                  wd, held=self.held, **self.routing,
+                                  select_bias=bias[0] if bias else None)
             return y.reshape(xa.shape).astype(xa.dtype)
 
+        bias = getattr(self, "e_score_correction_bias", None)
+        bias = () if bias is None else (bias,)
         return apply(f, x, self.router, self.gate_proj, self.up_proj,
-                     self.down_proj, op_name="routed_experts")
+                     self.down_proj, *bias, op_name="routed_experts")
