@@ -2,6 +2,8 @@
 
     python chip_kernels.py          # on the chip: compile + run + compare
     python chip_kernels.py --aot    # in the sandbox: chipless v5e compile only
+    python chip_kernels.py --grouped  # on the chip: the expert layer's grouped
+                                      # product timed alone (see grouped_main)
 
 The training path's kernels of ops/pallas/ (flash attention forward and
 both backward schemes, fused linear + cross-entropy) at the widths the
@@ -14,6 +16,7 @@ line, and chiprun_out/kernels.json when that directory exists. Exits
 non-zero if any case fails to compile or to match. On-chip run: about
 40 s after start-up (PR 21).
 """
+import functools
 import json
 import os
 import sys
@@ -92,6 +95,198 @@ def case(name, kernel_fn, ref_fn, args, tol):
     rec["s"] = round(time.time() - t0, 1)
     RESULTS.append(rec)
     print(json.dumps(rec), flush=True)
+
+
+# ---- the expert layer's grouped product, timed alone -----------------------
+def _parent_held_sorted(x, local, held, w, wg, wu, wd):
+    """The many-token path as it was before PR 34 (one 1,024-token chunk:
+    every assignment sorted, gathered and handed to `ragged_dot`)."""
+    N, K = local.shape
+    count = wg.shape[0]
+    key = jnp.where(held, local, count).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(count, dtype=key.dtype),
+                    axis=0, dtype=jnp.int32)
+    xs = x[(order // K)]
+    f32 = jnp.float32
+    g = jax.lax.ragged_dot(xs, wg, sizes, preferred_element_type=f32)
+    u = jax.lax.ragged_dot(xs, wu, sizes, preferred_element_type=f32)
+    ys = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(x.dtype), wd, sizes,
+                            preferred_element_type=f32)
+    where = jnp.zeros(N * K, jnp.int32).at[order].set(
+        jnp.arange(N * K, dtype=jnp.int32)).reshape(N, K)
+    yk = jnp.where(held[..., None], ys[where] * w[..., None], 0.0)
+    return yk.sum(axis=1)
+
+
+def _parent_layer(x, local, held, w, wg, wu, wd, chunk=1024):
+    N = x.shape[0]
+    if N <= chunk:
+        return _parent_held_sorted(x, local, held, w, wg, wu, wd)
+    parts = jax.lax.map(
+        lambda a: _parent_held_sorted(*a, wg, wu, wd),
+        tuple(a.reshape((N // chunk, chunk) + a.shape[1:])
+              for a in (x, local, held, w)))
+    return parts.reshape(N, -1)
+
+
+def _timed(fn, args, n=8, top=0):
+    """(median seconds of a compiled call after two warm calls, its
+    result); with `top`, also the device operations of three more calls
+    that took most time (seconds a call), from a profiler trace. Weights
+    go in as arguments: a closed-over array is compiled in as a
+    constant."""
+    exe = jax.jit(fn).lower(*args).compile()
+    for _ in range(2):
+        jax.block_until_ready(exe(*args))
+    ts = []
+    for _ in range(n):
+        t0 = time.perf_counter()
+        jax.block_until_ready(exe(*args))
+        ts.append(time.perf_counter() - t0)
+    seconds, out = sorted(ts)[len(ts) // 2], exe(*args)
+    if not top:
+        return seconds, out
+    import shutil
+    import tempfile
+    from chipbench import trace_reduce
+    where = tempfile.mkdtemp(prefix="grouped-trace-")
+    with jax.profiler.trace(where):
+        for _ in range(3):
+            jax.block_until_ready(exe(*args))
+    ops = trace_reduce.top_ops(trace_reduce.load_xplane(where), top)
+    shutil.rmtree(where, ignore_errors=True)
+    return seconds, out, [[k, round(v / 3, 6)] for k, v in ops]
+
+
+def grouped_main():
+    """The two serving cells' expert layers at their real widths,
+    bfloat16 operands: the parent's form, `ragged_dot` on the held prefix
+    alone, the two Pallas kernels at four tile heights, and the whole
+    layer (sort, gather, products, combine) a rung at a time. One JSON line a
+    timing, with its floor: held weights once over 819 GB/s or held-row
+    FLOPs over 197 TFLOP/s, whichever is larger. Non-zero exit where a
+    kernel's values leave the `ragged_dot` form's by more than 2e-2 of
+    their largest. `--only=axk1|kimi`
+    keeps one shape, `--rungs` skips the chunk's table."""
+    from paddle_tpu.nn.layer import moe
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    bf = jnp.bfloat16
+    out = []
+
+    def say(**rec):
+        rec = {k: (round(v, 6) if isinstance(v, float) else v)
+               for k, v in rec.items()}
+        out.append(rec)
+        print(json.dumps(rec), flush=True)
+
+    shapes = {"axk1": dict(H=7168, F=2048, count=12, routed=192, K=8,
+                           skew=0.0, rungs=(2048, 4096, 8192)),
+              "kimi": dict(H=2304, F=1024, count=128, routed=256, K=8,
+                           skew=0.5, rungs=(512, 1024, 2048))}
+    only = [a.split("=")[1] for a in sys.argv if a.startswith("--only=")]
+    for name, c in shapes.items():
+        if only and name not in only:
+            continue
+        H, F, count, K = c["H"], c["F"], c["count"], c["K"]
+        ks = jax.random.split(jax.random.key(7), 8)
+        ws = tuple(jax.random.normal(k, shape, bf) * 0.02 for k, shape in (
+            (ks[0], (count, H, F)), (ks[1], (count, H, F)),
+            (ks[2], (count, F, H))))
+        # picks: K distinct experts a token, expert e drawn with weight
+        # (e + 1) ** -skew (Gumbel top-k), in a random order of experts
+        logp = -c["skew"] * np.log(np.arange(1, c["routed"] + 1))
+        logp = np.random.default_rng(1).permutation(logp)
+
+        def case(N, seed):
+            k1, k2, k3 = jax.random.split(jax.random.key(seed), 3)
+            x = jax.random.normal(k1, (N, H), bf)
+            gum = jax.random.gumbel(k2, (N, c["routed"])) + jnp.asarray(logp)
+            local = jax.lax.top_k(gum, K)[1].astype(jnp.int32)
+            held = local < count
+            w = jax.random.uniform(k3, (N, K), jnp.float32)
+            return x, local, held, w
+
+        def floor(rows):
+            return max(3 * count * H * F * 2 / 819e9,
+                       3 * 2 * rows * H * F / 197e12)
+
+        def three_ragged(xs, sz, wg, wu, wd):
+            a = gm.grouped_swiglu_reference(xs, wg, wu, sz)
+            return jax.lax.ragged_dot(a, wd, sz,
+                                      preferred_element_type=jnp.float32)
+
+        def two_kernels(xs, sz, wg, wu, wd, kernel, tm=None):
+            # every eighth row onto a token, as the layer's sorted rows
+            token = jnp.arange(xs.shape[0], dtype=jnp.int32) // K
+            a = gm.grouped_swiglu(xs, wg, wu, sz, tm=tm, kernel=kernel)
+            return gm.grouped_matmul_add(
+                a, wd, sz, token, jnp.ones(xs.shape[0], jnp.float32),
+                jnp.zeros((xs.shape[0] // K, H), jnp.float32), tm=tm,
+                kernel=kernel)
+
+        # ---- one 1,024-token chunk ------------------------------------
+        if "--rungs" not in sys.argv:
+            x, local, held, w = case(1024, 11)
+            sizes = np.bincount(np.asarray(local)[np.asarray(held)],
+                                minlength=count)
+            rows = int(sizes.sum())
+            say(shape=name, what="chunk", tokens=1024, held_rows=rows,
+                group_min=int(sizes.min()), group_max=int(sizes.max()),
+                floor_s=floor(rows))
+            t, _ = _timed(_parent_held_sorted, (x, local, held, w, *ws))
+            say(shape=name, what="(a) parent layer, chunk", s=t)
+            prefix = -(-rows // 512) * 512
+            xs = jax.random.normal(ks[3], (8192, H), bf)
+            sz = jnp.asarray(sizes, jnp.int32)
+            t, _ = _timed(three_ragged, (xs, sz, *ws))
+            say(shape=name, what="(a) three ragged_dot, 8192 rows in", s=t)
+            t, _ = _timed(three_ragged, (xs[:prefix], sz, *ws))
+            say(shape=name, what="(b) three ragged_dot, held prefix in",
+                prefix=prefix, s=t)
+            want = np.asarray(jax.jit(functools.partial(
+                two_kernels, kernel="xla"))(xs, sz, *ws))
+            for tm in (64, 128, 256, 512):
+                t, got = _timed(functools.partial(
+                    two_kernels, kernel="pallas", tm=tm), (xs, sz, *ws))
+                say(shape=name, what="(c) pallas swiglu + down-and-add",
+                    tm=tm, s=t, rel_err=float(
+                        np.abs(np.asarray(got) - want).max()
+                        / max(np.abs(want).max(), 1e-9)))
+
+        # ---- (d) the whole layer, a rung at a time ---------------------
+        for N in c["rungs"]:
+            x, local, held, w = case(N, 100 + N)
+            rows = int(np.asarray(held).sum())
+            say(shape=name, what="rung", tokens=N, held_rows=rows,
+                floor_s=floor(rows))
+            args = (x, local, held, w, *ws)
+            t, want = _timed(_parent_layer, args, 5)
+            say(shape=name, what="(d) parent layer", tokens=N, s=t)
+            want = np.asarray(want)
+            rows_b = moe._block_rows(N * K, count, c["routed"])
+            tile = gm.row_tile(rows_b, count)
+            for tm in (tile, 384 - tile):
+                keep = gm.row_tile
+                gm.row_tile = lambda rows, groups: tm
+                fn = lambda *a: moe._held_grouped(*a, c["routed"])
+                t, got, ops = _timed(fn, args, 5, top=8)
+                gm.row_tile = keep
+                say(shape=name, what="(d) grouped layer", tokens=N,
+                    block=rows_b, tm=tm, s=t, rel_err=float(
+                        np.abs(np.asarray(got) - want).max()
+                        / max(np.abs(want).max(), 1e-9)), ops=ops)
+    if os.path.isdir("chiprun_out"):
+        with open("chiprun_out/grouped-%s.json" % "-".join(only or ["all"]),
+                  "w") as f:
+            json.dump(out, f, indent=1)
+    bad = [r for r in out if r.get("rel_err", 0.0) > 2e-2]
+    print("SUMMARY", len(out), "lines; off by more than 2e-2:", bad)
+    return 1 if bad else 0
+
+
+if "--grouped" in sys.argv:
+    sys.exit(grouped_main())
 
 
 # ---- flash attention: fwd + both backward schemes -------------------------

@@ -165,15 +165,26 @@ class MoELayer(Layer):
 # routes over all of them and computes the part of the result its own
 # experts give; assignments to experts held elsewhere are left out (on a
 # one-chip share nothing stands in for the absent chips or their
-# exchange). No capacity, no dropped token: the many-token path sorts the
-# held assignments by expert and runs one grouped matrix product per
-# projection (`jax.lax.ragged_dot`, which XLA lowers to a tiled grouped
-# kernel on a TPU), the few-token path (a decode step) applies every held
-# expert to every row under a zero/non-zero combine weight. Both are
-# exact; which one runs follows from the static token count.
+# exchange). No capacity, no dropped token. Which of two exact paths
+# runs follows from the static token count:
+#
+# * few tokens (a decode step): every held expert over every row under
+#   a zero/non-zero combine weight (`_held_dense`).
+# * many tokens (a prefill): the assignments are sorted by expert once
+#   a call, those that are held here and live first (`_held_grouped`).
+#   That held prefix, and nothing after it, is walked in blocks of
+#   `_block_rows` sorted rows under a trip count of ceil(held rows /
+#   block): gather the block's rows, one grouped product for gate and
+#   up with SwiGLU, one for down that adds each row, times its combine
+#   weight in float32, onto its token (`ops/pallas/grouped_matmul.py`:
+#   on a TPU Pallas kernels whose grid ends at the last tile a group
+#   touches and that read an expert's weights once a block; off it
+#   XLA's `ragged_dot` and a scatter). The work follows the held rows
+#   (all of them in the worst case: no row is ever dropped), the
+#   temporaries the block.
 
 DENSE_MAX_TOKENS = 256      # at or under this many tokens: the dense path
-SORT_CHUNK_TOKENS = 1024    # the sorted path's working set, in tokens
+BLOCK_ROWS = 16384          # the most sorted rows a block of the grouped path
 
 
 def route_sigmoid_grouped(x, router_w, *, top_k, n_group=1, topk_group=1,
@@ -230,27 +241,53 @@ def _held_dense(x, local, held, w, wg, wu, wd):
     return jnp.einsum("enf,efh->nh", a, wd, preferred_element_type=f32)
 
 
-def _held_sorted(x, local, held, w, wg, wu, wd):
-    """The held assignments sorted by expert, one grouped product per
-    projection, results gathered back per (token, pick)."""
+def _block_rows(A, count, n_routed):
+    """Sorted rows a block of the grouped path: twice what even routing
+    would hold here of A assignments, in whole row tiles, at most
+    `BLOCK_ROWS`. (At half of the experts held that is every assignment:
+    one block, and no loop around it.)"""
+    rows = min(A, 2 * A * count // n_routed)
+    return min(BLOCK_ROWS, -(-rows // 512) * 512)
+
+
+def _held_grouped(x, local, held, w, wg, wu, wd, n_routed):
+    """The held assignments sorted by expert, walked block by block:
+    grouped products over the block's rows, each row's result times its
+    combine weight (in float32) added onto its token."""
+    from ...ops.pallas import grouped_matmul as gm
     N, K = local.shape
     count = wg.shape[0]
-    f32 = jnp.float32
+    A = N * K
+    rows = _block_rows(A, count, n_routed)
+    tm = gm.row_tile(rows, count)
     key = jnp.where(held, local, count).reshape(-1)             # [A]
-    order = jnp.argsort(key, stable=True)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    order = jnp.pad(order, (0, -(-A // rows) * rows - A))
     sizes = jnp.sum(key[:, None] == jnp.arange(count, dtype=key.dtype),
                     axis=0, dtype=jnp.int32)                    # [count]
-    xs = x[(order // K)]                                        # [A, H]
-    g = jax.lax.ragged_dot(xs, wg, sizes, preferred_element_type=f32)
-    u = jax.lax.ragged_dot(xs, wu, sizes, preferred_element_type=f32)
-    ys = jax.lax.ragged_dot(_swiglu_f32(g, u).astype(x.dtype), wd, sizes,
-                            preferred_element_type=f32)         # [A, H]
-    where = jnp.zeros(N * K, jnp.int32).at[order].set(
-        jnp.arange(N * K, dtype=jnp.int32)).reshape(N, K)
-    # rows past the last group belong to no expert: whatever the
-    # grouped product left there is masked, never multiplied
-    yk = jnp.where(held[..., None], ys[where] * w[..., None], 0.0)
-    return yk.sum(axis=1)
+    ends = jnp.cumsum(sizes)
+    total = ends[-1]
+    wflat = w.reshape(-1)
+
+    def block(b, y):
+        start = b * rows
+        pick = jax.lax.dynamic_slice(order, (start,), (rows,))
+        token = pick // K
+        here = jnp.clip(ends - start, 0, rows) \
+            - jnp.clip(ends - sizes - start, 0, rows)           # [count]
+        a = gm.grouped_swiglu(x[token], wg, wu, here, tm=tm)
+        # rows past the last group belong to no expert: whatever the
+        # first product left there is never multiplied, never added
+        return gm.grouped_matmul_add(a, wd, here, token, wflat[pick], y,
+                                     tm=tm)
+
+    # (whole sublane tiles of tokens: the kernel adds onto rows in VMEM)
+    y = jnp.zeros((-(-N // 8) * 8, x.shape[1]), jnp.float32)
+    if A <= rows:       # one block at most: with no held row it adds nothing
+        y = block(0, y)
+    else:
+        y = jax.lax.fori_loop(0, (total + rows - 1) // rows, block, y)
+    return y if y.shape[0] == N else y[:N]
 
 
 def routed_experts(x, router_w, wg, wu, wd, *, top_k, n_group=1,
@@ -285,17 +322,7 @@ def routed_experts(x, router_w, wg, wu, wd, *, top_k, n_group=1,
     N = x.shape[0]
     if N <= DENSE_MAX_TOKENS:
         return _held_dense(x, local, mine, w, wg, wu, wd), hits
-    chunk = SORT_CHUNK_TOKENS
-    if N <= chunk or N % chunk:
-        return _held_sorted(x, local, mine, w, wg, wu, wd), hits
-
-    def one(args):
-        return _held_sorted(*args, wg, wu, wd)
-
-    parts = jax.lax.map(one, tuple(
-        a.reshape((N // chunk, chunk) + a.shape[1:])
-        for a in (x, local, mine, w)))
-    return parts.reshape(N, -1), hits
+    return _held_grouped(x, local, mine, w, wg, wu, wd, n_routed), hits
 
 
 class RoutedExperts(Layer):

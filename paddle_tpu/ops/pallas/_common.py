@@ -33,13 +33,16 @@ def interpret() -> bool:
         f"(JAX_PLATFORMS=cpu) for interpret mode.")
 
 
-def compiler_params(*dimension_semantics: str) -> dict:
-    """``pallas_call`` kwargs naming the grid's dimension semantics for
-    Mosaic; interpret mode takes none."""
+def compiler_params(*dimension_semantics: str,
+                    vmem_limit_bytes: int | None = None) -> dict:
+    """``pallas_call`` kwargs naming the grid's dimension semantics (and
+    a VMEM allowance above the compiler's default) for Mosaic;
+    interpret mode takes none."""
     if interpret():
         return {}
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=dimension_semantics)}
+        dimension_semantics=dimension_semantics,
+        vmem_limit_bytes=vmem_limit_bytes)}
 
 
 def mxu_dtype():

@@ -259,8 +259,9 @@ def test_a_share_matches_the_plain_loop_on_every_path(path, monkeypatch):
         monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 10 ** 9)
     else:
         monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0)
-        monkeypatch.setattr(moe, "SORT_CHUNK_TOKENS",
-                            100 if path == "chunked" else 10 ** 9)
+        # "chunked": the 450 held rows of 1,200 in four blocks
+        monkeypatch.setattr(moe, "BLOCK_ROWS",
+                            128 if path == "chunked" else 10 ** 9)
     live = jnp.arange(x.shape[0]) < 280
     y, hits = moe.routed_experts(jnp.asarray(h), rw, *held_ws, held=(4, 6),
                                  live=live, **ROUTING)
@@ -273,7 +274,7 @@ def test_a_share_matches_the_plain_loop_on_every_path(path, monkeypatch):
 
 def test_no_token_is_dropped_when_routing_piles_onto_one_expert(monkeypatch):
     """A router that sends every token to experts 5 and 6 first (two,
-    so that their group always stays eligible): the sorted path computes
+    so that their group always stays eligible): the grouped path computes
     all of them (a capacity-C dispatch would drop all but C)."""
     monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0)
     rng = np.random.default_rng(5)
@@ -285,6 +286,170 @@ def test_no_token_is_dropped_when_routing_piles_onto_one_expert(monkeypatch):
     assert hits[1] == hits[2] == 400           # every token, none dropped
     want, _, _ = _plain_routed(x, rw, ws, (4, 4))
     np.testing.assert_allclose(np.asarray(y), want, rtol=2e-5, atol=2e-5)
+
+
+# ---- the many-token path: grouped products over the held rows only -------
+
+
+def _grouped_case(count, n_routed, N, K=4, seed=7, H=32, F=16):
+    """Random rows, picks (K distinct experts a token) and weights; the
+    first `count` of `n_routed` experts are held."""
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(N, H)), jnp.float32)
+    local = jnp.asarray(np.stack([rng.permutation(n_routed)[:K]
+                                  for _ in range(N)]), jnp.int32)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, size=(N, K)), jnp.float32)
+    ws = [jnp.asarray(rng.normal(size=shape) * 0.2, jnp.float32)
+          for shape in ((count, H, F), (count, H, F), (count, F, H))]
+    return x, local, w, ws
+
+
+def _per_token_loop(x, local, held, w, ws):
+    """y[n] = sum over n's held picks of w * Expert(x[n]), a token and a
+    pick at a time in numpy float64."""
+    x, local, held, w = (np.asarray(a) for a in (x, local, held, w))
+    wg, wu, wd = (np.asarray(a, np.float64) for a in ws)
+    y = np.zeros((x.shape[0], wd.shape[2]))
+    for n, k in zip(*np.nonzero(held)):
+        e = local[n, k]
+        g, u = x[n] @ wg[e], x[n] @ wu[e]
+        y[n] += w[n, k] * ((g / (1 + np.exp(-g)) * u) @ wd[e])
+    return y
+
+
+GROUPED_CASES = {
+    # name: (held, routed, tokens, rows a block)
+    "12-held-of-48": (12, 48, 300, 10 ** 9),
+    "128-held-of-256": (128, 256, 300, 10 ** 9),
+    "12-held-three-blocks": (12, 48, 300, 128),
+    "128-held-five-blocks": (128, 256, 300, 128),
+    "tokens-a-multiple-of-no-block": (12, 48, 331, 256),
+}
+
+
+@pytest.mark.parametrize("name", GROUPED_CASES)
+def test_the_grouped_path_equals_the_dense_path_and_a_loop(name, monkeypatch):
+    count, n_routed, N, block = GROUPED_CASES[name]
+    monkeypatch.setattr(moe, "BLOCK_ROWS", block)
+    x, local, w, ws = _grouped_case(count, n_routed, N)
+    held = local < count
+    got = np.asarray(moe._held_grouped(x, local, held, w, *ws, n_routed))
+    dense = np.asarray(moe._held_dense(x, local, held, w, *ws))
+    np.testing.assert_allclose(got, dense, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got, _per_token_loop(x, local, held, w, ws),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("which", ["every-pick-held", "none-held"])
+@pytest.mark.parametrize("block", [256, 10 ** 9])
+@pytest.mark.parametrize("n_routed", [8, 64])
+def test_the_grouped_path_at_its_two_ends(which, block, n_routed,
+                                          monkeypatch):
+    """Every pick of every token held here (the worst case a dropless
+    layer must compute: all N x K rows, in one block with no loop around
+    it where the layer holds every expert, in three of 512 rows where it
+    was told it holds 8 of 64, in five of 256), and none (no block runs:
+    the result is zeros, exactly)."""
+    monkeypatch.setattr(moe, "BLOCK_ROWS", block)
+    x, local, w, ws = _grouped_case(8, 8, 300)
+    held = jnp.full(local.shape, which == "every-pick-held")
+    got = np.asarray(moe._held_grouped(x, local, held, w, *ws, n_routed))
+    if which == "none-held":
+        assert not got.any()
+    else:
+        assert np.abs(got).min(axis=1).max() > 0      # every token
+        np.testing.assert_allclose(
+            got, _per_token_loop(x, local, held, w, ws), rtol=2e-5,
+            atol=2e-5)
+
+
+def test_dead_rows_are_neither_computed_nor_counted(monkeypatch):
+    """Rows that are padding (`live` false) sort behind the held rows:
+    with NaN for input they would poison whatever read them, they add
+    no hit, and their result is zero; a block's trip count follows the
+    live held rows alone."""
+    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0)
+    monkeypatch.setattr(moe, "BLOCK_ROWS", 128)
+    x, rw, held_ws, ws = _routed_case(np.random.default_rng(8), held=(4, 6))
+    live = jnp.arange(x.shape[0]) < 200
+    y, hits = moe.routed_experts(x, rw, *held_ws, held=(4, 6), live=live,
+                                 **ROUTING)
+    poisoned = jnp.where(live[:, None], x, jnp.nan)
+    # the router sees NaN rows too: route on x, compute on the poisoned
+    idx, w = moe.route_sigmoid_grouped(x, rw, **ROUTING)
+    local = idx - 4
+    mine = (local >= 0) & (local < 6) & live[:, None]
+    y2 = np.asarray(moe._held_grouped(poisoned, local, mine, w, *held_ws,
+                                      16))
+    assert np.isfinite(y2).all()
+    np.testing.assert_array_equal(y2, np.asarray(y))
+    assert not y2[200:].any()
+    assert int(hits.sum()) == int(mine.sum())
+
+
+@pytest.mark.parametrize("case", ["swiglu", "add", "layer"])
+def test_the_kernel_body_equals_the_xla_form(case, monkeypatch):
+    """The Pallas kernel's body, run by the interpreter: groups that
+    straddle tiles, an empty group, rows past the last group (left
+    unwritten, so compared up to the last group only)."""
+    from paddle_tpu.ops.pallas import _common, grouped_matmul as gm
+    rng = np.random.default_rng(9)
+    if case == "layer":
+        monkeypatch.setattr(_common, "on_tpu", lambda: True)
+        monkeypatch.setattr(moe, "BLOCK_ROWS", 256)    # 300 rows: two
+        monkeypatch.setattr(gm, "row_tile", lambda rows, groups: 16)
+        x, local, w, ws = _grouped_case(12, 48, 300)
+        held = local < 12
+        args = (x, local, held, w, *ws)
+        # (a fresh function a trace: the tracing cache does not see
+        # what a monkeypatch changed)
+        assert gm.KERNEL_NAME in str(
+            jax.make_jaxpr(lambda *a: moe._held_grouped(*a, 48))(*args))
+        got = np.asarray(moe._held_grouped(*args, 48))
+        monkeypatch.setattr(_common, "on_tpu", lambda: False)
+        assert "pallas_call" not in str(
+            jax.make_jaxpr(lambda *a: moe._held_grouped(*a, 48))(*args))
+        want = np.asarray(moe._held_grouped(*args, 48))
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+        return
+    sizes = jnp.asarray([10, 0, 37, 1, 23], jnp.int32)       # 71 of 96 rows
+    x = jnp.asarray(rng.normal(size=(96, 32)), jnp.float32)
+    wa, wb = (jnp.asarray(rng.normal(size=(5, 32, 256)) * 0.2, jnp.float32)
+              for _ in range(2))
+    if case == "add":       # rows onto 40 tokens, some of them twice
+        token = jnp.asarray(rng.integers(0, 40, 96), jnp.int32)
+        scale = jnp.asarray(rng.uniform(0.1, 1, 96), jnp.float32)
+        y = jnp.asarray(rng.normal(size=(40, 256)), jnp.float32)
+        got = gm.grouped_matmul_add(x, wa, sizes, token, scale, y, tm=16,
+                                    kernel="pallas")
+        want = gm.grouped_matmul_add(x, wa, sizes, token, scale, y,
+                                     kernel="xla")
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+        none = gm.grouped_matmul_add(x, wa, sizes * 0, token, scale, y,
+                                     tm=16, kernel="pallas")
+        np.testing.assert_array_equal(np.asarray(none), np.asarray(y))
+        return
+    got = gm.grouped_swiglu(x, wa, wb, sizes, tm=16, kernel="pallas")
+    want = gm.grouped_swiglu(x, wa, wb, sizes, kernel="xla")
+    np.testing.assert_allclose(np.asarray(got)[:71], np.asarray(want)[:71],
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("tokens,count,n_routed,rows,tile", [
+    # kimi's share, its three rungs: every assignment in one block
+    (512, 128, 256, 4096, 128), (1024, 128, 256, 8192, 128),
+    (2048, 128, 256, 16384, 128),
+    (8192, 128, 256, 16384, 128),           # a long forward: the cap
+    # axk1's share: twice the sixteenth even routing holds here
+    (2048, 12, 192, 2048, 128), (4096, 12, 192, 4096, 256),
+    (8192, 12, 192, 8192, 256),
+    (300, 6, 16, 2048, 256)])
+def test_block_and_tile_follow_the_static_shapes(tokens, count, n_routed,
+                                                 rows, tile):
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    assert moe._block_rows(tokens * 8, count, n_routed) == rows
+    assert gm.row_tile(rows, count) == tile
 
 
 def test_routed_layer_is_a_layer_of_the_framework():

@@ -345,3 +345,94 @@ def test_gpt_pools_are_written_in_place_on_v5e(pool_pass_probe, program):
                for op, is_scatter in got["made"]), got["made"]
     assert got["alias"] >= pool_pass_probe["pool_bytes"]
     assert got["temp"] < pool_pass_probe["pool_bytes"] / 4, got
+
+
+# -- the serving cells' prefill programs fit where the parent's did ----------
+
+# temp_size_in_bytes of the parent of PR 34 (commit 8d7ae6f: `ragged_dot`
+# over every assignment, 1,024 tokens at a time), the same compile
+PARENT_PREFILL_TEMP = {
+    ("kimi-linear-share2", 512): 93_569_536,
+    ("kimi-linear-share2", 1024): 251_769_344,
+    ("kimi-linear-share2", 2048): 798_129_152,
+    ("axk1-share16", 2048): 630_308_864,
+    ("axk1-share16", 4096): 772_045_824,
+    ("axk1-share16", 8192): 1_412_020_736,
+}
+SLOTS = {"kimi-linear-share2": 256, "axk1-share16": 67}
+
+
+@pytest.fixture(scope="module")
+def as_on_a_tpu():
+    """Code that asks `_common.on_tpu()` answers as the chip would (the
+    chipless compiler runs under the CPU backend)."""
+    from paddle_tpu.ops.pallas import _common
+    keep = _common.on_tpu, _common.interpret
+    _common.on_tpu, _common.interpret = (lambda: True), (lambda: False)
+    yield
+    _common.on_tpu, _common.interpret = keep
+
+
+@pytest.mark.parametrize("config,rung", list(PARENT_PREFILL_TEMP))
+def test_prefill_temporaries_are_no_larger_than_the_parents(
+        v5e_chip, as_on_a_tpu, config, rung):
+    """Slot sizing reads the step alone, so a prefill that grew would
+    fail at run time, at 14.1 of 15.75 GB: every prefill rung of both
+    serving configurations, at the benchmark's sizes, compiled for the
+    chip; the expert layers run the grouped kernel (three calls a layer:
+    gate and up fused, down; twice in the text, the loop's body being
+    there twice) and nothing of `ragged_dot`."""
+    import jax.numpy as jnp
+
+    sys.path.insert(0, REPO)
+    from chipbench import harness
+    from paddle_tpu.inference import model_kinds
+    from paddle_tpu.ops.pallas.grouped_matmul import KERNEL_NAME
+
+    _, sizes, family = harness.load_config(harness.load_benchmark(), config)
+    kind = model_kinds.for_config(family._config(sizes), None)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=v5e_chip), tree)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=v5e_chip)
+
+    pt, slots = 16, SLOTS[config]
+    by_slot = getattr(kind, "slot_state", False)
+    pools = on_chip(kind.pools_sds(
+        slots * (sizes["max_seq_len"] // pt) + 1, pt, kind.pool_dtype(None),
+        *((slots,) if by_slot else ())))
+    with jax.default_matmul_precision("default"):
+        exe = jax.jit(kind.prefill_fn(pt), donate_argnums=(1,)).lower(
+            on_chip(family.param_shapes(sizes)), pools, ints(1, rung),
+            ints(1, rung // pt), ints(1),
+            *((ints(),) if by_slot else ())).compile()
+    text = exe.as_text()
+    assert KERNEL_NAME in text and "ragged-dot" not in text
+    temp = exe.memory_analysis().temp_size_in_bytes
+    assert temp <= PARENT_PREFILL_TEMP[config, rung], temp
+
+
+def test_the_grouped_layer_compiles_for_tokens_of_no_whole_tile(
+        v5e_chip, as_on_a_tpu):
+    """A full-sequence forward hands the expert layer any token count
+    (777 here, not a multiple of the 8 rows a float32 tile holds): the
+    many-token path pads its accumulator, the kernels compile."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.layer import moe
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    N, H, F, count = 777, 512, 256, 4
+    bf = jnp.bfloat16
+    exe = jax.jit(lambda *a: moe._held_grouped(*a, 16)).lower(
+        on_chip((N, H), bf), on_chip((N, 8), jnp.int32),
+        on_chip((N, 8), jnp.bool_), on_chip((N, 8), jnp.float32),
+        on_chip((count, H, F), bf), on_chip((count, H, F), bf),
+        on_chip((count, F, H), bf)).compile()
+    assert exe.output_shardings is not None
+    assert "moe_grouped_matmul_add" in exe.as_text()

@@ -468,6 +468,27 @@ def test_without_a_bias_the_routed_layer_is_what_it_was():
     np.testing.assert_array_equal(np.asarray(n0), np.asarray(n1))
 
 
+def test_many_tokens_take_the_new_path_and_few_tokens_the_old(monkeypatch):
+    """Above `DENSE_MAX_TOKENS` the layer lowers to another program than
+    its parent did (PR 34: grouped products over the held rows in place
+    of `ragged_dot` over every assignment), with the dense path's
+    values and hits."""
+    x, rw, ws, _ = _routed_case(np.random.default_rng(7), N=320)
+    kw = dict(top_k=4, n_group=4, topk_group=2, norm_topk_prob=True,
+              scale=2.5, held=(4, 8))
+    held = [a[4:12] for a in ws]
+    text = jax.jit(lambda *a: moe.routed_experts(*a, **kw)).lower(
+        x, rw, *held).compiler_ir(dialect="stablehlo").operation.get_asm(
+            enable_debug_info=False)
+    assert hashlib.sha256(text.encode()).hexdigest() != PARENT["routed.many"]
+    y, n = moe.routed_experts(x, rw, *held, **kw)
+    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 10 ** 9)
+    y0, n0 = jax.jit(lambda *a: moe.routed_experts(*a, **kw))(x, rw, *held)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y0), rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(n), np.asarray(n0))
+
+
 # ------------------------------------------------------ what it refuses
 
 
@@ -544,6 +565,9 @@ PARENT = {   # noqa: E501
     "axk1.step": "09d4c15dddc75df5fab26cb927ae5aa5195409c3b7c27cf91d5ea628b224d805",
     "axk1.prefill": "79f4a063381775a85bfeb4c732d935c139622ef36c6c13c841e5da69c0518209",
     "routed": "852c886fde95e9fc44e03cb1a0bca956673b886d27945d7a792c2e18b4620995",
+    # the parent of PR 34 (the expert layer's many-token path changed)
+    "kimi_linear.step": "ab2b0c93490c7dd8be31a2c317fa7c406f96f5e9f7cf79facd2cba67c4c8c5d6",
+    "routed.many": "f48cd35e3476a3a389d7a35611bbae5dc01d5c118f056bd0ecc088606f12589a",
 }
 
 
@@ -571,16 +595,29 @@ def _seam_texts():
                 params, pools, *rest).compiler_ir(
                     dialect="stablehlo").operation.get_asm(
                         enable_debug_info=False)
+    # this kind's own step (PR 34: the expert layer's many-token path
+    # changed; a step is few tokens and must not)
+    model, _ = build(seed=13)
+    kind = model_kinds.for_model(model)
+    rows = jax.ShapeDtypeStruct((2,), i32)
+    out["kimi_linear.step"] = jax.jit(
+        kind.step_fn(4), donate_argnums=(1,)).lower(
+            framework.param_arrays(model),
+            kind.pools_sds(9, 4, kind.pool_dtype(None), 2),
+            jax.ShapeDtypeStruct((2, 4), i32), rows, rows, rows).compiler_ir(
+                dialect="stablehlo").operation.get_asm(
+                    enable_debug_info=False)
     return out
 
 
 @pytest.mark.parametrize("program", ["gpt.step", "gpt.prefill", "axk1.step",
-                                     "axk1.prefill"])
+                                     "axk1.prefill", "kimi_linear.step"])
 def test_the_seam_leaves_the_other_kinds_programs_text_equal(program):
     """No slot argument is threaded through kinds that have no such
     state, and the shared MLA / FFN / routing functions trace for
     `axk1` exactly what they traced: the step and the prefill of `gpt`
-    and of `axk1`, through their kinds, lower to the parent's text."""
+    and of `axk1`, through their kinds, lower to the parent's text (and
+    since PR 34 this kind's own step to its parent's)."""
     got = hashlib.sha256(_seam_texts()[program].encode()).hexdigest()
     assert got == PARENT[program], (program, got)
 
