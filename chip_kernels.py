@@ -4,6 +4,9 @@
     python chip_kernels.py --aot    # in the sandbox: chipless v5e compile only
     python chip_kernels.py --grouped  # on the chip: the expert layer's grouped
                                       # product timed alone (see grouped_main)
+    python chip_kernels.py --gqa    # on the chip: the grouped-query decode
+                                    # reader and the banded flash forward
+                                    # timed alone (see gqa_main)
 
 The training path's kernels of ops/pallas/ (flash attention forward and
 both backward schemes, fused linear + cross-entropy) at the widths the
@@ -287,6 +290,104 @@ def grouped_main():
 
 if "--grouped" in sys.argv:
     sys.exit(grouped_main())
+
+
+def gqa_main():
+    """The `afmoe` kind's two kernels at the long-document cell's shapes
+    (48 query heads over 8 K/V heads of 128, pages of 128 tokens,
+    bfloat16), each timed alone with its floor, one JSON line a timing:
+
+    * the paged grouped-query decode reader over 26 rows, Pallas against
+      the `jnp.take` form: a window layer's ring (32 pages a slot, four
+      rows in five full and wrapping, the fifth at 1.6k rows) and the
+      full layer's table (128 pages, 9.3k to 15.6k rows a row), at 4
+      and 8 pages a grid cell; the floor is each live K and V row once
+      over 819 GB/s;
+    * the flash forward over one sequence at the 16,384 and 2,048
+      rungs: causal, and under a window of 4,096 (the band's blocks
+      alone); the floor is the band's FLOPs over 197 TFLOP/s.
+
+    Non-zero exit where a kernel leaves its plain form by more than
+    2e-2 of the largest value."""
+    from paddle_tpu.ops.pallas import gqa_attention as gq
+    os.makedirs("chiprun_out", exist_ok=True)
+    out = []
+
+    def say(**rec):
+        out.append(rec)
+        print(json.dumps(rec), flush=True)
+
+    bf = jnp.bfloat16
+    B, Hq, Hkv, D, pt = 26, 48, 8, 128, 128
+    scale = D ** -0.5
+    q = arr((B, Hq, D), bf)
+    pages_per_step = gq.PAGES_PER_STEP
+    for name, W, lens in (
+            ("ring", 32, [4096 if b % 5 else 1600 for b in range(B)]),
+            ("full", 128, [9344 + (b * 6272) // (B - 1) for b in range(B)])):
+        P = (B + 1) * W if name == "ring" else B * W + 1
+        kp, vp = arr((P, pt, Hkv * D), bf), arr((P, pt, Hkv * D), bf)
+        first = 0 if name == "ring" else 1
+        tables = jnp.asarray(first + np.arange(B * W).reshape(B, W),
+                             jnp.int32)
+        lengths = jnp.asarray(lens, jnp.int32)
+        floor = sum(lens) * Hkv * D * 2 * 2 / 819e9
+        t_x, ref = _timed(functools.partial(
+            gq.paged_gqa_decode_attention, scale=scale, kernel="xla"),
+            (q, kp, vp, tables, lengths))
+        say(kernel="gqa_decode", what=name, form="xla_take", seconds=t_x,
+            floor_s=floor, share=floor / t_x, rows=sum(lens))
+        for G in (4, 8):
+            gq.PAGES_PER_STEP = G
+            t_p, got = _timed(functools.partial(
+                gq.paged_gqa_decode_attention, scale=scale,
+                kernel="pallas"), (q, kp, vp, tables, lengths))
+            err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                        - ref.astype(jnp.float32)))
+                        / jnp.max(jnp.abs(ref.astype(jnp.float32))))
+            say(kernel="gqa_decode", what=name, form=f"pallas_G{G}",
+                seconds=t_p, floor_s=floor, share=floor / t_p, rel_err=err)
+        gq.PAGES_PER_STEP = pages_per_step
+    for T, window in ((16384, None), (16384, 4096), (2048, 4096),
+                      (2048, 512)):
+        qs, ks, vs = (arr((1, T, h, D), bf) for h in (Hq, Hkv, Hkv))
+        fn = functools.partial(fa.flash_attention_forward, causal=True,
+                               scale=scale, window=window)
+        t, got = _timed(fn, (qs, ks, vs))
+        bq, bk = fa._block_sizes(T, D)
+        w = window if window is not None and window < T else None
+        cells = sum(
+            (i * bq + bq - 1) // bk
+            - (max(i * bq - (w - 1), 0) // bk if w else 0) + 1
+            for i in range(T // bq))
+        flops = 4 * Hq * cells * bq * bk * D
+        rec = dict(kernel="flash_fwd", T=T, window=window, seconds=t,
+                   block_flops=flops, floor_s=flops / 197e12,
+                   share=flops / 197e12 / t)
+        if T == 2048:       # small enough for the masked plain form
+            kr, vr = (jnp.repeat(a, Hq // Hkv, axis=2) for a in (ks, vs))
+            s = jnp.einsum("bqhd,bkhd->bhqk", qs, kr,
+                           preferred_element_type=jnp.float32) * scale
+            t_ = jnp.arange(T)
+            seen = t_[:, None] >= t_[None, :]
+            if w:
+                seen = seen & (t_[:, None] - t_[None, :] < w)
+            p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+            ref = jnp.einsum("bhqk,bkhd->bqhd", p.astype(bf), vr,
+                             preferred_element_type=jnp.float32)
+            rec["rel_err"] = float(
+                jnp.max(jnp.abs(got.astype(jnp.float32) - ref))
+                / jnp.max(jnp.abs(ref)))
+        say(**rec)
+    with open("chiprun_out/gqa-kernels.json", "w") as f:
+        json.dump(out, f, indent=1)
+    bad = [r for r in out if r.get("rel_err", 0.0) > 2e-2]
+    print("SUMMARY", len(out), "lines; off by more than 2e-2:", bad)
+    return 1 if bad else 0
+
+
+if "--gqa" in sys.argv:
+    sys.exit(gqa_main())
 
 
 # ---- flash attention: fwd + both backward schemes -------------------------

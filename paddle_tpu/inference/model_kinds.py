@@ -51,7 +51,9 @@ the request. Who owns a slot's state when:
 
 `slot_bytes()` of such a kind counts the state with the pages of a
 slot; `state_bytes(slots)` is the state alone. The `gpt` and `axk1`
-kinds have no such state and their signatures carry no slot.
+kinds have no such state and their signatures carry no slot. State by
+slot need not be a recurrence: the `afmoe` kind keeps CACHE ROWS so, a
+ring of the last `sliding_window` positions of each window layer.
 
 Kinds: `gpt` (`GPTKind`: a K and a V pool, each one array a layer `[P,
 page_tokens, heads * head_dim]`, float32 or int8; its programs are the
@@ -64,9 +66,15 @@ compiled step writes rows into the arrays it was given and
 `memory.page_allocator`'s page ops serve either. `kimi_linear`
 (`KimiLinearKind`, an `AXK1Kind` with slot state) holds latent pages for
 its MLA layers and, by slot, the recurrent and convolution state of its
-KDA layers (`models.kimi_linear.kimi_linear_paged_fns`). The manifest of a
+KDA layers (`models.kimi_linear.kimi_linear_paged_fns`). `afmoe`
+(`AfmoeKind`, slot state too) holds K and V pages, bfloat16, for its
+full-attention layers and, by slot, a ring of `sliding_window` K and V
+rows for each window layer, in pools of the same page shape
+(`models.afmoe.afmoe_paged_fns`). The manifest of a
 `save_for_decode` artifact names its kind under `"model_kind"`; one
-without the key is a GPT.
+without the key is a GPT. Each kind names its config and its model
+class (`config_cls`, `model_cls`): `for_config` and `for_model` look
+both up in `KINDS`.
 """
 from __future__ import annotations
 
@@ -83,9 +91,11 @@ import jax.numpy as jnp
 
 from ..core import flags as _flags
 from ..memory.page_allocator import copy_page
+from ..models.afmoe import (Afmoe, AfmoeConfig, afmoe_paged_fns,
+                            afmoe_pools_sds)
 from ..models.axk1 import (AXK1, AXK1Config, axk1_paged_fns,
                            latent_pools_sds)
-from ..models.gpt import GPTConfig, gpt_paged_fns
+from ..models.gpt import GPT, GPTConfig, gpt_paged_fns
 from ..models.kimi_linear import (KimiLinear, KimiLinearConfig,
                                   kimi_linear_paged_fns,
                                   kimi_linear_pools_sds)
@@ -159,6 +169,8 @@ class GPTKind:
     pool into another layout and back, each step)."""
 
     name = "gpt"
+    config_cls = GPTConfig
+    model_cls = GPT
 
     def __init__(self, cfg: GPTConfig, eps: Optional[float] = None):
         self.cfg = cfg
@@ -250,6 +262,8 @@ class AXK1Kind:
     name = "axk1"
     DEFAULT_PAGE_TOKENS = 128   # 640 bfloat16 lanes a row: 160 KB a page
     config_cls = AXK1Config
+    model_cls = AXK1
+    pages = "latent pages"      # what its refusals call a page
     paged_fns = staticmethod(axk1_paged_fns)
     roadmap = "R3"              # where what it refuses is queued
 
@@ -287,13 +301,14 @@ class AXK1Kind:
             raise unsupported(self.name, "speculative decoding "
                               "(SpecDecodeEngine)", self.roadmap)
         if kv_dtype not in (None, self.cfg.dtype):
-            raise unsupported(self.name, f"kv_dtype={kv_dtype!r} (latent "
-                              f"pages are {self.cfg.dtype})", self.roadmap)
+            raise unsupported(self.name, f"kv_dtype={kv_dtype!r} "
+                              f"({self.pages} are {self.cfg.dtype})",
+                              self.roadmap)
         if host_pages:
-            raise unsupported(self.name, "host tiering of latent pages "
-                              "(host_pages)", self.roadmap)
+            raise unsupported(self.name, f"host tiering of {self.pages} "
+                              f"(host_pages)", self.roadmap)
         if handoff:
-            raise unsupported(self.name, "KV handoff of latent pages",
+            raise unsupported(self.name, f"KV handoff of {self.pages}",
                               self.roadmap)
         return self.cfg.dtype
 
@@ -357,6 +372,7 @@ class KimiLinearKind(AXK1Kind):
 
     name = "kimi_linear"
     config_cls = KimiLinearConfig
+    model_cls = KimiLinear
     paged_fns = staticmethod(kimi_linear_paged_fns)
     roadmap = "R5"
     slot_state = True
@@ -378,23 +394,80 @@ class KimiLinearKind(AXK1Kind):
             + self.cfg.state_slot_bytes
 
 
-KINDS = {GPTKind.name: GPTKind, AXK1Kind.name: AXK1Kind,
-         KimiLinearKind.name: KimiLinearKind}
+# ---------------------------------------------------------------- afmoe
+
+
+class AfmoeKind(AXK1Kind):
+    """`models.afmoe`: two classes of cache in one pools pytree. K and V
+    pages (bfloat16, `[P, page_tokens, kv heads x head_dim]`, page axis
+    0) for the full-attention layers ONLY, addressed through the block
+    table; and for each window layer a RING of `sliding_window` K and V
+    rows that lives by slot, `sliding_window / page_tokens` pages of a
+    pool `[(slots + 1) x ring pages, page_tokens, ..]` of its own, the
+    row of position p at `p mod sliding_window`; plus the routed
+    counters. The module docstring has the seam's contract for slot
+    state: an admission overwrites the ring, a step advances it in
+    place, a finish abandons it. Refused, typed, at construction: what
+    `axk1` refuses; prefix reuse is off (a page hit without the ring at
+    that boundary would serve wrong tokens; ROADMAP R2)."""
+
+    name = "afmoe"
+    config_cls = AfmoeConfig
+    model_cls = Afmoe
+    pages = "K/V pages"
+    paged_fns = staticmethod(afmoe_paged_fns)
+    roadmap = "R2"
+    slot_state = True
+
+    def pools_sds(self, num_pages, page_tokens, kv_dtype, slots):
+        return afmoe_pools_sds(self.cfg, num_pages, page_tokens, slots)
+
+    @staticmethod
+    def copy_page(pools, src, dst):
+        return dict(pools, k=copy_page(pools["k"], src, dst),
+                    v=copy_page(pools["v"], src, dst))
+
+    def page_bytes(self, page_tokens, kv_dtype):
+        c = self.cfg
+        return len(c.full_index) * 2 * int(page_tokens) * c.kv_width \
+            * jnp.dtype(c.dtype).itemsize
+
+    def state_bytes(self, slots):
+        """Bytes of the rings of an engine of `slots` slots (its null
+        slot counted)."""
+        return (int(slots) + 1) * self.cfg.ring_slot_bytes
+
+    def slot_bytes(self):
+        return self.page_bytes(self.max_seq_len, None) \
+            + self.cfg.ring_slot_bytes
+
+
+KINDS = {kind.name: kind
+         for kind in (GPTKind, AXK1Kind, KimiLinearKind, AfmoeKind)}
+
+
+def _kind_of(what, attr):
+    """The kind whose `attr` ("config_cls" | "model_cls") `what` is an
+    instance of."""
+    for kind in KINDS.values():
+        if isinstance(what, getattr(kind, attr)):
+            return kind
+    return None
 
 
 def for_config(cfg, eps=None):
-    if isinstance(cfg, GPTConfig):
-        return GPTKind(cfg, eps)
-    for kind in (AXK1Kind, KimiLinearKind):
-        if isinstance(cfg, kind.config_cls):
-            return kind(cfg, eps)
-    raise TypeError(f"no decode model kind for config {type(cfg).__name__}")
+    kind = _kind_of(cfg, "config_cls")
+    if kind is None:
+        raise TypeError(f"no decode model kind for config "
+                        f"{type(cfg).__name__}")
+    return kind(cfg, eps)
 
 
 def for_model(model, eps=None):
-    kind = AXK1Kind if isinstance(model, AXK1) \
-        else KimiLinearKind if isinstance(model, KimiLinear) else GPTKind
-    return kind.from_model(model, eps)
+    """The kind of a model of the framework; a model that is none of
+    the kinds' classes is served as a GPT (a subclass of it, or a layer
+    that has its `cfg` and `ln_f`), as before the kinds had names."""
+    return (_kind_of(model, "model_cls") or GPTKind).from_model(model, eps)
 
 
 def from_manifest(meta):

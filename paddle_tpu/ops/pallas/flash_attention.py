@@ -101,8 +101,15 @@ def _bwd_block_sizes(T, D):
 # forward kernel: grid (BH, nq, nk), scratch carries (m, l, acc) over nk
 # ---------------------------------------------------------------------------
 
+def _band_first(qi, block_q, block_k, window):
+    """The first k block a q block's rows can see under a window:
+    the one holding position `qi * block_q - window + 1`."""
+    return jax.lax.div(jnp.maximum(qi * block_q - (window - 1), 0),
+                       jnp.int32(block_k))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
-                scale, causal, block_q, block_k, nk, mxu):
+                scale, causal, block_q, block_k, nk, mxu, window=None):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -112,8 +119,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
         l_sc[:] = jnp.zeros_like(l_sc[:])
         acc_sc[:] = jnp.zeros_like(acc_sc[:])
 
+    # under a window the grid's k axis counts from the band's first
+    # block (`_fwd`'s index map), not from block 0
+    kb = kj if window is None \
+        else kj + _band_first(qi, block_q, block_k, window)
     # causal: process only blocks intersecting the lower triangle
-    should = (kj * block_k <= qi * block_q + block_q - 1) if causal else True
+    should = (kb * block_k <= qi * block_q + block_q - 1) if causal else True
 
     @pl.when(should)
     def _step():
@@ -126,9 +137,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
         if causal:
             rows = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            cols = kj * block_k + jax.lax.broadcasted_iota(
+            cols = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            seen = rows >= cols
+            if window is not None:
+                seen = seen & (rows - cols < window)
+            s = jnp.where(seen, s, NEG_INF)
         m_prev = m_sc[:, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -147,13 +161,42 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
         lse_ref[0] = m_sc[:] + jnp.log(jnp.maximum(l_sc[:], np.float32(1e-30)))
 
 
-def _fwd(q3, k3, v3, scale, causal):
+def band_blocks(T, block_q, block_k, window):
+    """The most k blocks any q block of `block_q` rows touches under
+    `window`: the grid's k extent of a banded call."""
+    return max((i * block_q + block_q - 1) // block_k
+               - max(i * block_q - (window - 1), 0) // block_k + 1
+               for i in range(T // block_q))
+
+
+def _fwd(q3, k3, v3, scale, causal, window=None):
+    """q3 [BH, T, D]; k3, v3 [BHkv, T, .], `BH // BHkv` query heads a
+    K/V head, query head b reading K/V head `b // group` (no repeat in
+    memory). `window` (with `causal`): row t sees keys `t - window + 1
+    .. t`; the grid's k axis then spans the band alone, so a cell
+    wholly behind the window does not exist, and past the diagonal the
+    index map stays on the diagonal's block (no fetch, no work)."""
     BH, T, D = q3.shape
     Dv = v3.shape[2]        # the value width; training's calls have Dv == D
+    group = BH // k3.shape[0]
     bq, bk = _block_sizes(T, D)
     nq, nk = T // bq, T // bk
+    if window is not None and window >= T:
+        window = None       # the band is the whole triangle
+    if window is not None:
+        nk = band_blocks(T, bq, bk, window)
+
+    def kv_map(b, i, j):
+        if window is not None:
+            j = jnp.minimum(j + _band_first(i, bq, bk, window),
+                            jax.lax.div(i * bq + bq - 1, jnp.int32(bk)))
+        if group > 1:
+            b = jax.lax.div(b, jnp.int32(group))
+        return (b, j, _I0)
+
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             block_q=bq, block_k=bk, nk=nk, mxu=_mxu_dtype())
+                             block_q=bq, block_k=bk, nk=nk, mxu=_mxu_dtype(),
+                             window=window)
     o, lse = pl.pallas_call(
         kern,
         name="flash_attention_fwd",
@@ -161,10 +204,8 @@ def _fwd(q3, k3, v3, scale, causal):
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, _I0),
                          memory_space=_VMEM),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, _I0),
-                         memory_space=_VMEM),
-            pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, _I0),
-                         memory_space=_VMEM),
+            pl.BlockSpec((1, bk, D), kv_map, memory_space=_VMEM),
+            pl.BlockSpec((1, bk, Dv), kv_map, memory_space=_VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, _I0),
@@ -436,25 +477,37 @@ def flash_attention(q, k, v, causal=False, scale=None):
     return jnp.transpose(o3.reshape(B, H, T, D), (0, 2, 1, 3))
 
 
-def flash_attention_forward(q, k, v, causal=False, scale=None):
-    """Forward only, for inference: q, k [B, T, H, D] and v
-    [B, T, H, Dv] -> [B, T, H, Dv]. The value width is its own (latent
-    attention's expanded prefill has 192-wide q and k and 128-wide v);
-    no gradient is defined. `flash_attention` keeps one width, which is
-    all its backward kernels know."""
+def flash_attention_forward(q, k, v, causal=False, scale=None, window=None):
+    """Forward only, for inference: q [B, T, H, D], k [B, T, Hkv, D] and
+    v [B, T, Hkv, Dv] -> [B, T, H, Dv]. The value width is its own
+    (latent attention's expanded prefill has 192-wide q and k and
+    128-wide v). `Hkv` may divide `H` (grouped-query heads): query head
+    j reads K/V head `j // (H // Hkv)` where it lies. `window` (causal
+    only): position t attends `t - window + 1 .. t`, and blocks wholly
+    outside that band are not visited. No gradient is defined;
+    `flash_attention` keeps one width and one head count, which is all
+    its backward kernels know."""
     B, T, H, D = q.shape
     Dv = v.shape[-1]
     if scale is None:
         scale = 1.0 / math.sqrt(D)
+    if H % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"flash_attention_forward: {H} query heads over "
+                         f"{k.shape[2]} key and {v.shape[2]} value heads")
+    if window is not None and (not causal or window < 1):
+        raise ValueError("flash_attention_forward: a window is causal "
+                         "and at least 1")
     bq, bk = _block_sizes(T, D)
     if T % bq or T % bk:
         raise ValueError(f"flash_attention_forward: seq len {T} must be a "
                          f"multiple of the block size {bq}")
 
     def to3(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(B * H, T, x.shape[-1])
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(
+            B * x.shape[2], T, x.shape[-1])
 
-    o3, _ = _fwd(to3(q), to3(k), to3(v), float(scale), bool(causal))
+    o3, _ = _fwd(to3(q), to3(k), to3(v), float(scale), bool(causal),
+                 None if window is None else int(window))
     return jnp.transpose(o3.reshape(B, H, T, Dv), (0, 2, 1, 3))
 
 

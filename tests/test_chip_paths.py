@@ -415,6 +415,67 @@ def test_prefill_temporaries_are_no_larger_than_the_parents(
     assert temp <= PARENT_PREFILL_TEMP[config, rung], temp
 
 
+# the fourth kind's programs, as PR 35 compiled them (bytes of temporaries)
+AFMOE_TEMP = {"step": 16 * 2 ** 20, 2048: 200 * 2 ** 20, 16384: 1800 * 2 ** 20}
+
+
+@pytest.mark.parametrize("program", list(AFMOE_TEMP))
+def test_the_window_kinds_programs_compile_and_fit(v5e_chip, as_on_a_tpu,
+                                                   program):
+    """The `afmoe` kind at the long-document cell's sizes (26 slots of
+    16,384 positions, pages of 128), compiled for the chip: the step
+    runs the paged grouped-query reader once a layer over pages and
+    rings alike and writes both in place; a prefill runs the flash
+    forward once a layer (four in a band, one causal); weights, pools
+    and the largest temporaries together stay inside the chip."""
+    import jax.numpy as jnp
+
+    sys.path.insert(0, REPO)
+    from chipbench import harness
+    from paddle_tpu.inference import model_kinds
+    from paddle_tpu.ops.pallas.gqa_attention import KERNEL_NAME
+
+    _, sizes, family = harness.load_config(harness.load_benchmark(),
+                                           "trinity-large-share8")
+    kind = model_kinds.for_config(family._config(sizes), None)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=v5e_chip), tree)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=v5e_chip)
+
+    pt, slots = kind.default_page_tokens(), 26
+    W = sizes["max_seq_len"] // pt
+    pools = on_chip(kind.pools_sds(slots * W + 1, pt, kind.pool_dtype(None),
+                                   slots))
+    params = on_chip(family.param_shapes(sizes))
+    with jax.default_matmul_precision("default"):
+        if program == "step":
+            lowered = jax.jit(kind.step_fn(pt), donate_argnums=(1,)).lower(
+                params, pools, ints(slots, W), ints(slots), ints(slots),
+                ints(slots))
+        else:
+            lowered = jax.jit(kind.prefill_fn(pt), donate_argnums=(1,)).lower(
+                params, pools, ints(1, program), ints(1, program // pt),
+                ints(1), ints())
+        exe = lowered.compile()
+    text, mem = exe.as_text(), exe.memory_analysis()
+    if program == "step":
+        assert KERNEL_NAME in text
+        assert "flash_attention_fwd" not in text
+    else:
+        assert "flash_attention_fwd" in text and KERNEL_NAME not in text
+    # every pool byte is aliased to its output: written where it lies
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(pools))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes <= AFMOE_TEMP[program], mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+
+
 def test_the_grouped_layer_compiles_for_tokens_of_no_whole_tile(
         v5e_chip, as_on_a_tpu):
     """A full-sequence forward hands the expert layer any token count

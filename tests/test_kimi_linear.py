@@ -568,6 +568,11 @@ PARENT = {   # noqa: E501
     # the parent of PR 34 (the expert layer's many-token path changed)
     "kimi_linear.step": "ab2b0c93490c7dd8be31a2c317fa7c406f96f5e9f7cf79facd2cba67c4c8c5d6",
     "routed.many": "f48cd35e3476a3a389d7a35611bbae5dc01d5c118f056bd0ecc088606f12589a",
+    # the parent of PR 35 (the fourth kind; flash attention gained a band)
+    "kimi_linear.prefill": "64bef6ad572df183ae769ee5c91fed128d2f2e921066d0014f922da0efe9a5e9",
+    # PR 35 itself: the kind it brought, pinned for the PRs after it
+    "afmoe.step": "1273830a58efa041efb24ebf6b64deaea62b3a4f12f1c4e78dd58d2accaa4a52",
+    "afmoe.prefill": "91e63b577008409371af831ea7a22b0d60f035957bfb581f95116dbb8d2a87b7",
 }
 
 
@@ -600,18 +605,35 @@ def _seam_texts():
     model, _ = build(seed=13)
     kind = model_kinds.for_model(model)
     rows = jax.ShapeDtypeStruct((2,), i32)
-    out["kimi_linear.step"] = jax.jit(
-        kind.step_fn(4), donate_argnums=(1,)).lower(
-            framework.param_arrays(model),
-            kind.pools_sds(9, 4, kind.pool_dtype(None), 2),
-            jax.ShapeDtypeStruct((2, 4), i32), rows, rows, rows).compiler_ir(
-                dialect="stablehlo").operation.get_asm(
-                    enable_debug_info=False)
+    # and, since PR 35, its prefill and the two of the fourth kind (the
+    # second with state by slot), as the PR that brought that kind
+    # lowers them
+    from paddle_tpu.models.afmoe import Afmoe, afmoe_tiny
+    paddle.seed(13)
+    for name, model in (("kimi_linear", model), ("afmoe", Afmoe(afmoe_tiny(
+            held_experts=(4, 6))))):
+        kind = model_kinds.for_model(model)
+        args = (framework.param_arrays(model),
+                kind.pools_sds(9, 4, kind.pool_dtype(None), 2))
+        for what, fn, rest in (
+                ("step", kind.step_fn(4),
+                 (jax.ShapeDtypeStruct((2, 4), i32), rows, rows, rows)),
+                ("prefill", kind.prefill_fn(4, name="prefill"),
+                 (jax.ShapeDtypeStruct((1, 16), i32),
+                  jax.ShapeDtypeStruct((1, 4), i32),
+                  jax.ShapeDtypeStruct((1,), i32),
+                  jax.ShapeDtypeStruct((), i32)))):
+            out[f"{name}.{what}"] = jax.jit(fn, donate_argnums=(1,)).lower(
+                *args, *rest).compiler_ir(
+                    dialect="stablehlo").operation.get_asm(
+                        enable_debug_info=False)
     return out
 
 
 @pytest.mark.parametrize("program", ["gpt.step", "gpt.prefill", "axk1.step",
-                                     "axk1.prefill", "kimi_linear.step"])
+                                     "axk1.prefill", "kimi_linear.step",
+                                     "kimi_linear.prefill", "afmoe.step",
+                                     "afmoe.prefill"])
 def test_the_seam_leaves_the_other_kinds_programs_text_equal(program):
     """No slot argument is threaded through kinds that have no such
     state, and the shared MLA / FFN / routing functions trace for
